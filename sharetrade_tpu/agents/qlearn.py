@@ -41,10 +41,12 @@ from sharetrade_tpu.precision import FP32
 
 def make_qlearn_agent(model: Model, env: TradingEnv,
                       cfg: LearnerConfig, *, num_agents: int = 10,
-                      steps_per_chunk: int = 200, precision=None) -> Agent:
+                      steps_per_chunk: int = 200, precision=None,
+                      update_sharding=None) -> Agent:
     optimizer = build_optimizer(cfg)
     precision = precision or FP32
-    apply_update = make_update_fn(optimizer, cfg, precision)
+    apply_update = make_update_fn(optimizer, cfg, precision,
+                                  sharding=update_sharding)
     horizon = env.num_steps
 
     def init(key: jax.Array) -> TrainState:

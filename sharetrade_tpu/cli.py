@@ -15,6 +15,7 @@ portfolio aggregation plus throughput.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import signal
@@ -25,6 +26,12 @@ import time
 from sharetrade_tpu.config import FrameworkConfig
 from sharetrade_tpu.data.service import PriceDataService
 from sharetrade_tpu.utils.logging import configure, get_logger
+from sharetrade_tpu.utils.runtime_env import (
+    configure_compile_cache,
+    device_block,
+    device_process_refusal,
+    supervising_only,
+)
 
 log = get_logger("cli")
 
@@ -190,6 +197,7 @@ def cmd_train(args) -> int:
             "agent_steps_per_sec": total_agent_steps / max(elapsed, 1e-9),
             "elapsed_s": elapsed,
             "restarts": orch.restarts,
+            "device": device_block(),
         }
         if args.eval:
             result.update(orch.evaluate())
@@ -322,7 +330,8 @@ def cmd_serve(args) -> int:
         print(json.dumps({"event": "serving_ready", "params_step": step,
                           "model": agent.model.name,
                           "max_batch": cfg.serve.max_batch,
-                          "slots": cfg.serve.slots}), flush=True)
+                          "slots": cfg.serve.slots,
+                          "device": device_block()}), flush=True)
 
         if args.listen:
             # Fleet worker mode (fleet/frontend.py): expose submit over
@@ -444,6 +453,7 @@ def cmd_serve(args) -> int:
             "drained": drained,
             "stopped_clean": stopped_clean,
             "engine_failed": engine_failed,
+            "device": device_block(),
         }
         # Session-tier counters (ISSUE 18): only meaningful when the
         # warm tier is on (serve.warm_bytes > 0), so gate on activity.
@@ -604,6 +614,13 @@ def cmd_learner(args) -> int:
                   "(got %d); use cli train for the single-process loop",
                   cfg.distrib.num_actors)
         return 1
+    refusal = device_process_refusal(
+        1 + cfg.distrib.num_actors,
+        f"cli learner (in-process learner + {cfg.distrib.num_actors} "
+        "cli actor children)")
+    if refusal:
+        log.error("%s", refusal)
+        return 1
     if cfg.learner.algo != "dqn" and cfg.distrib.ingest_every_updates > 0:
         log.error("actor-feed ingest requires learner.algo=dqn (replay "
                   "buffer); got %r", cfg.learner.algo)
@@ -723,6 +740,14 @@ def cmd_learner(args) -> int:
 
 
 def cmd_fleet(args) -> int:
+    """``cli fleet``. Without ``--learner`` this process only supervises
+    (EnginePool, router, front-end): it must stay off JAX, or it takes the
+    chip its one engine child needs."""
+    with (contextlib.nullcontext() if args.learner else supervising_only()):
+        return _cmd_fleet(args)
+
+
+def _cmd_fleet(args) -> int:
     """The whole serving fleet in one command (fleet/): N supervised
     ``cli serve --listen`` engine workers (EnginePool), the telemetry-
     driven router behind one public front-end port, and — with
@@ -746,6 +771,15 @@ def cmd_fleet(args) -> int:
         cfg.fleet.num_engines = args.engines
     if getattr(args, "autoscale", False):
         cfg.fleet.autoscale = True
+    engines = max(cfg.fleet.num_engines,
+                  cfg.fleet.max_engines if cfg.fleet.autoscale else 0)
+    refusal = device_process_refusal(
+        engines + bool(args.learner),
+        f"cli fleet ({engines} cli serve --listen engine(s)"
+        + (" + the in-process learner)" if args.learner else ")"))
+    if refusal:
+        log.error("%s", refusal)
+        return 1
     if args.learner:
         # The flywheel's learner half: ingest session journals with no
         # ActorPool in this process, and evaluate often enough that
@@ -1095,6 +1129,7 @@ def main(argv=None) -> int:
 
     args = parser.parse_args(argv)
     configure()
+    configure_compile_cache()
     return args.fn(args)
 
 

@@ -118,6 +118,13 @@ def build_model(cfg: ModelConfig, obs_dim: int, *, head: str = "ac",
             has_shard_map_axis as _has_shard_map_axis, mesh_platform)
         use_pallas = (False if mesh is not None
                       and mesh_platform(mesh) != "tpu" else None)
+        # Mosaic kernels cannot be partitioned automatically: on a
+        # multi-device mesh the local kernels run under a shard_map over
+        # the batch axis (ops/attention.py). A pipeline stage already is
+        # one (parallel/pipeline.py), and shard_maps do not nest.
+        kernel_mesh = (mesh if mesh is not None and mesh.size > 1
+                       and use_pallas is None and not cfg.pipeline_blocks
+                       else None)
         if cfg.seq_mode == "episode":
             if num_assets > 1:
                 raise ConfigError(
@@ -178,7 +185,8 @@ def build_model(cfg: ModelConfig, obs_dim: int, *, head: str = "ac",
                 # axes compile clean already and keep their exact
                 # programs (mesh.has_shard_map_axis — the same scope
                 # predicate as PPO's rollout→update seam).
-                seam_mesh=(mesh if _has_shard_map_axis(mesh) else None))
+                seam_mesh=(mesh if _has_shard_map_axis(mesh) else None),
+                kernel_mesh=kernel_mesh)
         if cfg.attention in ("ring", "ulysses"):
             if mesh is None or "sp" not in mesh.axis_names:
                 raise ConfigError(
@@ -223,5 +231,6 @@ def build_model(cfg: ModelConfig, obs_dim: int, *, head: str = "ac",
             moe_experts=cfg.moe_experts, ep_mesh=ep_mesh,
             moe_top_k=cfg.moe_top_k,
             moe_capacity_factor=cfg.moe_capacity_factor,
-            moe_dispatch=cfg.moe_dispatch, num_assets=num_assets)
+            moe_dispatch=cfg.moe_dispatch, num_assets=num_assets,
+            kernel_mesh=kernel_mesh)
     raise ConfigError(f"unknown model kind {cfg.kind!r}")

@@ -43,7 +43,7 @@ def transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
                        ep_axis: str = "ep", moe_top_k: int = 0,
                        moe_capacity_factor: float = 1.25,
                        moe_dispatch: str = "psum",
-                       num_assets: int = 1) -> Model:
+                       num_assets: int = 1, kernel_mesh=None) -> Model:
     """``attention_fn(q, k, v) -> out`` overrides the local flash kernel —
     the sequence-parallel hook (e.g. ``ring_attention_sharded`` binds a mesh
     so attention rings over the sp axis, parallel/ring_attention.py).
@@ -54,6 +54,12 @@ def transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
     stored stacked (leading dim = num_layers) so stage i's slice shards onto
     pp-device i. ``pp_batch_axis`` names the mesh axis the agent batch is
     sharded over (usually "dp") so microbatches keep that sharding.
+
+    ``kernel_mesh``: the multi-device mesh the model's programs are
+    partitioned over; the local flash kernel then runs under a shard_map
+    over ``pp_batch_axis`` (ops/attention.py ``_per_device`` — a bare
+    Mosaic call cannot be partitioned). None inside a pipeline stage,
+    which is per-device already.
 
     ``num_assets`` > 1 tokenizes the multi-asset portfolio observation
     (env/portfolio.py: A windows ++ budget ++ A share counts) as A
@@ -73,7 +79,8 @@ def transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
     d_model = num_heads * head_dim
     if attention_fn is None:
         attention_fn = lambda q, k, v: flash_attention(  # noqa: E731
-            q, k, v, causal=True, use_pallas=use_pallas)
+            q, k, v, causal=True, use_pallas=use_pallas,
+            mesh=kernel_mesh, batch_axis=pp_batch_axis)
     if pp_mesh is not None and pp_mesh.shape[pp_axis] != num_layers:
         raise ConfigError(
             f"pipeline_blocks needs num_layers == pp size "

@@ -98,7 +98,7 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
                                moe_capacity_factor: float = 1.25,
                                moe_dispatch: str = "psum",
                                remat_blocks: bool = False,
-                               seam_mesh=None) -> Model:
+                               seam_mesh=None, kernel_mesh=None) -> Model:
     """Build the episode-mode policy (``ModelConfig.seq_mode="episode"``).
 
     ``attention_fn(q, k, v, window) -> out`` overrides the local banded
@@ -123,6 +123,13 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
     idling (stages-1)/stages of the schedule; m=1 remains only for
     sequences shorter than two window-1 chunks. pp + MoE is rejected
     (nested shard_maps), as is pp + a non-local attention override.
+
+    ``kernel_mesh``: the multi-device mesh the model's programs are
+    partitioned over; the local banded kernel then runs under a shard_map
+    over ``pp_batch_axis`` (ops/attention.py ``_per_device`` — a bare
+    Mosaic call cannot be partitioned), replicated for the batch-of-one
+    trunk passes. None with ``pp_mesh``: a pipeline stage is per-device
+    already.
     """
     if head_dim % 2:
         raise ConfigError(f"RoPE needs an even head_dim, got {head_dim}")
@@ -153,7 +160,8 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
     sm_scale = head_dim ** -0.5
     def local_attention(q, k, v, w):
         return flash_attention(q, k, v, causal=True, sm_scale=sm_scale,
-                               local_window=w, use_pallas=use_pallas)
+                               local_window=w, use_pallas=use_pallas,
+                               mesh=kernel_mesh, batch_axis=pp_batch_axis)
 
     if attention_fn is None:
         attention_fn = local_attention

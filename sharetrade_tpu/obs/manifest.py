@@ -43,11 +43,17 @@ def config_hash(cfg: Any) -> str:
 
 def build_manifest(cfg: Any, *, mesh: Any = None) -> dict:
     cfg_dict = cfg.to_dict()
+    from sharetrade_tpu.utils.runtime_env import owns_devices
     try:
         import jax
-        backend = jax.default_backend()
-        device_count = jax.device_count()
         jax_version = jax.__version__
+        if owns_devices():
+            backend = jax.default_backend()
+            device_count = jax.device_count()
+        else:
+            # A supervising parent (cli fleet without --learner): asking
+            # would take the chip from the child that serves on it.
+            backend = device_count = None
     except Exception:       # manifest must not force device discovery to work
         backend, device_count, jax_version = None, None, None
     manifest = {

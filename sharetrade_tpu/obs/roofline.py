@@ -201,16 +201,28 @@ class RooflineCapture:
                  peak_hbm_bw: float | None = None,
                  flight_record: Callable[..., None] | None = None):
         if peak_flops is None or peak_hbm_bw is None:
-            from sharetrade_tpu.utils.flops import (chip_peak_flops,
+            from sharetrade_tpu.utils.flops import (UnknownDeviceKind,
+                                                    chip_peak_flops,
                                                     chip_peak_hbm_bw)
-            peak_flops = peak_flops or chip_peak_flops()
-            peak_hbm_bw = peak_hbm_bw or chip_peak_hbm_bw()
+            try:
+                peak_flops = peak_flops or chip_peak_flops()
+                peak_hbm_bw = peak_hbm_bw or chip_peak_hbm_bw()
+            except UnknownDeviceKind as exc:
+                # No published peak for this device (the CPU included):
+                # program costs and achieved rates are still captured, but
+                # nothing relative to a peak — no mfu, no ridge, no bound
+                # classification — is recorded. "Not measured", never a
+                # figure relative to some other chip.
+                log.info("roofline: %s", exc)
+                peak_flops = peak_hbm_bw = None
         self.registry = registry
         self.run_dir = run_dir
-        self.peak_flops = float(peak_flops)
-        self.peak_hbm_bw = float(peak_hbm_bw)
-        #: FLOPs/byte above which a program is compute-bound on this chip.
-        self.ridge = self.peak_flops / self.peak_hbm_bw
+        self.peak_flops = None if peak_flops is None else float(peak_flops)
+        self.peak_hbm_bw = None if peak_hbm_bw is None else float(peak_hbm_bw)
+        #: FLOPs/byte above which a program is compute-bound on this chip
+        #: (None where the chip's peaks are not known).
+        self.ridge = (self.peak_flops / self.peak_hbm_bw
+                      if self.peak_flops and self.peak_hbm_bw else None)
         #: Analytic model FLOPs for ONE chunk's dispatch span
         #: (train_flops_per_agent_step x workers x chunk_steps); the
         #: orchestrator sets it once the env's obs_dim is known. None
@@ -274,7 +286,7 @@ class RooflineCapture:
         ba = raw_ba * scale if raw_ba is not None else None
         ai = (flops / ba) if flops and ba else None
         classification = None
-        if ai is not None:
+        if ai is not None and self.ridge is not None:
             classification = ("compute-bound" if ai >= self.ridge
                               else "memory-bound")
         peak_bytes = None
@@ -350,11 +362,13 @@ class RooflineCapture:
         if flops:
             achieved = flops / chunk_seconds
             gauges["achieved_tflops"] = achieved / 1e12
-            gauges["mfu"] = achieved / self.peak_flops
+            if self.peak_flops:
+                gauges["mfu"] = achieved / self.peak_flops
         if ba:
             gauges["hbm_gbps"] = ba / chunk_seconds / 1e9
         if cost.arithmetic_intensity is not None:
             gauges["arithmetic_intensity"] = cost.arithmetic_intensity
+        if cost.classification is not None:
             gauges["roofline_compute_bound"] = float(
                 cost.classification == "compute-bound")
         if gauges:
@@ -433,4 +447,9 @@ def summarize_roofline(bundle: dict, *, top: int = 3) -> dict:
         "memory_bound": [
             _brief(n) for n in by_flops
             if programs[n].get("classification") == "memory-bound"][:top],
+        # No published peak for the device the run used (the CPU
+        # included): costs captured, bound not measured.
+        "unclassified": [
+            _brief(n) for n in by_flops
+            if programs[n].get("classification") is None][:top],
     }

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from sharetrade_tpu.config import ConfigError
 
@@ -712,21 +713,56 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, local_window,
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention(q, k, v, causal, sm_scale, local_window, interpret):
-    out, _ = _flash_forward(q, k, v, causal, sm_scale, local_window, interpret)
-    return out
+def _per_device(fn, shard, in_ranks, out_ranks):
+    """``fn`` itself, or ``fn`` run once per device of a mesh.
+
+    Mosaic kernels cannot be partitioned automatically: a bare
+    ``pallas_call`` inside a program jitted over several devices is refused
+    ("wrap the call in a shard_map"). ``shard = (mesh, batch_axis)`` wraps
+    the kernel call in a ``shard_map`` whose every operand splits its
+    LEADING (batch) dim over ``batch_axis`` — attention rows are
+    independent, so each device runs the kernel on its own rows with no
+    collective — or, with ``batch_axis=None`` (the episode model's
+    batch-of-one shared trunk), replicates: every device computes the same
+    small call. The wrap sits INSIDE the custom_vjp, around the forward and
+    backward kernel calls separately, so autodiff never transposes a
+    shard_map and the replicated case needs no psum."""
+    if shard is None:
+        return fn
+    mesh, batch_axis = shard
+
+    def spec(rank):
+        return P(batch_axis, *([None] * (rank - 1)))
+
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=tuple(spec(r) for r in in_ranks),
+        out_specs=tuple(spec(r) for r in out_ranks), check_vma=False)
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, local_window, interpret):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, local_window, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_attention(q, k, v, causal, sm_scale, local_window, interpret,
+                     shard=None):
+    return _flash_fwd_rule(q, k, v, causal, sm_scale, local_window,
+                           interpret, shard)[0]
+
+
+def _flash_fwd_rule(q, k, v, causal, sm_scale, local_window, interpret,
+                    shard):
+    fwd = _per_device(
+        lambda q, k, v: _flash_forward(q, k, v, causal, sm_scale,
+                                       local_window, interpret),
+        shard, (4, 4, 4), (4, 3))
+    out, lse = fwd(q, k, v)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_rule(causal, sm_scale, local_window, interpret, residuals, g):
-    q, k, v, out, lse = residuals
-    return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                           local_window, interpret)
+def _flash_bwd_rule(causal, sm_scale, local_window, interpret, shard,
+                    residuals, g):
+    bwd = _per_device(
+        lambda q, k, v, out, lse, g: _flash_backward(
+            q, k, v, out, lse, g, causal, sm_scale, local_window, interpret),
+        shard, (4, 4, 4, 4, 3, 4), (4, 4, 4))
+    return bwd(*residuals, g)
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -735,7 +771,8 @@ _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: float | None = None,
                     local_window: int | None = None,
-                    use_pallas: bool | None = None):
+                    use_pallas: bool | None = None,
+                    mesh=None, batch_axis: str | None = None):
     """Causal MHA over (batch, heads, seq, head_dim).
 
     ``local_window=W`` restricts each query to the W-key band ending at
@@ -747,6 +784,13 @@ def flash_attention(q, k, v, *, causal: bool = True,
     ``use_pallas=None`` auto-selects: the kernel on TPU, the XLA reference
     elsewhere (the unit suite runs the kernel through the Pallas interpreter
     separately — tests/test_ops.py — so both paths stay covered).
+
+    ``mesh``: the mesh of the program this call is traced into, when that
+    program is partitioned over more than one device. The kernel then runs
+    under a ``shard_map`` with the batch dim split over ``batch_axis``
+    (replicated when the batch does not divide it) — see
+    :func:`_per_device`. Callers already inside a ``shard_map`` (the sp /
+    ulysses / pipeline paths) pass no mesh: they are per-device already.
     """
     if q.ndim != 4:
         raise ConfigError(f"expected (batch, heads, seq, head_dim), got {q.shape}")
@@ -765,4 +809,10 @@ def flash_attention(q, k, v, *, causal: bool = True,
         return reference_attention(q, k, v, causal=causal, sm_scale=sm_scale,
                                    local_window=local_window)
     interpret = jax.default_backend() != "tpu"
-    return _flash_attention(q, k, v, causal, sm_scale, local_window, interpret)
+    shard = None
+    if mesh is not None and mesh.size > 1:
+        if batch_axis is not None and q.shape[0] % mesh.shape[batch_axis]:
+            batch_axis = None      # e.g. the batch-of-one trunk: replicate
+        shard = (mesh, batch_axis)
+    return _flash_attention(q, k, v, causal, sm_scale, local_window,
+                            interpret, shard)

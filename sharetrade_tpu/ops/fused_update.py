@@ -25,7 +25,8 @@ update into one pass per leaf:
   runs), because threading the copy would put a second weight tree in
   the scan carry / TrainState shape. ``emit_compute`` is the seam for
   the TPU follow-up where that read is worth eliminating; it is
-  compiled by tools/smoke_compile.py and pinned by tests either way.
+  compiled for a described v5e by tests/test_chip_compile.py and pinned
+  by tests either way.
 - **elsewhere** (the CPU test/dev tier): the same arithmetic as plain jnp
   ops inside the caller's jit — XLA fuses the chain into one elementwise
   pass per leaf, so the fallback is semantically identical and leaves no
@@ -131,8 +132,8 @@ def _pallas_leaf(leaf_fn, n_state, p, g, state_leaves, *, compute_dtype,
     elements compute garbage that is sliced off (no cross-element data
     flow in any supported optimizer, so padding never contaminates).
     ``interpret`` runs the kernel in Pallas interpret mode — the CPU test
-    path for kernel logic (tiling legality still needs a real TPU compile,
-    tools/smoke_compile.py)."""
+    path for kernel logic (tiling legality needs the TPU's compiler:
+    tests/test_chip_compile.py, then ``chip_smoke.py`` on the chip)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -182,6 +183,35 @@ def _pallas_leaf(leaf_fn, n_state, p, g, state_leaves, *, compute_dtype,
     return p_new, state_new, p_c
 
 
+def _pallas_leaf_on(mesh, spec, leaf_fn, n_state, p, g, state_leaves, *,
+                    scalar_hyper, emit_compute, **kw):
+    """:func:`_pallas_leaf`, per device of ``mesh`` under a shard_map when
+    a ``spec`` is given: every array operand and result carries the leaf's
+    own ``spec``, the traced per-step scalars ride along replicated."""
+    if spec is None:
+        return _pallas_leaf(leaf_fn, n_state, p, g, state_leaves,
+                            scalar_hyper=scalar_hyper,
+                            emit_compute=emit_compute, **kw)
+    from jax.sharding import PartitionSpec as P
+    names = tuple(sorted(scalar_hyper))
+
+    def per_device(p, g, *rest):
+        p_new, state_new, p_c = _pallas_leaf(
+            leaf_fn, n_state, p, g, rest[:n_state],
+            scalar_hyper=dict(zip(names, rest[n_state:])),
+            emit_compute=emit_compute, **kw)
+        return (p_new, *state_new, *((p_c,) if emit_compute else ()))
+
+    outs = jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(spec,) * (2 + n_state) + (P(),) * len(names),
+        out_specs=(spec,) * (1 + n_state + int(emit_compute)),
+        check_vma=False,
+    )(p, g, *state_leaves, *(scalar_hyper[k] for k in names))
+    return (outs[0], tuple(outs[1:1 + n_state]),
+            outs[1 + n_state] if emit_compute else None)
+
+
 def _use_pallas_default() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -194,7 +224,8 @@ def fused_apply(optimizer_name: str, lr: float, grads: Any, opt_state: Any,
                 params: Any, *, compute_dtype=jnp.float32,
                 emit_compute: bool = False,
                 use_pallas: bool | None = None,
-                interpret: bool = False):
+                interpret: bool = False,
+                mesh=None, param_rules=None):
     """One fused pass over the parameter pytree.
 
     Returns ``(new_params, new_opt_state[, new_compute_params])`` — the
@@ -203,9 +234,21 @@ def fused_apply(optimizer_name: str, lr: float, grads: Any, opt_state: Any,
     optax state from ``build_optimizer(...).init(params)`` and the
     returned state has the identical structure, so fused and optax paths
     (and their checkpoints) interchange freely. Raw (possibly bf16) grads
-    go in; the upcast happens inside the pass."""
+    go in; the upcast happens inside the pass.
+
+    ``mesh`` (+ ``param_rules``): the multi-device mesh of the program this
+    call is traced into. Mosaic kernels cannot be partitioned
+    automatically, so each leaf's kernel then runs under a ``shard_map``
+    with that leaf's own spec (``parallel/sharding.py param_shardings`` —
+    the spec the TrainState is placed by): the update is elementwise, so
+    every device updates exactly the shard it holds, with no collective."""
     if use_pallas is None:
         use_pallas = _use_pallas_default()
+    leaf_specs = None
+    if mesh is not None and mesh.size > 1 and (use_pallas or interpret):
+        from sharetrade_tpu.parallel.sharding import param_shardings
+        leaf_specs = [sh.spec for sh in jax.tree.leaves(
+            param_shardings(params, mesh, param_rules))]
     lr = float(lr)
 
     static_hyper = {"lr": lr}
@@ -252,12 +295,12 @@ def fused_apply(optimizer_name: str, lr: float, grads: Any, opt_state: Any,
         # Pallas needs tiled 2-D blocks; scalars and tiny leaves stay on
         # the (identical-math) fused XLA path.
         if (use_pallas or interpret) and p.size >= _LANE:
-            out = _pallas_leaf(leaf_fn, n_state, p, g, leaves,
-                               compute_dtype=compute_dtype,
-                               emit_compute=emit_compute,
-                               static_hyper=static_hyper,
-                               scalar_hyper=scalar_hyper,
-                               interpret=interpret)
+            out = _pallas_leaf_on(
+                mesh, leaf_specs[i] if leaf_specs else None,
+                leaf_fn, n_state, p, g, leaves,
+                compute_dtype=compute_dtype, emit_compute=emit_compute,
+                static_hyper=static_hyper, scalar_hyper=scalar_hyper,
+                interpret=interpret)
         else:
             out = leaf_fn(p, g, *leaves, compute_dtype=compute_dtype,
                           **static_hyper, **scalar_hyper)

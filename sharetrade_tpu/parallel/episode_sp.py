@@ -37,7 +37,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from sharetrade_tpu.config import ConfigError
 
 from sharetrade_tpu.ops.attention import flash_attention
-from sharetrade_tpu.parallel.compat import shard_map
 
 
 def halo_banded_attention_sharded(mesh: Mesh, *, seq_axis: str = "sp",
@@ -77,7 +76,7 @@ def halo_banded_attention_sharded(mesh: Mesh, *, seq_axis: str = "sp",
         spec = P(b_axis, None, seq_axis, None)
 
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=(spec, spec, spec),
+            jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
             out_specs=spec, check_vma=False)
         def sharded(ql, kl, vl):
             halo = window - 1

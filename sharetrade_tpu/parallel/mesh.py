@@ -87,26 +87,6 @@ def build_mesh(cfg: ParallelConfig | None = None, devices=None) -> Mesh:
     return mesh
 
 
-def _distributed_initialized() -> bool:
-    """Version-portable "is the distributed runtime already up?" probe —
-    the idempotence guard of :func:`init_distributed`. Newer jax exposes
-    ``jax.distributed.is_initialized``; the 0.4.x line on this container
-    does not (calling it raised AttributeError, which is what broke
-    tests/test_distributed.py's gating tier since seed), but its client
-    handle lives at ``jax._src.distributed.global_state.client`` — None
-    until initialize() succeeds. An unreadable probe reads as "not
-    initialized": the worst case is jax's own loud double-initialize
-    error, strictly better than silently skipping bring-up."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    try:
-        from jax._src import distributed as _dist
-        return getattr(_dist.global_state, "client", None) is not None
-    except Exception:
-        return False
-
-
 def init_distributed(coordinator_address: str | None = None,
                      num_processes: int | None = None,
                      process_id: int | None = None,
@@ -137,7 +117,7 @@ def init_distributed(coordinator_address: str | None = None,
     successful bring-up is a no-op (jax raises on double-initialize).
     """
     import os
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return jax.process_count() > 1
     if cpu_collectives is not None:
         jax.config.update("jax_cpu_collectives_implementation", cpu_collectives)

@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from sharetrade_tpu.config import ConfigError
-from sharetrade_tpu.parallel.compat import shard_map
 
 
 def init_moe_params(key: jax.Array, num_experts: int, in_dim: int,
@@ -252,7 +251,7 @@ def moe_apply_topk_sharded(params: dict, tokens: jax.Array, mesh: Mesh,
             out = jax.lax.dynamic_slice_in_dim(out, shard * nloc, nloc, axis=0)
         return out, aux
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(batch_axis)),
         out_specs=(P(batch_axis), P()),
@@ -332,7 +331,7 @@ def moe_apply_topk_a2a(params: dict, tokens: jax.Array, mesh: Mesh,
             combine, ys.reshape(num_experts, groups * cap, d))
         return out[:toks.shape[0]], aux
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(axis)),
         out_specs=(P(axis), P()),
@@ -381,7 +380,7 @@ def moe_apply_sharded(params: dict, tokens: jax.Array, mesh: Mesh,
             aux = jax.lax.pmean(aux, batch_axis)
         return out, aux
 
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P(batch_axis)),
         out_specs=(P(batch_axis), P()),

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from sharetrade_tpu.config import ConfigError
-from sharetrade_tpu.parallel.compat import shard_map
 
 
 def pipeline_apply(stage_fn, stage_params, microbatches, mesh: Mesh,
@@ -149,7 +148,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches, mesh: Mesh,
     # check_vma=False: stage_fn may invoke a pallas_call (the flash kernel),
     # whose out_shapes don't carry varying-mesh-axes metadata; the schedule
     # is stage-local by construction so the check adds nothing here.
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(stage_spec, mb_spec), out_specs=out_specs,
         check_vma=False,

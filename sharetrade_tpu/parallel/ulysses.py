@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from sharetrade_tpu.config import ConfigError
-from sharetrade_tpu.parallel.compat import shard_map
 
 from sharetrade_tpu.ops.attention import flash_attention
 
@@ -69,7 +68,7 @@ def ulysses_attention(q, k, v, mesh: Mesh, *, seq_axis: str = "sp",
                                   concat_axis=1, tiled=True)
 
     spec = P(batch_axis, None, seq_axis, None)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(
         q, k, v)
 

@@ -108,12 +108,17 @@ def host_fingerprint() -> dict:
     """This host's identity as the autotuner sees it. Backend probing is
     best-effort (a profile written where jax could not initialize carries
     ``None`` and only matches hosts in the same state)."""
-    try:
-        import jax
-        backend = jax.default_backend()
-        device_count = jax.device_count()
-    except Exception:       # fingerprinting must never block a run
-        backend = device_count = None
+    from sharetrade_tpu.utils.runtime_env import owns_devices
+    backend = device_count = None
+    # A supervising parent must not take the chip to fingerprint it; its
+    # device-owning children gate the profile themselves.
+    if owns_devices():
+        try:
+            import jax
+            backend = jax.default_backend()
+            device_count = jax.device_count()
+        except Exception:   # fingerprinting must never block a run
+            pass
     import platform
     return {
         "cpu_count": os.cpu_count(),
@@ -248,9 +253,11 @@ def fingerprint_mismatches(profile_fp: dict | None,
     this host (empty = the profile applies here)."""
     if not isinstance(profile_fp, dict):
         return list(_FINGERPRINT_MATCH_KEYS)
+    from sharetrade_tpu.utils.runtime_env import owns_devices
     fp = fp or host_fingerprint()
-    return [k for k in _FINGERPRINT_MATCH_KEYS
-            if profile_fp.get(k) != fp.get(k)]
+    keys = (_FINGERPRINT_MATCH_KEYS if owns_devices()
+            else ("cpu_count",))    # a supervisor cannot see the devices
+    return [k for k in keys if profile_fp.get(k) != fp.get(k)]
 
 
 # ---------------------------------------------------------------------------

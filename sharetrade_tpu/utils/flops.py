@@ -27,14 +27,16 @@ import jax
 
 from sharetrade_tpu.config import FrameworkConfig, LearnerConfig, ModelConfig
 
-# device_kind substrings -> dense bf16 peak FLOP/s per chip.
+# device_kind substrings -> dense bf16 peak FLOP/s per chip (Google Cloud
+# TPU documentation, per-generation system architecture pages). A device
+# that is not in the table is an error, not a default: a utilisation
+# figure relative to some other chip's peak is not a measurement.
 _PEAK_BY_KIND = (
     ("v6 lite", 918e12),   # Trillium
     ("v5p", 459e12),
     ("v5 lite", 197e12),   # v5e
     ("v4", 275e12),
 )
-_DEFAULT_PEAK = 197e12
 
 # device_kind substrings -> HBM bandwidth bytes/s per chip — the other
 # roofline axis (obs/roofline.py): achieved HBM GB/s and the ridge point
@@ -45,32 +47,36 @@ _HBM_BW_BY_KIND = (
     ("v5 lite", 819e9),    # v5e
     ("v4", 1228e9),
 )
-_DEFAULT_HBM_BW = 819e9
+
+
+class UnknownDeviceKind(LookupError):
+    """The device (the CPU included) has no entry in the peak tables, so
+    no utilisation or roofline figure can be stated for it."""
+
+
+def _peak_for(device, table, what: str) -> float:
+    if device is None:
+        device = jax.devices()[0]
+    kind = getattr(device, "device_kind", "")
+    for sub, peak in table:
+        if sub in kind.lower():
+            return peak
+    raise UnknownDeviceKind(
+        f"no published {what} for device_kind {kind!r}; utilisation is "
+        "not measured on this device")
 
 
 def chip_peak_flops(device=None) -> float:
-    """Dense bf16 peak for the attached chip (fallback: v5e)."""
-    if device is None:
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for sub, peak in _PEAK_BY_KIND:
-        if sub in kind:
-            return peak
-    return _DEFAULT_PEAK
+    """Dense bf16 peak FLOP/s of ``device`` (default: the first attached
+    device); raises :class:`UnknownDeviceKind` for a kind not in the
+    table."""
+    return _peak_for(device, _PEAK_BY_KIND, "bf16 peak FLOP/s")
 
 
 def chip_peak_hbm_bw(device=None) -> float:
-    """Peak HBM bytes/s for the attached chip (fallback: v5e). On the CPU
-    backend this — like :func:`chip_peak_flops` — reports the v5e default,
-    so CPU-measured MFU/roofline rows are comparable placeholders for the
-    TPU numbers that slot in later (the BASELINE.md convention)."""
-    if device is None:
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for sub, bw in _HBM_BW_BY_KIND:
-        if sub in kind:
-            return bw
-    return _DEFAULT_HBM_BW
+    """Peak HBM bytes/s of ``device``; raises :class:`UnknownDeviceKind`
+    for a kind not in the table."""
+    return _peak_for(device, _HBM_BW_BY_KIND, "HBM bandwidth")
 
 
 def forward_flops_per_obs(model: ModelConfig, obs_dim: int,

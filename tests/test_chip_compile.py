@@ -1,0 +1,282 @@
+"""Compile the main path's programs for a DESCRIBED TPU v5e — no chip.
+
+The TPU compiler is installed beside JAX and compiles for a ``v5e:2x2``
+topology that is described, not attached, so what the chip's compiler would
+refuse (a Mosaic tiling rule, a VMEM limit, a kernel that cannot be
+partitioned, a program that does not fit HBM) is refused here, on the CPU
+host, at no chip time. Nothing runs: a passing compile is not a chip run.
+
+Rules this file follows (on-chip-measurement guide §2): the topology is
+described inside a module-scoped fixture that skips when it cannot be —
+never at import, in a ``skipif``, in ``parametrize`` arguments or in
+conftest.py; everything compiles in the test's own process (the process
+that loaded libtpu keeps its lock until exit); the persistent compile cache
+is off around the compiles (a described-chip entry cannot be read back
+without a chip); all such tests live in this ONE file so one xdist worker
+owns the library.
+
+The program picks its kernels by ``jax.default_backend()``, which is the CPU
+here, so the tests steer it themselves (``tpu_backend``) — no option of the
+program exists for that.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe = skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip_compile(topo):
+    """Module-wide compile environment: persistent cache off (its entries
+    for a described chip cannot be read back), and the program's own
+    DEFAULT matmul precision instead of the suite's "highest" pin (which
+    has no bf16 meaning inside Mosaic — ops/attention.py ``_dot``)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    cache_was = jax.config.jax_enable_compilation_cache
+    precision_was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    jax.config.update("jax_default_matmul_precision", None)
+    yield topo
+    jax.config.update("jax_default_matmul_precision", precision_was)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(chip_compile):
+    return SingleDeviceSharding(chip_compile.devices[0])
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """The package asks ``jax.default_backend()`` to choose kernel vs XLA
+    fallback and compiled vs interpreted; answer for the described chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _on(sharding, tree):
+    """Shapes of ``tree`` placed by ``sharding`` (one sharding, or a
+    matching tree of them) — a described device cannot hold arrays."""
+    if isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding), tree)
+    return jax.tree.map(lambda x, s: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=s), tree, sharding)
+
+
+def _mosaic_calls(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# -- kernels ----------------------------------------------------------------
+
+ATTENTION_SHAPES = [
+    # (batch, heads, seq, head_dim), dtype, local_window
+    pytest.param((2, 4, 202, 64), jnp.float32, None, id="full_kv_f32_w202"),
+    pytest.param((2, 2, 256, 128), jnp.bfloat16, None, id="full_kv_bf16"),
+    pytest.param((8, 2, 1225, 128), jnp.bfloat16, 202, id="banded_1225"),
+    pytest.param((2, 2, 6046, 128), jnp.bfloat16, 202,
+                 id="streaming_full_episode"),
+    pytest.param((2, 8, 8192, 128), jnp.bfloat16, 202, id="streaming_8k"),
+    pytest.param((1, 2, 32969, 128), jnp.float32, 202,
+                 id="streaming_32k_f32"),
+]
+
+
+@pytest.mark.parametrize("shape,dtype,window", ATTENTION_SHAPES)
+def test_attention_fwd_bwd_compiles_for_v5e(one_chip, tpu_backend, shape,
+                                            dtype, window):
+    from sharetrade_tpu.ops.attention import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, local_window=window,
+                              use_pallas=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        x, x, x).compile()
+    # Forward + dQ + dK/dV (the value_and_grad forward is the residual-
+    # saving one; XLA drops the unused plain forward).
+    assert _mosaic_calls(compiled) >= 3
+
+
+@pytest.mark.parametrize("optimizer", ["adagrad", "adam", "sgd"])
+def test_fused_update_compiles_for_v5e(one_chip, optimizer):
+    from sharetrade_tpu.agents.base import build_optimizer
+    from sharetrade_tpu.config import LearnerConfig
+    from sharetrade_tpu.ops.fused_update import fused_apply
+
+    params = {"w": jnp.zeros((1024, 4096), jnp.float32)}
+    state = jax.eval_shape(
+        build_optimizer(LearnerConfig(optimizer=optimizer)).init, params)
+    grads = {"w": jax.ShapeDtypeStruct((1024, 4096), jnp.bfloat16)}
+
+    def update(g, s, p):
+        return fused_apply(optimizer, 0.01, g, s, p,
+                           compute_dtype=jnp.bfloat16, emit_compute=True,
+                           use_pallas=True)
+
+    compiled = jax.jit(update).lower(
+        _on(one_chip, grads), _on(one_chip, state),
+        _on(one_chip, params)).compile()
+    assert _mosaic_calls(compiled) == 1
+
+
+def test_fused_update_compiles_under_tp_specs_for_v5e(chip_compile):
+    """On a dp x tp mesh each leaf's kernel runs under a shard_map with the
+    leaf's own Megatron spec: every device updates the shard it holds."""
+    from sharetrade_tpu.agents.base import build_optimizer
+    from sharetrade_tpu.config import LearnerConfig
+    from sharetrade_tpu.ops.fused_update import fused_apply
+    from sharetrade_tpu.parallel.sharding import (mlp_tp_rules,
+                                                  param_shardings)
+    mesh = Mesh(np.array(chip_compile.devices).reshape(2, 2), ("dp", "tp"))
+    rules = mlp_tp_rules()
+    params = {"qkv": {"w": jnp.zeros((1024, 3072), jnp.float32)},
+              "proj": {"w": jnp.zeros((1024, 1024), jnp.float32)}}
+    shardings = param_shardings(params, mesh, rules)
+    assert {s.spec for s in jax.tree.leaves(shardings)} == {
+        P(None, "tp"), P("tp", None)}
+    state = jax.eval_shape(
+        build_optimizer(LearnerConfig(optimizer="adam")).init, params)
+    grads = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16), params)
+
+    def update(g, s, p):
+        return fused_apply("adam", 0.01, g, s, p, compute_dtype=jnp.bfloat16,
+                           use_pallas=True, mesh=mesh, param_rules=rules)
+
+    compiled = jax.jit(update).lower(
+        _on(shardings, grads), state, _on(shardings, params)).compile()
+    assert _mosaic_calls(compiled) == 2
+    assert "all-gather" not in compiled.as_text()   # no leaf is regathered
+
+
+# -- whole programs at the widest supported model ---------------------------
+# Config and agent are chip_smoke.py's own (``ppo_tr_episode_large_d1024``,
+# horizon of 3 chunks), so what compiles here is what its train_wide phase
+# runs on the chip.
+
+def _wide_config():
+    import chip_smoke
+    return chip_smoke.wide_config(seed=0)
+
+
+def _wide_agent(cfg, mesh=None):
+    import chip_smoke
+    return chip_smoke.wide_agent(cfg, mesh=mesh)
+
+
+def test_agent_step_compiles_for_one_v5e(one_chip, tpu_backend):
+    agent = _wide_agent(_wide_config())
+    ts = jax.eval_shape(agent.init, jax.random.PRNGKey(0))
+    compiled = jax.jit(agent.step, donate_argnums=(0,)).lower(
+        _on(one_chip, ts)).compile()
+    assert _mosaic_calls(compiled) > 0
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 1024 ** 3)       # one v5e chip's HBM
+
+
+def _window_config():
+    """A window-mode transformer PPO (the other model file that calls the
+    flash kernel), small: its dp=4 step must keep the batch dp-sharded
+    through the kernel's shard_map."""
+    cfg = _wide_config()
+    cfg.model.seq_mode = "window"
+    cfg.model.num_layers, cfg.model.num_heads, cfg.model.head_dim = 2, 4, 64
+    cfg.parallel.num_workers = 16
+    cfg.runtime.chunk_steps = cfg.learner.unroll_len = 32
+    return cfg
+
+
+@pytest.mark.parametrize("mesh_shape,make_cfg", [
+    pytest.param({"dp": 4}, _wide_config, id="wide_dp4"),
+    pytest.param({"dp": 4}, _window_config, id="window_dp4"),
+])
+def test_agent_step_compiles_for_v5e_mesh(chip_compile, tpu_backend,
+                                          mesh_shape, make_cfg):
+    """``cli train --mesh``: a bare ``pallas_call`` inside the partitioned
+    program is refused ("Mosaic kernels cannot be automatically
+    partitioned" — wide_dp4 failed so before PR 21), so both kernel call
+    sites run under a shard_map on a multi-device mesh — attention over the
+    batch axis, the fused update with each leaf's own spec — and stay
+    kernels. (wide on dp2 x tp2 also compiles, 18 s: ROADMAP S7.)"""
+    from sharetrade_tpu.parallel.sharding import (jit_parallel_step,
+                                                  mesh_param_rules)
+    mesh = Mesh(np.array(chip_compile.devices).reshape(
+        tuple(mesh_shape.values())), tuple(mesh_shape))
+    agent = _wide_agent(make_cfg(), mesh=mesh)
+    ts = jax.eval_shape(agent.init, jax.random.PRNGKey(0))
+    shardings, step = jit_parallel_step(
+        agent, mesh, ts, param_rules=mesh_param_rules(mesh))
+    compiled = step.lower(_on(shardings, ts)).compile()
+    assert _mosaic_calls(compiled) > 0
+    assert " all-reduce" in compiled.as_text()      # the dp gradient mean
+
+
+@pytest.fixture(scope="module")
+def wide_engine():
+    """A ``ServeEngine`` over the widest model with train_wide's model keys
+    (bf16_mixed carries), built on the host; its four device programs are
+    what ``test_serve_program_compiles_for_v5e`` lowers."""
+    from sharetrade_tpu.precision import policy_from_config
+    from sharetrade_tpu.serve import ServeEngine
+    cfg = _wide_config()
+    cfg.serve.max_batch, cfg.serve.slots = 8, 16
+    cfg.serve.warm_bytes = 1 << 30
+    agent = _wide_agent(cfg)
+    params = agent.init(jax.random.PRNGKey(0)).params
+    engine = ServeEngine(agent.model, cfg.serve, params,
+                         precision=policy_from_config(cfg.precision))
+    yield engine, cfg
+    engine.stop(drain=False)
+
+
+@pytest.mark.parametrize("program", ["warm", "cold", "park", "install"])
+def test_serve_program_compiles_for_v5e(one_chip, tpu_backend, wide_engine,
+                                        program):
+    engine, cfg = wide_engine
+    batch = cfg.serve.max_batch
+    params = _on(one_chip, engine._live.params)
+    pool = _on(one_chip, engine._pool)
+    obs = jax.ShapeDtypeStruct((batch, cfg.env.window + 2), jnp.float32,
+                               sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    rows = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        (batch,) + x.shape[1:], x.dtype, sharding=one_chip), pool)
+    fn, args = {
+        "warm": (jax.jit(engine._warm_program, donate_argnums=(1,)),
+                 (params, pool, obs, idx)),
+        "cold": (jax.jit(engine._cold_program, donate_argnums=(1,)),
+                 (params, pool, obs, idx)),
+        "park": (jax.jit(engine._park_program), (pool, idx)),
+        "install": (jax.jit(engine._install_program, donate_argnums=(0,)),
+                    (pool, rows, idx)),
+    }[program]
+    compiled = fn.lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16 * 1024 ** 3)
+    if program == "cold":
+        # The batched prefill attends L*(window-1)+1 rows per session
+        # through the local flash kernel.
+        assert _mosaic_calls(compiled) > 0

@@ -116,6 +116,9 @@ class TestEndToEndGolden:
         assert np.isfinite(result["avg_portfolio"])
         assert result["avg_portfolio"] > 0
         assert result["restarts"] == 0
+        # Every summary names the device its numbers belong to.
+        assert result["device"] == {"platform": "cpu", "device_kind": "cpu",
+                                    "count": 8}
 
     def test_resume_completes_consistently(self, tmp_path, capsys):
         """Train to completion, then --resume from the final checkpoint:

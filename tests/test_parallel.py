@@ -618,3 +618,89 @@ class TestEpisodeSequenceParallel:
         orch.start_training(background=False)
         assert orch.is_everything_done().state is ReplyState.COMPLETED
         assert orch.get_avg().ok and np.isfinite(orch.get_avg().value)
+
+
+# ---------------------------------------------------------------------------
+# Kernels inside a partitioned program (PR 21): a bare pallas_call cannot be
+# partitioned by the compiler, so on a multi-device mesh both kernel call
+# sites run per device under a shard_map. The TPU side of that is compiled by
+# tests/test_chip_compile.py; here the SAME wraps run the interpreted kernels
+# on the virtual CPU mesh, so their numerics are pinned.
+# ---------------------------------------------------------------------------
+
+class TestKernelsUnderShardMap:
+    @pytest.mark.parametrize("batch", [8, 1],
+                             ids=["batch_split_over_dp", "batch_of_one_replicated"])
+    def test_flash_attention_on_mesh_matches_reference(self, cpu_devices,
+                                                       batch):
+        from jax.sharding import Mesh
+        from sharetrade_tpu.ops.attention import (flash_attention,
+                                                  reference_attention)
+        mesh = Mesh(np.array(cpu_devices[:4]).reshape(4), ("dp",))
+        keys = jax.random.split(jax.random.PRNGKey(0), 3)
+        q, k, v = (jax.random.normal(kk, (batch, 2, 160, 16)) for kk in keys)
+
+        def on_mesh(q, k, v):
+            return flash_attention(q, k, v, local_window=33, use_pallas=True,
+                                   mesh=mesh, batch_axis="dp")
+
+        def ref(q, k, v):
+            return reference_attention(q, k, v, local_window=33)
+
+        np.testing.assert_allclose(jax.jit(on_mesh)(q, k, v), ref(q, k, v),
+                                   atol=2e-5)
+        grads = jax.jit(jax.grad(
+            lambda *a: jnp.sum(on_mesh(*a) ** 2), argnums=(0, 1, 2)))(q, k, v)
+        want = jax.grad(
+            lambda *a: jnp.sum(ref(*a) ** 2), argnums=(0, 1, 2))(q, k, v)
+        for got, exp in zip(grads, want):
+            np.testing.assert_allclose(got, exp, atol=1e-4)
+
+    @pytest.mark.parametrize("optimizer", ["adagrad", "adam", "sgd"])
+    def test_fused_update_on_mesh_is_bitwise_the_single_device_update(
+            self, cpu_devices, optimizer):
+        """Each leaf's kernel runs under a shard_map with that leaf's own
+        spec (tp column/row rules here): elementwise, so every device
+        updates exactly its shard — bit for bit the unpartitioned result."""
+        from jax.sharding import Mesh, PartitionSpec as P
+        from sharetrade_tpu.agents.base import build_optimizer
+        from sharetrade_tpu.config import LearnerConfig
+        from sharetrade_tpu.ops.fused_update import fused_apply
+        mesh = Mesh(np.array(cpu_devices[:4]).reshape(2, 2), ("dp", "tp"))
+        rules = {"layer1/w": P(None, "tp"), "layer2/w": P("tp", None)}
+        k1, k2 = jax.random.split(jax.random.PRNGKey(1))
+        params = {"layer1": {"w": jax.random.normal(k1, (256, 64)),
+                             "b": jnp.zeros((64,))},
+                  "layer2": {"w": jax.random.normal(k2, (64, 256))}}
+        grads = jax.tree.map(lambda x: (x * 0.1).astype(jnp.bfloat16), params)
+        state = build_optimizer(LearnerConfig(optimizer=optimizer)).init(params)
+
+        def run(**kw):
+            return jax.jit(lambda g, s, p: fused_apply(
+                optimizer, 0.01, g, s, p, interpret=True, **kw))(
+                    grads, state, params)
+
+        for got, exp in zip(jax.tree.leaves(run(mesh=mesh, param_rules=rules)),
+                            jax.tree.leaves(run())):
+            np.testing.assert_array_equal(got, exp)
+
+    def test_cpu_mesh_keeps_the_xla_paths(self, cpu_mesh):
+        """The virtual-CPU mesh cannot lower Mosaic: build_model turns the
+        attention kernel off there and the update seam follows, so
+        ``cli train --mesh`` on the CPU backend is unchanged."""
+        from sharetrade_tpu.agents import build_agent
+        from sharetrade_tpu.config import FrameworkConfig
+        from sharetrade_tpu.env import trading
+        cfg = FrameworkConfig()
+        cfg.learner.algo, cfg.model.kind = "ppo", "transformer"
+        cfg.model.seq_mode, cfg.precision.mode = "episode", "bf16_mixed"
+        cfg.model.num_layers = cfg.model.num_heads = 2
+        cfg.model.head_dim, cfg.env.window = 8, 8
+        cfg.parallel.num_workers = 8
+        cfg.runtime.chunk_steps = cfg.learner.unroll_len = 8
+        env = trading.env_from_prices(jnp.linspace(10.0, 20.0, 64),
+                                      window=cfg.env.window)
+        agent = build_agent(cfg, env, mesh=cpu_mesh)
+        ts = agent.init(jax.random.PRNGKey(0))
+        text = jax.jit(agent.step).lower(ts).as_text()
+        assert "shard_map" not in text and "pallas" not in text.lower()

@@ -83,7 +83,10 @@ def test_golden_cost_capture_reference_mlp(tmp_path):
     bundle = read_roofline(cfg.obs.dir)
     assert bundle is not None
     assert bundle["schema_version"] == 1
-    assert bundle["ridge_flops_per_byte"] > 0
+    # The CPU has no published peak: nothing relative to one is recorded
+    # ("not measured"), never a figure relative to some other chip.
+    assert bundle["peak_flops_per_s"] is None
+    assert bundle["ridge_flops_per_byte"] is None
     chunk = bundle["programs"]["chunk"]
     assert chunk["flops"] > 0
     assert chunk["bytes_accessed"] > 0
@@ -103,7 +106,7 @@ def test_golden_cost_capture_reference_mlp(tmp_path):
     # Agreement keeps the measured XLA count as the gauge source.
     assert chunk["gauge_flops_source"] == "xla"
     assert chunk["gauge_flops"] == chunk["flops"]
-    assert chunk["classification"] in ("compute-bound", "memory-bound")
+    assert chunk["classification"] is None      # no ridge on this device
     assert chunk["arithmetic_intensity"] == pytest.approx(
         chunk["flops"] / chunk["bytes_accessed"])
 
@@ -124,17 +127,21 @@ def test_megachunk_program_captured(tmp_path):
 
 
 def test_gauges_reach_prometheus_textfile(tmp_path):
-    """Acceptance: mfu/achieved_tflops/hbm_gbps exported via the existing
-    Prometheus textfile during a CPU training run with obs.roofline."""
+    """Acceptance: achieved_tflops/hbm_gbps exported via the existing
+    Prometheus textfile during a CPU training run with obs.roofline — and
+    the figures that need a published peak (mfu, the bound
+    classification) are NOT: the CPU has none, so they are "not
+    measured" rather than stated against another chip's peak."""
     cfg = _cfg(tmp_path, megachunk=2)
     orch = _train(cfg)
     prom = open(os.path.join(cfg.obs.dir, "metrics.prom")).read()
-    for gauge in ("sharetrade_mfu", "sharetrade_achieved_tflops",
-                  "sharetrade_hbm_gbps", "sharetrade_arithmetic_intensity",
-                  "sharetrade_roofline_compute_bound"):
+    for gauge in ("sharetrade_achieved_tflops", "sharetrade_hbm_gbps",
+                  "sharetrade_arithmetic_intensity"):
         assert f"# TYPE {gauge} gauge" in prom, f"{gauge} missing"
+    for gauge in ("sharetrade_mfu", "sharetrade_roofline_compute_bound"):
+        assert gauge not in prom, f"{gauge} stated without a known peak"
     # And they are live numbers, not placeholders.
-    assert orch.metrics.latest("mfu") > 0
+    assert orch.metrics.latest("mfu") is None
     assert orch.metrics.latest("achieved_tflops") > 0
     assert orch.metrics.latest("hbm_gbps") > 0
 
@@ -263,8 +270,9 @@ def test_cli_obs_summarizes_roofline_and_counters(tmp_path, capsys):
     assert "roofline" in summary
     roof = summary["roofline"]
     assert roof["programs"] == 2
-    named = [p["program"]
-             for p in roof["compute_bound"] + roof["memory_bound"]]
+    # No published peak for the CPU: the programs are listed, unclassified.
+    assert roof["compute_bound"] == roof["memory_bound"] == []
+    named = [p["program"] for p in roof["unclassified"]]
     assert set(named) == {"chunk", "megachunk_k2"}
     # Counter totals surfaced (the cli-obs satellite): totals dict plus
     # the explicit pipeline health number.
@@ -351,7 +359,7 @@ def test_perf_gate_legacy_fallback_parser(tmp_path):
             {"metric": "m", "value": 195.0}) + "\n"}))
     # Error round with a cpu_fallback subtree (r05 shape).
     (tmp_path / "BENCH_r03.json").write_text(json.dumps({
-        "n": 3, "parsed": {"error": "tunnel down", "cpu_fallback": {
+        "n": 3, "parsed": {"error": "no device", "cpu_fallback": {
             "metric": "m", "value": 50.0, "backend": "cpu"}}}))
     snap1 = perf_gate.parse_bench_file(str(tmp_path / "BENCH_r01.json"))
     assert snap1["rows"] == [{"metric": "m", "value": 200.0,
@@ -398,3 +406,40 @@ def test_shard_audit_manifest_has_roofline_rows():
         assert cost, f"{name} missing roofline cost row"
         assert cost.get("flops", 0) > 0, f"{name} flops not recorded"
         assert cost.get("hbm_peak_bytes", 0) > 0
+
+
+# ---------------------------------------------------------------------------
+# peaks: a device that is not in the table is an error, not a v5e default
+# ---------------------------------------------------------------------------
+
+def test_unknown_device_kind_raises_known_kind_resolves():
+    from types import SimpleNamespace
+
+    from sharetrade_tpu.utils.flops import (UnknownDeviceKind,
+                                            chip_peak_flops,
+                                            chip_peak_hbm_bw)
+
+    v5e = SimpleNamespace(device_kind="TPU v5 lite")
+    assert chip_peak_flops(v5e) == 197e12
+    assert chip_peak_hbm_bw(v5e) == 819e9
+    for fn in (chip_peak_flops, chip_peak_hbm_bw):
+        with pytest.raises(UnknownDeviceKind, match="cpu"):
+            fn(jax.devices("cpu")[0])
+        with pytest.raises(UnknownDeviceKind, match="TPU v9"):
+            fn(SimpleNamespace(device_kind="TPU v9"))
+
+
+def test_known_peaks_still_publish_utilisation():
+    """With the chip's peaks known (passed here; looked up by device_kind
+    on a TPU) the peak-relative gauges are live: mfu and the bound."""
+    reg = MetricsRegistry()
+    cap = RooflineCapture(reg, None, peak_flops=1e12, peak_hbm_bw=1e9)
+    cap._trip_blind = False
+    costs = {"flops": 4e9, "bytes_accessed": 1e6, "argument_bytes": None,
+             "temp_bytes": None, "output_bytes": None}
+    cost = cap._build_cost("chunk", 1, costs)
+    assert cost.classification == "compute-bound"      # AI 4000 >= ridge 1000
+    cap._by_factor[1] = cost
+    cap.on_boundary(k=1, chunk_seconds=0.1)
+    assert reg.latest("mfu") == pytest.approx(4e10 / 1e12)
+    assert reg.latest("roofline_compute_bound") == 1.0

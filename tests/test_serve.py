@@ -619,6 +619,7 @@ def test_cli_serve_sigterm_drains_and_exits_75(tmp_path):
     try:
         ready = json.loads(proc.stdout.readline())
         assert ready["event"] == "serving_ready"
+        assert ready["device"]["platform"] == "cpu"
         time.sleep(1.0)                       # let traffic flow
         proc.send_signal(signal.SIGTERM)
         out, _ = proc.communicate(timeout=90)
@@ -630,5 +631,6 @@ def test_cli_serve_sigterm_drains_and_exits_75(tmp_path):
     assert summary["preempted"] is True
     assert summary["drained"] is True
     assert summary["completed"] > 0
+    assert summary["device"] == ready["device"]
     # Metrics were flushed on the way out.
     assert os.path.isfile(os.path.join(run_dir, "metrics.prom"))
