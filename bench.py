@@ -605,7 +605,7 @@ def bench_async_pipeline(factors: tuple[int, ...] = (1, 8), *,
 
     - ``agent_steps_per_sec`` — end-to-end throughput (median of trials);
     - ``gap_p50_us``/``gap_p99_us`` — the INTER-DISPATCH GAP, measured from
-      the obs trace's ``dispatch`` spans (end of span N to start of span
+      the obs trace's ``train/dispatch`` spans (end of span N to start of span
       N+1, pooled across trials). The sync path's gap contains the batched
       ``device_get`` plus the whole host_process block; the pipeline's gap
       is the enqueue cost, so its p50 must sit strictly below the sync
@@ -627,7 +627,7 @@ def bench_async_pipeline(factors: tuple[int, ...] = (1, 8), *,
             return []
         return sorted(
             (e for e in read_trace(trace_path)
-             if e.get("ph") == "X" and e.get("name") == "dispatch"),
+             if e.get("ph") == "X" and e.get("name") == "train/dispatch"),
             key=lambda e: e["ts"])
 
     def gaps_us(spans: list[dict]) -> list[float]:
@@ -735,11 +735,11 @@ def bench_obs_sample_cost(samples: int = 20000) -> dict:
         row = {f"m{i}": float(i) for i in range(14)}
         t0 = time.perf_counter()
         for i in range(samples):
-            with obs.span("dispatch", chunk=i, k=1):
+            with obs.span("train/dispatch", chunk=i, k=1):
                 pass
-            with obs.span("readback", chunk=i, k=1):
+            with obs.span("train/readback", chunk=i, k=1):
                 pass
-            with obs.span("host_process", chunk=i, k=1):
+            with obs.span("train/host_process", chunk=i, k=1):
                 pass
             obs.record("chunk_metrics", chunk=i, **row)
         per_sample_us = (time.perf_counter() - t0) / samples * 1e6

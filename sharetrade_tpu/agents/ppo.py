@@ -138,11 +138,15 @@ def make_ppo_agent(model: Model, env: TradingEnv,
 
             def mb_body(carry, mb_idx):
                 params, opt_state = carry
-                idx = jax.lax.dynamic_slice_in_dim(
-                    perm, mb_idx * mb_size, mb_size)
-                traj_mb = jax.tree.map(lambda x: x[:, idx], traj)
-                carry_mb = jax.tree.map(lambda x: x[idx], init_carry)
-                adv_mb, ret_mb = advantages[:, idx], returns[:, idx]
+                # The named scopes reach a profiler trace as the events'
+                # scope (an operator reading runtime.profile_dir in XProf
+                # sees the chunk's phases).
+                with jax.named_scope("minibatch_gather"):
+                    idx = jax.lax.dynamic_slice_in_dim(
+                        perm, mb_idx * mb_size, mb_size)
+                    traj_mb = jax.tree.map(lambda x: x[:, idx], traj)
+                    carry_mb = jax.tree.map(lambda x: x[idx], init_carry)
+                    adv_mb, ret_mb = advantages[:, idx], returns[:, idx]
                 if seam_mesh is not None:
                     # Pin the GATHERED slices replicated as well: GSPMD
                     # otherwise re-derives a dp layout for the tiny
@@ -159,11 +163,20 @@ def make_ppo_agent(model: Model, env: TradingEnv,
                 # Differentiate against the compute copy of the CURRENT
                 # masters (re-cast per minibatch — the masters just moved);
                 # the update itself applies in f32 to the masters.
+                # No scope around the replay: one that encloses a
+                # pallas_call enters its name stack, and XLA names the
+                # kernel's trace event after the stack's last entry
+                # (``%jvp__``, ``%transpose_jvp___``: the names the
+                # benchmark's attention_roofline matches). Under
+                # named_scope("replay") the backward kernels come out as
+                # ``%jvp__`` too (described-chip compile, PR 24).
                 (loss, aux), grads = jax.value_and_grad(
                     minibatch_loss, has_aux=True)(
                     precision.cast_compute(params), traj_mb, carry_mb,
                     adv_mb, ret_mb)
-                params, opt_state = apply_update(grads, opt_state, params)
+                with jax.named_scope("update"):
+                    params, opt_state = apply_update(grads, opt_state,
+                                                     params)
                 return (params, opt_state), (loss, *aux)
 
             (params, opt_state), losses = jax.lax.scan(

@@ -106,6 +106,8 @@ def collect_rollout(model: Model, env: TradingEnv,
                         value=outs.value, reward=rewards, active=active)
         return (new_env, new_model_carry, rng), data
 
+    # Not under a named scope: this scan's body runs the model, and a scope
+    # that encloses a pallas_call renames the kernel's device-trace event.
     (env_state, model_carry, rng), traj = jax.lax.scan(
         one_step, (ts.env_state, ts.carry, ts.rng), None, length=unroll_len)
 
@@ -206,7 +208,9 @@ def _collect_rollout_precomputed(model: Model, env: TradingEnv,
     # carry, the broadcast NaN trunk makes the chunk's loss non-finite and
     # the orchestrator's detector escalates to restore — correct when the
     # whole batch is beyond a row-level heal.
-    rep = jnp.argmax(election_health(ts.env_state, ts.carry)).astype(jnp.int32)
+    with jax.named_scope("rows_finite"):
+        rep = jnp.argmax(
+            election_health(ts.env_state, ts.carry)).astype(jnp.int32)
     take_rep = lambda x: jax.lax.dynamic_index_in_dim(x, rep, 0,
                                                       keepdims=True)
     state1 = jax.tree.map(take_rep, ts.env_state)
@@ -287,9 +291,10 @@ def _collect_rollout_precomputed(model: Model, env: TradingEnv,
                         value=value, reward=rewards, active=active)
         return new_env, data
 
-    env_state, traj = jax.lax.scan(
-        one_step, ts.env_state,
-        (windows[:-1], trade_prices, gumbel, head_xs))
+    with jax.named_scope("rollout_scan"):
+        env_state, traj = jax.lax.scan(
+            one_step, ts.env_state,
+            (windows[:-1], trade_prices, gumbel, head_xs))
 
     final_raw = jax.vmap(env.observe)(env_state)
     final_fine = quarantine_mask(final_raw, env_state)

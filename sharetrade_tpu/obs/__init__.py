@@ -4,14 +4,27 @@ Three persistent surfaces over the existing in-memory primitives, all
 gated by ``ObsConfig`` (everything off by default — zero files, near-zero
 hot-loop cost when disabled):
 
-- :mod:`trace` — host span tracer → ``trace.jsonl`` (Chrome trace events;
-  open in Perfetto / chrome://tracing). Under ``runtime.async_pipeline``
-  the timeline splits across threads: ``dispatch`` spans stay on the
-  dispatcher tid while ``readback``/``host_process`` move to the consumer
-  tid, joined by ``queue_wait`` (consumer starved — healthy) and
-  ``pipeline_stall`` (dispatcher blocked on the bounded queue — host-bound)
-  spans, with ``pipeline_stalls_total``/``pipeline_queue_depth`` in the
-  metrics export;
+- :mod:`trace` — the ONE host-span path (``host_span``): every span is a
+  ``jax.profiler.TraceAnnotation`` (on the ``/host:CPU`` plane and the
+  clock of whatever profiler session runs; inert otherwise) and, with
+  ``obs.trace`` on, the same call writes the Chrome event to
+  ``trace.jsonl`` (open in Perfetto / chrome://tracing). Fixed names, the
+  chunk or tick serial as an identifier, per chunk or per tick only:
+  ``train/dispatch`` and ``train/pipeline_stall`` (dispatcher blocked on
+  the bounded queue) on the dispatcher tid; ``train/queue_wait`` (consumer
+  starved — healthy), ``train/readback`` and ``train/host_process`` on
+  the consumer tid; ``serve/collect_batch``, ``serve/dispatch_tick``,
+  ``serve/done_wait`` on the engine's dispatcher, ``serve/complete_batch``
+  with its ``serve/readback`` children on its consumer (the engine emits
+  them with or without an ``Obs`` bundle). Beside them, from the same
+  stamps, the stage histograms ``train_dispatch_call_ms`` /
+  ``train_pipeline_stall_ms`` / ``train_host_process_ms`` (obs-gated) and
+  ``serve_tick_host_ms`` / ``serve_done_wait_ms`` /
+  ``serve_complete_host_ms`` / ``serve_inflight_ticks`` (always on), with
+  ``pipeline_stalls_total``/``pipeline_queue_depth`` in the metrics
+  export. ``trace.jsonl`` opens with a ``clock`` event (epoch ns beside
+  ``perf_counter``): event start = ``epoch_ns + ts * 1000``, which lays
+  the file over a profiler trace (README "Observability");
 - :mod:`exporter` — background drain of :class:`MetricsRegistry` →
   ``metrics.jsonl`` + Prometheus textfile ``metrics.prom``;
 - :mod:`flight` — bounded ring of recent chunk metrics / lifecycle /
@@ -23,8 +36,9 @@ hot-loop cost when disabled):
   gauges + schema-versioned ``roofline.json`` (``obs.roofline`` knob).
 
 The :class:`Obs` facade is what the orchestrator holds; a disabled instance
-is inert (``span()`` hands back a shared null context, ``record()`` returns
-immediately) so the hot loop never branches on more than ``obs.enabled``.
+is inert (``span()`` hands back the bare, inactive profiler annotation,
+``record()`` returns immediately) so the hot loop never branches on more
+than ``obs.enabled``.
 """
 
 from __future__ import annotations

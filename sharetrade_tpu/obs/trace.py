@@ -1,28 +1,41 @@
-"""Host-side span tracer emitting Chrome trace-event JSON.
+"""Host spans: ONE entry, on the profiler's clock and in ``trace.jsonl``.
 
-``jax.profiler`` device traces (utils/profiling.py Tracer) need TensorBoard/
-XProf to read their XPlane protos; this tracer is the complementary HOST
-timeline: orchestrator phases (dispatch, readback, host processing,
-checkpoint IO, supervision recovery) written as Chrome trace events that
-Perfetto (https://ui.perfetto.dev) or chrome://tracing load directly, no
-profiler runtime required.
+:func:`host_span` is the single way this package opens a host span. It
+always opens a ``jax.profiler.TraceAnnotation(name, **ids)``: while a
+profiler session runs (``runtime.profile_dir``, or whoever called
+``jax.profiler.start_trace``) the span lands in the trace's ``/host:CPU``
+plane, on that session's clock, beside the device operations; with no
+session the annotation is inert (no name is encoded, nothing is stored).
+Given an enabled :class:`SpanTracer` it ALSO writes the Chrome trace event
+to ``trace.jsonl`` (Perfetto / chrome://tracing load it directly, no
+profiler runtime required). ``SpanTracer.span`` / ``Obs.span`` are that
+entry with the run's tracer; :func:`span` is it with none, for a caller
+that holds no ``Obs`` bundle (``ServeEngine(obs=None)``). Names are fixed
+strings; a chunk or tick serial is an argument, never part of the name, so
+every consumer can aggregate by name. ``tools/lint_hot_loop.py`` (check 20)
+keeps ``TraceAnnotation`` out of every other module, so no per-request or
+per-agent-step annotation can slip in beside this helper.
 
-File format: the JSON Array Format of the Trace Event spec — an opening
-``[`` then one ``{event},`` per line. The spec makes the closing ``]``
-optional precisely so crashed writers still leave a loadable trace, which is
-also what makes the file greppable/tail-able like JSONL: every event is one
-self-contained line. Events are buffered and flushed every
+``trace.jsonl``: the JSON Array Format of the Trace Event spec — an
+opening ``[`` then one ``{event},`` per line. The spec makes the closing
+``]`` optional precisely so crashed writers still leave a loadable trace,
+which is also what makes the file greppable/tail-able like JSONL: every
+event is one self-contained line. Events are buffered and flushed every
 ``flush_every`` records (and on close), so the hot loop pays a dict+append,
-not a syscall, per span.
+not a syscall, per span. Timestamps are microseconds on ``perf_counter``
+from tracer construction; the file's FIRST event (``clock``) pairs that
+origin with the epoch (:func:`clock_pair`): event start = ``epoch_ns + ts
+* 1000``. An ``.xplane.pb`` read through ``ProfileData`` counts nanoseconds
+from its session's start instead; every span here is in both files, so any
+one of them gives the offset between the two.
 
-``SpanTracer(None)`` is the disabled instance: ``span()`` returns a shared
-null context and nothing is ever opened or written (the obs.enabled=false
-contract — zero files, near-zero cost).
+``SpanTracer(None)`` is the disabled instance: nothing is ever opened or
+written (the obs.enabled=false contract — zero files); its ``span()``
+still hands back the bare annotation.
 """
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import json
 import os
@@ -31,31 +44,73 @@ import time
 from collections import deque
 from typing import Any
 
-_NULL_CTX = contextlib.nullcontext()
+_TraceAnnotation = None
+
+
+def span(name: str, **ids: Any):
+    """:func:`host_span` with no tracer: the profiler annotation alone."""
+    # Imported on first use: the fleet's router and supervisor processes
+    # import this module and must stay off JAX.
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **ids)
+
+
+def clock_pair(samples: int = 5) -> tuple[float, float]:
+    """``(time.time(), time.perf_counter())`` read together: the tightest
+    of several samples, so the pairing error is bounded by the narrowest
+    observed sampling window."""
+    best = None
+    for _ in range(samples):
+        a = time.perf_counter()
+        epoch = time.time()
+        b = time.perf_counter()
+        if best is None or (b - a) < best[2]:
+            best = (epoch, (a + b) / 2.0, b - a)
+    return best[0], best[1]
 
 
 class _Span:
-    """One in-flight span; emits a complete ("ph": "X") event on exit."""
+    """One in-flight span of an enabled tracer: the profiler annotation,
+    and a complete ("ph": "X") ``trace.jsonl`` event on exit."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "SpanTracer", name: str, args: dict):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._ann = span(name, **args)
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
+    def set_metadata(self, **ids: Any) -> None:
+        """Identifiers known only once the phase is under way (the bare
+        annotation has the same method)."""
+        self._ann.set_metadata(**ids)
+        self._args = {**self._args, **ids}
+
     def __exit__(self, *exc) -> None:
         t1 = self._tracer._now_us()
+        self._ann.__exit__(*exc)
         self._tracer._emit({
             "name": self._name, "ph": "X", "ts": self._t0,
             "dur": t1 - self._t0, "pid": self._tracer._pid,
             "tid": threading.get_ident(),
             **({"args": self._args} if self._args else {}),
         })
+
+
+def host_span(name: str, tracer: "SpanTracer | None" = None, **ids: Any):
+    """Context manager for one named host phase (module docstring)."""
+    if tracer is None or tracer._fh is None:
+        return span(name, **ids)
+    return _Span(tracer, name, ids)
 
 
 class SpanTracer:
@@ -66,13 +121,17 @@ class SpanTracer:
         self._buf: list[str] = []
         self._pid = os.getpid()
         # Trace timestamps are microseconds on the perf_counter clock from
-        # tracer construction (Perfetto only needs them monotone/relative);
-        # wall-clock anchoring lives in the run manifest.
-        self._t0 = time.perf_counter()
+        # tracer construction; the leading clock event anchors ts=0 to the
+        # epoch (a profiler trace's clock).
+        epoch, self._t0 = clock_pair()
         self._fh = None
         if path:
             self._fh = open(path, "w", encoding="utf-8")
-            self._fh.write("[\n")
+            self._fh.write("[\n" + json.dumps({
+                "name": "clock", "ph": "i", "ts": 0.0, "s": "p",
+                "pid": self._pid, "tid": threading.get_ident(),
+                "args": {"epoch_ns": int(epoch * 1e9),
+                         "perf_counter": self._t0}}) + ",\n")
 
     @property
     def enabled(self) -> bool:
@@ -82,10 +141,8 @@ class SpanTracer:
         return (time.perf_counter() - self._t0) * 1e6
 
     def span(self, name: str, **args: Any):
-        """Context manager timing one named phase; no-op when disabled."""
-        if self._fh is None:
-            return _NULL_CTX
-        return _Span(self, name, args)
+        """:func:`host_span` with this tracer."""
+        return host_span(name, self, **args)
 
     def instant(self, name: str, **args: Any) -> None:
         """Zero-duration marker (lifecycle transitions, dumps, restarts)."""
@@ -188,14 +245,7 @@ class SpanJournal:
         os.makedirs(directory, exist_ok=True)
         self.path = os.path.join(directory,
                                  f"spans-{proc}-{self.pid}.journal")
-        best = None
-        for _ in range(5):
-            a = time.perf_counter()
-            epoch = time.time()
-            b = time.perf_counter()
-            if best is None or (b - a) < best[2]:
-                best = (epoch, (a + b) / 2.0, b - a)
-        self.epoch, self.mono = best[0], best[1]
+        self.epoch, self.mono = clock_pair()
         self._clock_line = json.dumps(
             {"clock": 1, "proc": proc, "pid": self.pid,
              "epoch": self.epoch, "mono": self.mono},

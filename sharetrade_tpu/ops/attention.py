@@ -37,6 +37,12 @@ LANE = 128
 
 _NEG_INF = -1e30
 
+# Kernel identities (``pallas_call(metadata=...)``): on TPU the custom call
+# carries them as ``frontend_attributes={kernel_metadata=...}``, the text a
+# device trace prints as the event's name. NOT ``name=``: that enters the
+# name stack and renames the instruction itself.
+KERNEL_FWD, KERNEL_DQ, KERNEL_DKDV = "flash_fwd", "flash_dq", "flash_dkdv"
+
 
 def _dot(a, b):
     """MXU matmul with f32 accumulation. For bf16 operands the precision is
@@ -229,6 +235,7 @@ def _flash_forward(q, k, v, causal, sm_scale, local_window, interpret):
             jax.ShapeDtypeStruct((bh, 8, t_pad), jnp.float32),
         ),
         interpret=interpret,
+        metadata={"kernel": KERNEL_FWD},
     )(qp, kp, vp)
 
     out = out.reshape(batch, heads, t_pad, d_pad)[:, :, :seq_len, :head_dim]
@@ -370,6 +377,7 @@ def _banded_forward(qp, kp, vp, d_pad, seq_params, sm_scale, window,
             pltpu.VMEM((8, block_q), jnp.float32),
         ],
         interpret=interpret,
+        metadata={"kernel": KERNEL_FWD},
     )(qp, kp, vp)
 
 
@@ -586,6 +594,7 @@ def _banded_backward(qp, kp, vp, gp, lse_p, delta, d_pad, seq_params,
         out_shape=jax.ShapeDtypeStruct((bh, t_pad, d_pad), qp.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d_pad), jnp.float32)],
         interpret=interpret,
+        metadata={"kernel": KERNEL_DQ},
     )(qp, kp, vp, gp, lse_p, delta)
 
     def q_index(b, i, j):
@@ -625,6 +634,7 @@ def _banded_backward(qp, kp, vp, gp, lse_p, delta, d_pad, seq_params,
             pltpu.VMEM((block_k, d_pad), jnp.float32),
         ],
         interpret=interpret,
+        metadata={"kernel": KERNEL_DKDV},
     )(qp, kp, vp, gp, lse_p, delta)
     return dq, dk, dv
 
@@ -679,6 +689,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, local_window,
         out_specs=pl.BlockSpec((1, block_q, d_pad), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t_pad, d_pad), q.dtype),
         interpret=interpret,
+        metadata={"kernel": KERNEL_DQ},
     )(qp, kp, vp, gp, lse_p, delta)
 
     dkv_kernel = functools.partial(
@@ -705,6 +716,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, local_window,
             jax.ShapeDtypeStruct((bh, kv_pad, d_pad), v.dtype),
         ),
         interpret=interpret,
+        metadata={"kernel": KERNEL_DKDV},
     )(qp, kp, vp, gp, lse_p, delta)
 
     dq = dq.reshape(batch, heads, t_pad, d_pad)[:, :, :seq_len, :head_dim]

@@ -57,6 +57,10 @@ from sharetrade_tpu.config import LearnerConfig
 ADAGRAD_EPS = 1e-7
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
 
+#: Kernel identity in a device trace (``pallas_call(metadata=...)``, as
+#: ops/attention.py's).
+KERNEL_ID = "fused_update"
+
 _LANE = 128
 _BLOCK_ROWS = 256          # (256, 128) f32 blocks: 128 KiB per operand
 
@@ -172,6 +176,7 @@ def _pallas_leaf(leaf_fn, n_state, p, g, state_leaves, *, compute_dtype,
         out_specs=tuple([spec] * len(out_shapes)),
         out_shape=tuple(out_shapes),
         interpret=interpret,
+        metadata={"kernel": KERNEL_ID},
     )(*operands)
 
     def unprep(x):

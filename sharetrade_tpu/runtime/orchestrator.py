@@ -36,7 +36,6 @@ owns:
 
 from __future__ import annotations
 
-import contextlib
 import random
 import threading
 import time
@@ -53,6 +52,7 @@ from sharetrade_tpu.config import ConfigError, FrameworkConfig
 from sharetrade_tpu.env import trading
 from sharetrade_tpu.env.portfolio import make_portfolio_env
 from sharetrade_tpu.obs import build_obs
+from sharetrade_tpu.obs.trace import span as trace_span
 from sharetrade_tpu.parallel import build_mesh, make_parallel_step
 from sharetrade_tpu.runtime.lifecycle import Lifecycle, Phase, QueryReply, ReplyState
 from sharetrade_tpu.runtime.pipeline import AsyncPipeline, Boundary
@@ -63,7 +63,6 @@ from sharetrade_tpu.utils.profiling import StepTimer, Tracer
 log = get_logger("runtime.orchestrator")
 
 #: Shared no-op context for un-sampled / obs-disabled span sites.
-_NULL_CTX = contextlib.nullcontext()
 
 
 #: Supervision verbs (the Akka directive vocabulary).
@@ -180,13 +179,28 @@ class Orchestrator:
         # of what bench_async_pipeline measures from trace spans. Obs-
         # gated: the default obs-off hot loop stays structurally
         # instrumentation-free (one None check per dispatch).
+        # The three stage histograms say where the host's time per chunk
+        # goes (inside the dispatch call, blocked on the pipeline's
+        # back-pressure, in the consumer's host_process block): one
+        # observation per materialization boundary, each divided by the
+        # chunks the boundary covers as train_chunk_seconds is, so the
+        # ratio of their sums to its sum is a share of the chunk at any
+        # metrics_every_chunks.
         self._h_chunk_seconds = self._h_dispatch_gap = None
+        self._h_dispatch_call = self._h_pipeline_stall = None
+        self._h_host_process = None
         if cfg.obs.enabled:
             from sharetrade_tpu.obs.hist import SECONDS_BOUNDS, Histogram
             self._h_chunk_seconds = self.metrics.attach_histogram(
                 "train_chunk_seconds", Histogram(bounds=SECONDS_BOUNDS))
             self._h_dispatch_gap = self.metrics.attach_histogram(
                 "train_dispatch_gap_ms", Histogram())
+            self._h_dispatch_call = self.metrics.attach_histogram(
+                "train_dispatch_call_ms", Histogram())
+            self._h_pipeline_stall = self.metrics.attach_histogram(
+                "train_pipeline_stall_ms", Histogram())
+            self._h_host_process = self.metrics.attach_histogram(
+                "train_host_process_ms", Histogram())
         self.checkpoints = checkpoints or CheckpointManager(
             cfg.runtime.checkpoint_dir, keep=cfg.runtime.keep_checkpoints,
             fsync=cfg.checkpoint.fsync,
@@ -704,18 +718,32 @@ class Orchestrator:
         # backoff sleep never counts as a "gap". ONE helper pair shared
         # by the sync and prefetch dispatch sites: both paths must stamp
         # identically for train_dispatch_gap_ms to mean one distribution.
+        # The same two stamps give train_dispatch_call_ms: the host time
+        # inside the dispatch calls since the last boundary, observed
+        # there (_observe_boundary) beside the time blocked in pl.put.
         last_dispatch_end: float | None = None
+        dispatch_ms = 0.0
 
-        def _note_dispatch_gap() -> None:
-            if (self._h_dispatch_gap is not None
-                    and last_dispatch_end is not None):
-                self._h_dispatch_gap.observe(
-                    (time.perf_counter() - last_dispatch_end) * 1e3)
+        def _dispatch_begin() -> float | None:
+            if self._h_dispatch_gap is None:
+                return None
+            now = time.perf_counter()
+            if last_dispatch_end is not None:
+                self._h_dispatch_gap.observe((now - last_dispatch_end) * 1e3)
+            return now
 
-        def _stamp_dispatch_end() -> None:
-            nonlocal last_dispatch_end
-            if self._h_dispatch_gap is not None:
+        def _dispatch_end(t_begin: float | None) -> None:
+            nonlocal last_dispatch_end, dispatch_ms
+            if t_begin is not None:
                 last_dispatch_end = time.perf_counter()
+                dispatch_ms += (last_dispatch_end - t_begin) * 1e3
+
+        def _observe_boundary(chunks: int, stall_ms: float) -> None:
+            nonlocal dispatch_ms
+            if self._h_dispatch_call is not None:
+                self._h_dispatch_call.observe(dispatch_ms / chunks)
+                self._h_pipeline_stall.observe(stall_ms / chunks)
+                dispatch_ms = 0.0
         self._committed_idx = 0
         # Double-buffered dispatch (runtime.double_buffer_dispatch; sync
         # path only — the async pipeline subsumes it): the (metrics, K,
@@ -736,8 +764,7 @@ class Orchestrator:
             self.pipeline_stats = {}
             pl = AsyncPipeline(
                 rt.pipeline_depth, self._host_process,
-                attn_check=self._row_needs_attention,
-                span=obs.span if obs.enabled else None)
+                attn_check=self._row_needs_attention, span=obs.span)
         self._pl = pl
         # Chunk position of the boundary row _boundary_actions is acting on
         # in the attention path — a supervision raise from there (NaN loss,
@@ -824,12 +851,12 @@ class Orchestrator:
                                 last_env_steps, chunks_ahead = refreshed
                                 continue    # re-enter: attention first
                     k = mega if can_fuse else 1
-                    # Obs spans ride the SAMPLING cadence, not the chunk
-                    # cadence: only the dispatch whose readback will
-                    # materialize this sample is timed, so between samples
-                    # the fast path stays span-free (the <2% overhead
-                    # budget, bench_obs_overhead). The predicate mirrors
-                    # the sample decision below — chunk-count cadence, the
+                    # trace.jsonl events ride the SAMPLING cadence, not the
+                    # chunk cadence: only the dispatch whose readback will
+                    # materialize this sample is written, so between samples
+                    # the fast path opens the profiler annotation alone (the
+                    # <2% overhead budget, bench_obs_overhead). The predicate
+                    # mirrors the sample decision below — chunk-count cadence, the
                     # near-threshold exact path, or a transitions journal
                     # (journaled runs materialize every chunk).
                     sampling = obs.enabled and (
@@ -837,11 +864,9 @@ class Orchestrator:
                         or self._transitions_journal is not None
                         or (last_env_steps + (chunks_ahead + k)
                             * rt.chunk_steps) >= threshold)
-                    _note_dispatch_gap()
-                    with (obs.span("dispatch", chunk=chunk_idx, k=k)
-                          if sampling else _NULL_CTX), self.tracer.span(
-                            f"train_chunk_{chunk_idx}"
-                            + (f"_x{k}" if k > 1 else "")):
+                    t_begin = _dispatch_begin()
+                    with (obs.span if sampling else trace_span)(
+                            "train/dispatch", chunk=chunk_idx, k=k):
                         # The step lock fences evaluate()'s state snapshot
                         # from this donating dispatch; dispatch is
                         # non-blocking so the lock is held microseconds,
@@ -858,7 +883,7 @@ class Orchestrator:
                             # the CPU fused-scan carve-outs (_build_step,
                             # sharding.py) exist to avoid a use-after-free.
                             self._ts = ts
-                    _stamp_dispatch_end()
+                    _dispatch_end(t_begin)
                 transitions = metrics.pop("transitions", None)
                 chunks_since += k
                 chunks_ahead += k
@@ -877,15 +902,18 @@ class Orchestrator:
                     _start_readback(metrics, transitions)
                     boundary = Boundary(chunk_idx, k, metrics, transitions,
                                         heals_mark, chunks_since)
+                    stall_ms = 0.0
                     if not pl.try_put(boundary):
-                        with (obs.span("pipeline_stall", chunk=chunk_idx,
-                                       depth=pl.depth)
-                              if obs.enabled else _NULL_CTX):
+                        t_stall = time.perf_counter()
+                        with obs.span("train/pipeline_stall",
+                                      chunk=chunk_idx, depth=pl.depth):
                             ok = pl.put(boundary, stop=self._stop)
+                        stall_ms = (time.perf_counter() - t_stall) * 1e3
                         self.metrics.inc("pipeline_stalls_total")
                         if not ok:
                             continue   # fault/stop while blocked: top of
                                        # loop takes over
+                    _observe_boundary(chunks_since, stall_ms)
                     self.metrics.record("pipeline_queue_depth", pl.qsize())
                     chunk_idx += k
                     chunks_since = 0
@@ -922,24 +950,23 @@ class Orchestrator:
                     # Consequence, documented in config.py: fault detection
                     # and the checkpoint/eval cadence act on a state one
                     # in-flight megachunk ahead of the rows being read.
-                    # The span covers the chunks the prefetch advances
-                    # (chunk_idx + k onward) so the trace keeps one
-                    # train_chunk_* entry per dispatch, not just the first.
-                    # The obs dispatch span mirrors that (this block only
+                    # The span names the chunks the prefetch advances
+                    # (chunk_idx + k onward), so the trace keeps one
+                    # train/dispatch entry per dispatch (this block only
                     # runs at materialization boundaries, so it is already
                     # on the sampled path).
-                    _note_dispatch_gap()
-                    with (obs.span("dispatch", chunk=chunk_idx + k, k=k,
-                                   prefetch=True)
-                          if obs.enabled else _NULL_CTX), self.tracer.span(
-                            f"train_chunk_{chunk_idx + k}_x{k}"):
+                    t_begin = _dispatch_begin()
+                    with obs.span("train/dispatch", chunk=chunk_idx + k,
+                                  k=k, prefetch=True):
                         with self._step_lock:
                             ts, ahead = self._mega_fn(self._ts)
                             self._ts = ts
-                    _stamp_dispatch_end()
+                    _dispatch_end(t_begin)
                     pending = (ahead, k, self.agent_heals)
                 # Synchronous path: readback + host processing inline (the
-                # pre-pipeline behavior, byte-identical).
+                # pre-pipeline behavior, byte-identical). No queue, so the
+                # dispatcher is never stalled by back-pressure: 0.
+                _observe_boundary(chunks_since, 0.0)
                 metrics = self._host_process(Boundary(
                     chunk_idx, k, metrics, transitions, heals_mark,
                     chunks_since))
@@ -954,6 +981,7 @@ class Orchestrator:
                 last_env_steps = None   # resync after any recovery path
                 pending = None          # in-flight megachunk is now stale
                 last_dispatch_end = None  # recovery/backoff is not a "gap"
+                dispatch_ms = 0.0
                 pipeline_fault = pl is not None and exc is pl.error
                 if pl is not None:
                     # Quiesce and replace the pipeline: boundaries still
@@ -964,8 +992,7 @@ class Orchestrator:
                     self._record_pipeline_stats(pl)
                     pl = AsyncPipeline(
                         rt.pipeline_depth, self._host_process,
-                        attn_check=self._row_needs_attention,
-                        span=obs.span if obs.enabled else None)
+                        attn_check=self._row_needs_attention, span=obs.span)
                     self._pl = pl
                 # Attribution: a consumer fault belongs to the chunk the
                 # consumer committed last; a supervision raise from the
@@ -1022,8 +1049,7 @@ class Orchestrator:
                 log.warning("chunk failed (%r); restart %d/%d in %.2fs",
                             exc, self.restarts, rt.max_restarts, delay)
                 with obs.span("supervision_recovery",
-                              restart=self.restarts) \
-                        if obs.enabled else _NULL_CTX:
+                              restart=self.restarts):
                     if self._wait_backoff(delay):
                         return
                     self._restore_or_reinit()
@@ -1095,8 +1121,7 @@ class Orchestrator:
                     "checkpoint (%.1fs of the %.1fs grace left)",
                     max(0.0, deadline - time.monotonic()), grace)
         saved = False
-        with (obs.span("preemption_drain", grace_s=grace)
-              if obs.enabled else _NULL_CTX):
+        with obs.span("preemption_drain", grace_s=grace):
             try:
                 if pl is not None:
                     pl.drain(timeout_s=max(0.5,
@@ -1153,11 +1178,10 @@ class Orchestrator:
         is the fault-attribution cursor either way."""
         obs = self.obs
         self._committed_idx = b.base
-        with (obs.span("readback", chunk=b.base, k=b.k)
-              if obs.enabled else _NULL_CTX):
+        with obs.span("train/readback", chunk=b.base, k=b.k):
             host, host_tr = jax.device_get((b.metrics, b.transitions))  # hot-loop-sync-ok: consumer-side batched megachunk readback, off the dispatch path
-        with (obs.span("host_process", chunk=b.base, k=b.k)
-              if obs.enabled else _NULL_CTX):
+        t_host = time.perf_counter()
+        with obs.span("train/host_process", chunk=b.base, k=b.k):
             rows = _metric_rows(host, b.k)
             for i, row in enumerate(rows):
                 if obs.enabled:
@@ -1203,7 +1227,10 @@ class Orchestrator:
             with self._snapshot_lock:
                 self._snapshot = metrics
             self.metrics.record_many(metrics)
-            return metrics
+        if self._h_host_process is not None:
+            self._h_host_process.observe(
+                (time.perf_counter() - t_host) * 1e3 / b.chunks_covered)
+        return metrics
 
     def _row_needs_attention(self, row: dict[str, float]) -> bool:
         """Consumer-side hint: does this boundary row need a DISPATCHER

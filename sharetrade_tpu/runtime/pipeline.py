@@ -18,7 +18,7 @@ Contracts the orchestrator builds on:
   fault hooks observe exactly the chunk order of the synchronous path.
 - **Backpressure**: the queue is bounded (``runtime.pipeline_depth``), so
   HBM held by in-flight readback buffers is bounded and dispatch stalls
-  (``pipeline_stall``) rather than racing ahead unboundedly.
+  (``train/pipeline_stall``) rather than racing ahead unboundedly.
 - **Fault propagation**: an exception raised while consuming is stored (not
   swallowed) and ``error`` is visible to the dispatcher BEFORE it commits
   the next megachunk; the original exception object is re-raised on the
@@ -39,6 +39,8 @@ import queue
 import threading
 import time
 from typing import Any, Callable, NamedTuple
+
+from sharetrade_tpu.obs.trace import span as trace_span
 
 
 class Boundary(NamedTuple):
@@ -62,13 +64,14 @@ class AsyncPipeline:
     boundary metric row; ``attn_check(row)`` (optional) decides whether the
     row needs a dispatcher-side action (heal, cadence, completion) — if so
     the ``attention`` event is set and the dispatcher drains and acts.
-    ``span`` (optional) is an ``obs.span``-shaped factory used for the
-    ``queue_wait`` consumer-idle spans.
+    ``span`` is the host-span entry for the ``train/queue_wait``
+    consumer-idle spans: the run's ``obs.span``, or the bare profiler
+    annotation.
     """
 
     def __init__(self, depth: int, consume: Callable[[Boundary], dict], *,
                  attn_check: Callable[[dict], bool] | None = None,
-                 span: Callable[..., Any] | None = None,
+                 span: Callable[..., Any] = trace_span,
                  name: str = "readback-consumer"):
         if depth < 1:
             raise ValueError(f"pipeline depth must be >= 1, got {depth}")
@@ -188,12 +191,9 @@ class AsyncPipeline:
 
     def _loop(self) -> None:
         while True:
-            if self._span is not None:
-                # Consumer-idle time: a long queue_wait span means the
-                # pipeline is starved (dispatch-bound) — the healthy state.
-                with self._span("queue_wait", depth=self._q.qsize()):
-                    item = self._q.get()
-            else:
+            # Consumer-idle time: a long queue_wait span means the
+            # pipeline is starved (dispatch-bound) — the healthy state.
+            with self._span("train/queue_wait", depth=self._q.qsize()):
                 item = self._q.get()
             if item is _SHUTDOWN:
                 with self._cond:

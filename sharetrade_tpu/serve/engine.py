@@ -183,6 +183,7 @@ from sharetrade_tpu.config import ConfigError, ServeConfig
 from sharetrade_tpu.models.core import apply_batched
 from sharetrade_tpu.obs import SERVE_STAGES
 from sharetrade_tpu.obs.hist import Histogram
+from sharetrade_tpu.obs.trace import span as trace_span
 from sharetrade_tpu.precision import FP32, PrecisionPolicy
 from sharetrade_tpu.serve.spill import SpillArena
 from sharetrade_tpu.utils.logging import get_logger
@@ -410,6 +411,9 @@ class _DoneBatch(NamedTuple):
     #:  readback so the committed warm entry — and any spill record it
     #: later demotes into — is sealed with the right adoption clock.
     parked_steps: tuple = ()
+    #: The dispatcher's batch serial: the identifier the tick's host spans
+    #: share across the two threads.
+    tick: int = 0
 
 
 class SlotPool:
@@ -620,6 +624,10 @@ class ServeEngine:
         self._precision = precision
         self._registry = registry if registry is not None else MetricsRegistry()
         self._obs = obs
+        # The one host-span entry (obs/trace.py): the run's when an Obs
+        # bundle came along, else the bare profiler annotation. Per tick,
+        # never per request.
+        self._span = getattr(obs, "span", None) or trace_span
         self._episode = (model.apply_prefill is not None
                          and model.apply_serve_batch is not None)
         self._live = _Live(jax.device_put(precision.cast_compute(params)),
@@ -764,6 +772,22 @@ class ServeEngine:
             for name in ("serve_request_ms",
                          *(f"serve_{s}_ms" for s in SERVE_STAGES))}
         self._h_e2e = self._hists["serve_request_ms"]
+        # Per-TICK histograms, where the work waits between the two
+        # threads: the dispatcher's host time in a tick, how long it then
+        # blocks handing the tick to the consumer (0 when the done queue
+        # has room), the consumer's host time per tick less its readback,
+        # and the ticks dispatched and not yet completed at each dispatch.
+        self._h_tick_host = self._registry.attach_histogram(
+            "serve_tick_host_ms", Histogram())
+        self._h_done_wait = self._registry.attach_histogram(
+            "serve_done_wait_ms", Histogram())
+        self._h_complete_host = self._registry.attach_histogram(
+            "serve_complete_host_ms", Histogram())
+        self._h_inflight = self._registry.attach_histogram(
+            "serve_inflight_ticks",
+            Histogram(bounds=tuple(float(n) for n in range(1, 17))))
+        self._ticks_dispatched = 0      # dispatcher-thread-owned
+        self._ticks_completed = 0       # consumer-thread-owned
         #: End-to-end bucket counts at the last stats publish — the
         #: per-window delta the p50/p99 gauges are quantiled over.
         self._p50_prev_counts = self._h_e2e.snapshot()["counts"]
@@ -909,19 +933,24 @@ class ServeEngine:
     def _warm_program(self, params, pool, obs, idx):
         """One incremental step for a warm batch: gather slot carries,
         per-row-clock serve step, scatter back. THE steady-state program."""
-        rows = jax.tree.map(lambda x: x[idx], pool)
-        out, new_rows = self.model.apply_serve_batch(params, obs, rows)
-        new_pool = jax.tree.map(lambda p, r: p.at[idx].set(r), pool,
-                                new_rows)
+        with jax.named_scope("gather"):
+            rows = jax.tree.map(lambda x: x[idx], pool)
+        with jax.named_scope("model"):
+            out, new_rows = self.model.apply_serve_batch(params, obs, rows)
+        with jax.named_scope("scatter"):
+            new_pool = jax.tree.map(lambda p, r: p.at[idx].set(r), pool,
+                                    new_rows)
         actions = jnp.argmax(out.logits, axis=-1).astype(jnp.int32)
         return actions, out.logits, out.value, new_pool
 
     def _cold_program(self, params, pool, obs, idx):
         """Batched re-prefill: cold sessions (fresh or evicted) compute
         their episode-start pass and land their carries in their slots."""
-        out, new_rows = self.model.apply_prefill(params, obs)
-        new_pool = jax.tree.map(lambda p, r: p.at[idx].set(r), pool,
-                                new_rows)
+        with jax.named_scope("model"):
+            out, new_rows = self.model.apply_prefill(params, obs)
+        with jax.named_scope("scatter"):
+            new_pool = jax.tree.map(lambda p, r: p.at[idx].set(r), pool,
+                                    new_rows)
         actions = jnp.argmax(out.logits, axis=-1).astype(jnp.int32)
         return actions, out.logits, out.value, new_pool
 
@@ -1393,12 +1422,18 @@ class ServeEngine:
                     self._supervise(self._consumer_fault
                                     or RuntimeError("serve consumer fault"))
                 continue
-            batch = self._collect_batch()
+            tick = self._batch_serial + 1
+            with self._span("serve/collect_batch", tick=tick):
+                batch = self._collect_batch()
             if not batch:
                 continue
             live = self._live       # ONE read per tick: the atomicity seam
+            t_tick = time.perf_counter()
             try:
-                done = self._dispatch_batch(batch, live)
+                with self._span("serve/dispatch_tick", tick=tick,
+                                rows=len(batch)) as tick_span:
+                    done = self._dispatch_batch(batch, live)
+                    tick_span.set_metadata(cold=done.cold)
             except Exception as exc:    # noqa: BLE001 — one malformed
                 # request (bad obs shape) must fail ITS batch, not wedge
                 # the dispatcher and hang every later session.
@@ -1408,9 +1443,21 @@ class ServeEngine:
                 # default max_restarts=0, the PR-8 contract).
                 self._supervise(exc)
                 continue
+            t_put = time.perf_counter()
+            self._h_tick_host.observe((t_put - t_tick) * 1e3)
+            self._ticks_dispatched += 1
+            self._h_inflight.observe(
+                self._ticks_dispatched - self._ticks_completed)
             # Bounded handoff: blocking here is the backpressure that
             # keeps in-flight device buffers bounded (pipeline.py's put).
-            self._done_q.put(done)
+            try:
+                self._done_q.put_nowait(done)
+                self._h_done_wait.observe(0.0)
+            except queue.Full:
+                with self._span("serve/done_wait", tick=done.tick):
+                    self._done_q.put(done)
+                self._h_done_wait.observe(
+                    (time.perf_counter() - t_put) * 1e3)
         # Dispatcher exit: whatever is still queued/deferred can never be
         # dispatched — fail it terminally HERE, on the thread that owns
         # these structures (stop() and submit() re-sweep only for racers,
@@ -1855,7 +1902,7 @@ class ServeEngine:
                           epoch=self._fault_epoch,
                           parked_sids=tuple(park_sids),
                           parked_rows=parked_rows,
-                          parked_steps=tuple(park_steps))
+                          parked_steps=tuple(park_steps), tick=bid)
 
     def _pad(self, reqs: list[_Request],
              idx: list[int]) -> tuple[np.ndarray, np.ndarray]:
@@ -2052,7 +2099,9 @@ class ServeEngine:
 
     def _consume_done(self, item: _DoneBatch) -> None:
         try:
-            self._complete_batch(item)
+            with self._span("serve/complete_batch", tick=item.tick,
+                            rows=item.n):
+                self._complete_batch(item)
         except Exception as exc:  # noqa: BLE001 — a completion fault
             # (readback error, device fault) must neither wedge the
             # dispatcher behind a full done queue NOR leak the batch's
@@ -2093,6 +2142,8 @@ class ServeEngine:
             self._consumer_fault = exc
             self._consumer_fault_epoch = item.epoch
             self._restart_requested.set()
+        finally:
+            self._ticks_completed += 1
 
     #: Arena take verdicts -> registry counters (the fleet router folds
     #: these per engine into fleet_spill_* — ISSUE 20 observability).
@@ -2166,14 +2217,19 @@ class ServeEngine:
         n_done = slow = 0
         slo_target = self._slo[1]
         hists = self._hists
+        t_begin = time.perf_counter()
+        readback_s = 0.0
         if done.parked_sids:
             # Page-out step 2: the host readback of the victims' carry
             # rows rides HERE, on the consumer — the dispatch loop never
             # blocks on a device_get (lint check 17). The copies detach
             # each session's rows from the stacked transfer buffer so a
             # later partial demotion frees real memory.
-            # serve-host-ok: consumer-side page-out readback.
-            host_rows = jax.device_get(done.parked_rows)
+            t_rb = time.perf_counter()
+            with self._span("serve/readback", tick=done.tick):
+                # serve-host-ok: consumer-side page-out readback.
+                host_rows = jax.device_get(done.parked_rows)
+            readback_s += time.perf_counter() - t_rb
             for i, sid in enumerate(done.parked_sids):
                 row = jax.tree.map(lambda x: np.asarray(x[i]).copy(),
                                    host_rows)
@@ -2186,11 +2242,14 @@ class ServeEngine:
             [] if self._req_tracer is not None else None)
         try:
             for reqs, act_dev, logit_dev, val_dev in done.groups:
-                # serve-host-ok: consumer-side readback — the dispatcher
-                # never blocks on these buffers.
-                actions, logits, values = jax.device_get(
-                    (act_dev, logit_dev, val_dev))
+                t_rb = time.perf_counter()
+                with self._span("serve/readback", tick=done.tick):
+                    # serve-host-ok: consumer-side readback — the
+                    # dispatcher never blocks on these buffers.
+                    actions, logits, values = jax.device_get(
+                        (act_dev, logit_dev, val_dev))
                 now = time.perf_counter()
+                readback_s += now - t_rb
                 # The consumer serializes a batch's completions, so the
                 # readback HISTOGRAM charges each request only its own
                 # completion slice (t_prev→t_done): billing t_done minus
@@ -2298,6 +2357,8 @@ class ServeEngine:
         if done.evicted:
             reg.inc("serve_evictions_total", done.evicted)
         self._publish_stats()
+        self._h_complete_host.observe(
+            (time.perf_counter() - t_begin - readback_s) * 1e3)
 
     def _note_exemplar(self, req: _Request, latency_ms: float,
                        stages: dict, step: int) -> None:

@@ -4,9 +4,9 @@ Reference status (SURVEY.md §5): no tracing of any kind; TF's SummarySaver is
 imported but never used (QDecisionPolicyActor.scala:8); the only timing
 signal is a progress log every 200 fold steps. Here:
 
-- :class:`Tracer` wraps ``jax.profiler`` device traces (XPlane output,
-  viewable in TensorBoard/XProf) gated by config, with annotated host-side
-  ``TraceAnnotation`` spans so chunk boundaries show up in the timeline;
+- :class:`Tracer` starts and stops ``jax.profiler`` device traces (XPlane
+  output, viewable in TensorBoard/XProf) gated by config; the host spans
+  that show up in its timeline are ``obs/trace.py``'s (``host_span``);
 - :class:`StepTimer` measures per-chunk wall time and derives steps/sec,
   feeding the metrics registry (the throughput series BASELINE.md needs).
 """
@@ -26,7 +26,7 @@ log = get_logger("utils.profiling")
 
 
 class Tracer:
-    """Device + host tracing around training chunks.
+    """Device tracing around training chunks.
 
     ``profile_dir=None`` disables everything at zero cost (the config
     default, RuntimeConfig.profile_dir).
@@ -55,15 +55,6 @@ class Tracer:
             yield self
         finally:
             self.stop()
-
-    @contextlib.contextmanager
-    def span(self, name: str):
-        """Named host annotation visible in the device timeline."""
-        if self.profile_dir:
-            with jax.profiler.TraceAnnotation(name):
-                yield
-        else:
-            yield
 
 
 @dataclass

@@ -536,6 +536,35 @@ class TestSpanEmissionLint:
         assert lint_hot_loop.lint_span_emission() == []
 
 
+class TestProfilerAnnotationLint:
+    def test_lint_profiler_annotations_semantics(self, tmp_path):
+        import lint_hot_loop
+        pkg = tmp_path / "pkg"
+        (pkg / "obs").mkdir(parents=True)
+        (pkg / "serve").mkdir()
+        (pkg / "obs" / "trace.py").write_text(       # the one module
+            "from jax.profiler import TraceAnnotation\n"
+            "def span(name, **ids):\n"
+            "    return TraceAnnotation(name, **ids)\n")
+        (pkg / "serve" / "engine.py").write_text(
+            "import jax\n"
+            "from jax.profiler import TraceAnnotation\n"   # import: fine
+            "def per_request(req):\n"
+            "    with jax.profiler.TraceAnnotation('req'):\n"    # line 4
+            "        pass\n"
+            "    with TraceAnnotation(f'req_{req}'):\n"          # line 6
+            "        pass\n"
+            "    # trace-annotation-ok: once, at start-up\n"
+            "    with TraceAnnotation('boot'):\n"          # marker-exempt
+            "        pass\n"
+            "    return 'TraceAnnotation(x)'\n")           # prose: fine
+        hits = lint_hot_loop.lint_profiler_annotations(root=pkg)
+        assert [(rel, ln) for rel, ln, _ in hits] == [
+            ("serve/engine.py", 4), ("serve/engine.py", 6)]
+        # The real tree is clean (the repo-level invariant).
+        assert lint_hot_loop.lint_profiler_annotations() == []
+
+
 # ---- the native wire backend (ISSUE 19) ----------------------------
 
 

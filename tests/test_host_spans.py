@@ -1,0 +1,265 @@
+"""The one host-span path (obs/trace.py ``host_span``) and the stage
+histograms observed beside it.
+
+One call opens a ``jax.profiler.TraceAnnotation`` (on the ``/host:CPU``
+plane of whatever profiler session runs) and, with an enabled tracer, also
+writes the ``trace.jsonl`` event. The orchestrator, the async pipeline and
+the serve engine open every span through it, per chunk or per tick, under
+fixed names with the serial as an identifier.
+"""
+
+from __future__ import annotations
+
+import glob
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from sharetrade_tpu.config import FrameworkConfig, ModelConfig, ServeConfig
+from sharetrade_tpu.models import build_model
+from sharetrade_tpu.obs import SpanTracer, read_trace
+from sharetrade_tpu.obs.trace import clock_pair, host_span, span
+from sharetrade_tpu.runtime import Orchestrator, ReplyState
+from sharetrade_tpu.serve import ServeEngine
+from sharetrade_tpu.utils.profiling import Tracer
+
+WINDOW = 8
+PRICES = np.linspace(10.0, 20.0, 72, dtype=np.float32)  # 64-step episode
+DONE_DEPTH = 1
+
+TRAIN_SPANS = {"train/dispatch": {"chunk", "k"},
+               "train/pipeline_stall": {"chunk"},
+               "train/queue_wait": set(),
+               "train/readback": {"chunk"},
+               "train/host_process": {"chunk"}}
+SERVE_SPANS = {"serve/collect_batch": {"tick"},
+               "serve/dispatch_tick": {"tick", "rows", "cold"},
+               "serve/done_wait": {"tick"},
+               "serve/complete_batch": {"tick", "rows"},
+               "serve/readback": {"tick"}}
+
+
+def host_plane_events(trace_dir) -> list[tuple[str, dict]]:
+    """(name, identifiers) of every event on the trace's host plane."""
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert paths, f"no xplane trace under {trace_dir}"
+    data = ProfileData.from_file(sorted(paths)[-1])
+    return [(ev.name, dict(ev.stats)) for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def start_profiler(trace_dir) -> None:
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0      # host TraceMe spans only
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+
+
+def toy_train_cfg(tmp_path, *, obs: bool) -> FrameworkConfig:
+    cfg = FrameworkConfig()
+    cfg.learner.algo = "qlearn"
+    cfg.env.window = WINDOW
+    cfg.model.hidden_dim = 8
+    cfg.parallel.num_workers = 4
+    cfg.runtime.chunk_steps = 16
+    cfg.runtime.metrics_every_chunks = 1
+    cfg.runtime.pipeline_depth = 1
+    cfg.runtime.checkpoint_every_updates = 0
+    cfg.runtime.checkpoint_dir = str(tmp_path / "ckpts")
+    cfg.obs.enabled = obs
+    cfg.obs.dir = str(tmp_path / "obs")
+    cfg.obs.export_interval_s = 3600
+    return cfg
+
+
+def run_toy_training(cfg, *, slow_consumer_s: float = 0.0) -> Orchestrator:
+    """One 4-chunk episode; a slowed consumer fills the depth-1 pipeline,
+    so the dispatcher blocks in ``pl.put`` as it does on the chip."""
+    orch = Orchestrator(cfg)
+    orch.send_training_data(PRICES)
+    if slow_consumer_s:
+        consume = orch._host_process
+
+        def slowed(boundary):
+            time.sleep(slow_consumer_s)
+            return consume(boundary)
+        orch._host_process = slowed
+    orch.start_training(background=False)
+    assert orch.is_everything_done().state is ReplyState.COMPLETED
+    return orch
+
+
+def toy_engine(*, done_depth: int = DONE_DEPTH) -> ServeEngine:
+    model = build_model(ModelConfig(kind="mlp", hidden_dim=16), WINDOW + 2,
+                        head="ac")
+    engine = ServeEngine(
+        model, ServeConfig(max_batch=1, slots=8, batch_timeout_ms=0.0),
+        model.init(jax.random.PRNGKey(1)), done_depth=done_depth)
+    engine.warmup()
+    return engine
+
+
+def serve_ticks(engine, ticks: int, *, slow_consumer_s: float = 0.0) -> None:
+    """``ticks`` one-row ticks; a slowed consumer fills the done queue, so
+    the dispatcher blocks in ``_done_q.put``."""
+    if slow_consumer_s:
+        complete = engine._complete_batch
+
+        def slowed(done):
+            time.sleep(slow_consumer_s)
+            complete(done)
+        engine._complete_batch = slowed
+    obs = np.concatenate([PRICES[:WINDOW], [2400.0, 0.0]]).astype(np.float32)
+    handles = [engine.submit(f"s{i}", obs) for i in range(ticks)]
+    assert all(h.wait(30.0) is not None for h in handles)
+
+
+# -- the entry itself --------------------------------------------------------
+
+@pytest.mark.parametrize("case", ["no_tracer_no_session",
+                                  "disabled_tracer_no_session",
+                                  "profile_dir_session"])
+def test_host_span_cases(tmp_path, case):
+    """The two ``Tracer.span`` cases of old (no profiler: a no-op; under
+    ``runtime.profile_dir``: the span is in the device trace), on the one
+    span path."""
+    if case == "profile_dir_session":
+        tracer = Tracer(str(tmp_path))
+        with tracer.trace():
+            with span("matmul", chunk=7):
+                x = jnp.ones((64, 64))
+                jax.block_until_ready(x @ x)
+        assert ("matmul", {"chunk": 7}) in host_plane_events(tmp_path)
+        return
+    with Tracer(None).trace():                 # no profiler started
+        if case == "no_tracer_no_session":
+            ctx = span("x", chunk=1)
+        else:
+            ctx = host_span("x", SpanTracer(None), chunk=1)
+        assert type(ctx) is jax.profiler.TraceAnnotation
+        with ctx:
+            pass
+    assert list(tmp_path.iterdir()) == []
+
+
+def test_one_call_lands_in_the_profiler_trace_and_in_trace_jsonl(tmp_path):
+    tracer = SpanTracer(str(tmp_path / "trace.jsonl"))
+    start_profiler(tmp_path / "prof")
+    with tracer.span("serve/dispatch_tick", tick=5, rows=3) as sp:
+        sp.set_metadata(cold=1)
+    jax.profiler.stop_trace()
+    tracer.close()
+    ids = {"tick": 5, "rows": 3, "cold": 1}
+    assert ("serve/dispatch_tick", ids) in host_plane_events(tmp_path / "prof")
+    events = read_trace(str(tmp_path / "trace.jsonl"))
+    assert [(e["name"], e.get("args")) for e in events if e["ph"] == "X"] \
+        == [("serve/dispatch_tick", ids)]
+
+
+def test_clock_event_lays_trace_jsonl_over_the_epoch(tmp_path):
+    epoch, mono = clock_pair()
+    assert abs(epoch - time.time()) < 5
+    assert abs(mono - time.perf_counter()) < 5
+    tracer = SpanTracer(str(tmp_path / "trace.jsonl"))
+    before = time.time_ns()
+    with tracer.span("a"):
+        pass
+    after = time.time_ns()
+    tracer.close()
+    clock, ev = read_trace(str(tmp_path / "trace.jsonl"))
+    assert clock["name"] == "clock" and clock["ts"] == 0.0
+    start_ns = clock["args"]["epoch_ns"] + ev["ts"] * 1e3
+    assert before - 5e6 <= start_ns <= after + 5e6
+
+
+# -- every span of the table, on the host plane ------------------------------
+
+def test_profiler_session_holds_every_training_and_serving_span(tmp_path):
+    start_profiler(tmp_path / "prof")
+    orch = run_toy_training(toy_train_cfg(tmp_path, obs=True),
+                            slow_consumer_s=0.05)
+    engine = toy_engine()
+    serve_ticks(engine, 4, slow_consumer_s=0.05)
+    jax.profiler.stop_trace()
+    orch.stop()
+    engine.stop(drain=False)
+    on_host: dict[str, list[dict]] = {}
+    for name, ids in host_plane_events(tmp_path / "prof"):
+        on_host.setdefault(name, []).append(ids)
+    for name, wanted in {**TRAIN_SPANS, **SERVE_SPANS}.items():
+        assert name in on_host, f"{name} is not on the host plane"
+        assert all(wanted <= set(ids) for ids in on_host[name]), name
+    # One dispatch span a chunk, the chunk's serial as its identifier.
+    assert sorted(ids["chunk"] for ids in on_host["train/dispatch"]) \
+        == [0, 1, 2, 3]
+    assert sorted(ids["tick"] for ids in on_host["serve/dispatch_tick"]) \
+        == [1, 2, 3, 4]
+    assert not [n for n in on_host if n.startswith("train_chunk_")]
+    # The same spans, from the same calls, in the run's trace.jsonl.
+    written = {e["name"] for e in read_trace(
+        os.path.join(orch.cfg.obs.dir, "trace.jsonl")) if e["ph"] == "X"}
+    assert set(TRAIN_SPANS) <= written
+
+
+# -- the stage histograms ----------------------------------------------------
+
+def test_training_histograms_observe_once_per_boundary(tmp_path):
+    orch = run_toy_training(toy_train_cfg(tmp_path, obs=True),
+                            slow_consumer_s=0.05)
+    orch.stop()
+    snaps = orch.metrics.histograms()
+    boundaries = 4
+    for name in ("train_dispatch_call_ms", "train_pipeline_stall_ms",
+                 "train_host_process_ms"):
+        assert snaps[name]["count"] == boundaries, name
+    assert snaps["train_chunk_seconds"]["count"] == boundaries
+    # The consumer sleeps 50 ms a boundary behind a depth-1 queue: the
+    # dispatcher spends that time blocked in put (the first dispatch call
+    # holds the step's compile, so the two are not compared).
+    assert snaps["train_pipeline_stall_ms"]["sum"] > 50.0
+    assert snaps["train_dispatch_call_ms"]["sum"] > 0
+    assert snaps["train_host_process_ms"]["sum"] > 0
+    assert orch.metrics.counters()["pipeline_stalls_total"] >= 1
+
+
+def test_training_histograms_are_absent_with_obs_off(tmp_path):
+    orch = run_toy_training(toy_train_cfg(tmp_path, obs=False))
+    orch.stop()
+    assert not [n for n in orch.metrics.histograms()
+                if n.startswith("train_")]
+    assert not os.path.exists(orch.cfg.obs.dir)
+
+
+@pytest.mark.parametrize("slow_consumer_s", [0.0, 0.03])
+def test_serving_histograms_observe_once_per_tick(slow_consumer_s):
+    engine = toy_engine()
+    ticks = 6
+    try:
+        serve_ticks(engine, ticks, slow_consumer_s=slow_consumer_s)
+    finally:
+        engine.stop(drain=False)
+    snaps = engine.registry.histograms()
+    for name in ("serve_tick_host_ms", "serve_done_wait_ms",
+                 "serve_complete_host_ms", "serve_inflight_ticks"):
+        assert snaps[name]["count"] == ticks, name
+    inflight = snaps["serve_inflight_ticks"]
+    assert inflight["bounds"] == [float(n) for n in range(1, 17)]
+    worst = max(b for b, c in zip(inflight["bounds"], inflight["counts"])
+                if c)
+    assert 1 <= worst <= DONE_DEPTH + 2 and inflight["counts"][-1] == 0
+    if slow_consumer_s:
+        # Ticks pile up behind the consumer: the dispatcher waits in put.
+        assert worst == DONE_DEPTH + 2
+        assert snaps["serve_done_wait_ms"]["sum"] > 10.0
+    else:
+        assert snaps["serve_done_wait_ms"]["sum"] < \
+            snaps["serve_tick_host_ms"]["sum"] + 50.0
+    assert snaps["serve_complete_host_ms"]["sum"] > 0
+    assert engine.registry.counters().get(
+        "serve_trace_decomposition_error_total", 0.0) == 0.0

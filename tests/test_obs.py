@@ -25,7 +25,6 @@ from sharetrade_tpu.obs import (
     read_trace,
     summarize_run_dir,
 )
-from sharetrade_tpu.obs.trace import _NULL_CTX
 from sharetrade_tpu.runtime import Orchestrator, Phase, ReplyState
 from sharetrade_tpu.utils.metrics import MetricsRegistry
 from sharetrade_tpu.utils.profiling import StepTimer
@@ -61,7 +60,10 @@ class TestSpanTracer:
         tracer.instant("marker", reason="x")
         tracer.close()
         events = read_trace(path)
-        assert len(events) == 2
+        # The leading clock event (epoch beside perf_counter), then ours.
+        assert [e["name"] for e in events] == ["clock", "alpha", "marker"]
+        assert events[0]["ts"] == 0.0
+        assert abs(events[0]["args"]["epoch_ns"] / 1e9 - time.time()) < 60
         span = next(e for e in events if e["ph"] == "X")
         assert span["name"] == "alpha"
         assert span["dur"] > 0
@@ -81,11 +83,15 @@ class TestSpanTracer:
         tracer.flush()   # no close(): simulates a killed process
         raw = open(path).read()
         assert raw.startswith("[") and not raw.rstrip().endswith("]")
-        assert read_trace(path)[0]["name"] == "s"
+        assert read_trace(path)[1]["name"] == "s"
 
-    def test_disabled_writes_nothing_and_is_shared_nullctx(self, tmp_path):
+    def test_disabled_writes_nothing_and_hands_back_the_bare_annotation(
+            self, tmp_path):
+        import jax
         tracer = SpanTracer(None)
-        assert tracer.span("x") is _NULL_CTX  # no per-call allocation
+        # No wrapper object: the profiler annotation itself, inert while
+        # no profiler session runs.
+        assert type(tracer.span("x")) is jax.profiler.TraceAnnotation
         with tracer.span("x"):
             pass
         tracer.instant("y")
@@ -181,7 +187,7 @@ class TestObsRun:
         events = read_trace(os.path.join(run_dir, "trace.jsonl"))
         span_names = {e["name"] for e in events if e["ph"] == "X"}
         # The orchestrator phase decomposition the ISSUE names.
-        assert {"dispatch", "readback", "host_process",
+        assert {"train/dispatch", "train/readback", "train/host_process",
                 "checkpoint_save"} <= span_names
         assert "phase:completed" in {
             e["name"] for e in events if e["ph"] == "i"}
@@ -196,17 +202,22 @@ class TestObsRun:
 
         summary = summarize_run_dir(run_dir)
         assert summary["manifest"]["config_hash"] == manifest["config_hash"]
-        assert summary["trace"]["dispatch"]["count"] >= 1
+        assert summary["trace"]["train/dispatch"]["count"] >= 1
         assert summary["metrics"]["prom_file"]
         assert "flight_recorder" not in summary   # healthy run: no bundle
 
     def test_disabled_means_zero_files(self, tmp_path):
         cfg = obs_cfg(tmp_path, enabled=False)
         orch = Orchestrator(cfg)
-        # Structural zero-cost: inert facade, shared null context, and the
-        # run dir is never even created.
+        # Structural zero-cost: inert facade, the bare (inactive) profiler
+        # annotation, no training histogram, and the run dir is never even
+        # created.
+        import jax
         assert not orch.obs.enabled
-        assert orch.obs.span("dispatch") is _NULL_CTX
+        assert (type(orch.obs.span("train/dispatch"))
+                is jax.profiler.TraceAnnotation)
+        assert not [n for n in orch.metrics.histograms()
+                    if n.startswith("train_")]
         orch.send_training_data(PRICES)
         orch.start_training(background=False)
         assert orch.is_everything_done().state is ReplyState.COMPLETED
@@ -341,7 +352,7 @@ class TestCliObs:
         assert cli.main(["obs", "--dir", cfg.obs.dir]) == 0
         out = json.loads(capsys.readouterr().out)
         assert out["manifest"]["config_hash"]
-        assert out["trace"]["dispatch"]["count"] >= 1
+        assert out["trace"]["train/dispatch"]["count"] >= 1
 
     def test_obs_command_rejects_missing_dir(self, tmp_path):
         from sharetrade_tpu import cli

@@ -505,6 +505,19 @@ SPAN_EMIT_DUMPS_PATTERN = re.compile(r"json\.dumps?\s*\(")
 SPAN_EMIT_CTX_PATTERN = re.compile(r"span|tctx|trace", re.IGNORECASE)
 SPAN_NAME_PATTERN = re.compile(r"span|trace", re.IGNORECASE)
 
+#: Check 20 (the host-span PR): ``jax.profiler.TraceAnnotation`` is
+#: constructed in ONE module, obs/trace.py (``host_span`` / ``span``), whose
+#: callers open spans per chunk or per tick under fixed names. A bare
+#: annotation anywhere else in the package is how a span per request or
+#: per agent-step (a TraceMe each, in every profiled run, at request rate)
+#: slips in beside the helper, under a name nothing aggregates. Matched in
+#: the AST (a call to a name or attribute ``TraceAnnotation``), so prose
+#: and imports do not count. Escape hatch: ``trace-annotation-ok`` on the
+#: line or the two above, naming why the helper cannot serve.
+ANNOTATION_MODULE = "obs/trace.py"
+ANNOTATION_NAME = "TraceAnnotation"
+ANNOTATION_MARKER = "trace-annotation-ok"
+
 #: Check 17 (the session-paging PR): the warm session tier stays a
 #: BOUNDED host-RAM cache and the paging seam keeps the serve engine's
 #: dispatcher/consumer split. (a) The ``WarmStore`` class must carry its
@@ -921,6 +934,38 @@ def lint_span_emission(
                 bad.append((rel, node.lineno,
                             lines[node.lineno - 1].strip()))
     return sorted(bad, key=lambda hit: (hit[0], hit[1]))
+
+
+def lint_profiler_annotations(
+        root: pathlib.Path | None = None) -> list[tuple[str, int, str]]:
+    """Check 20: no ``TraceAnnotation(...)`` call in ``sharetrade_tpu/``
+    outside ANNOTATION_MODULE unless the line (or the two above) carries
+    ``trace-annotation-ok``. Returns (relpath, line, text) hits. ``root``
+    overrides the scanned package root (tests exercise the semantics on
+    fixtures)."""
+    root = root or TARGET.parent.parent     # sharetrade_tpu/
+    bad: list[tuple[str, int, str]] = []
+    for path in sorted(pathlib.Path(root).rglob("*.py")):
+        rel = path.relative_to(root).as_posix()
+        if rel == ANNOTATION_MODULE:
+            continue
+        src = path.read_text()
+        if ANNOTATION_NAME not in src:
+            continue
+        lines = src.splitlines()
+        for node in ast.walk(ast.parse(src)):
+            if not isinstance(node, ast.Call):
+                continue
+            fn = node.func
+            fname = (fn.attr if isinstance(fn, ast.Attribute)
+                     else getattr(fn, "id", None))
+            if fname != ANNOTATION_NAME:
+                continue
+            window = lines[max(0, node.lineno - 3):node.lineno]
+            if not any(ANNOTATION_MARKER in w for w in window):
+                bad.append((rel, node.lineno,
+                            lines[node.lineno - 1].strip()))
+    return sorted(bad)
 
 
 def lint_warm_tier(target: pathlib.Path | None = None
@@ -1436,6 +1481,20 @@ def main() -> int:
               f"(or the two above) '# {TRACE_BUFFER_MARKER}: <the "
               "bound / why serialization is off the hot path>'")
         return 1
+    ann_bad = lint_profiler_annotations()
+    if ann_bad:
+        print("profiler-annotation lint FAILED:")
+        for rel, ln, text in ann_bad:
+            print(f"  sharetrade_tpu/{rel}:{ln}: {text}")
+        print("host spans open through ONE entry, obs/trace.py host_span "
+              "(Obs.span / SpanTracer.span / span): per chunk or per tick, "
+              "a fixed name, the serial as an identifier, on the "
+              "profiler's clock and in trace.jsonl from one call; a bare "
+              "TraceAnnotation elsewhere is how a per-request or "
+              "per-agent-step span slips in. Use the helper, or tag the "
+              f"line (or the two above) '# {ANNOTATION_MARKER}: <why the "
+              "helper cannot serve>'")
+        return 1
     warm_bad, warm_found = lint_warm_tier()
     warm_missing = ({SERVE_WARM_CLASS} | set(SERVE_PAGE_FUNCS)) - warm_found
     if warm_missing:
@@ -1561,6 +1620,7 @@ def main() -> int:
           f"evloop non-blocking lint OK ({', '.join(EVLOOP_FILES)}); "
           f"sans-IO import lint OK ({SANSIO_FILE}); "
           f"span-emission lint OK ({', '.join(SPAN_EMIT_FILES)}); "
+          f"profiler-annotation lint OK (confined to {ANNOTATION_MODULE}); "
           f"warm-tier lint OK ({SERVE_WARM_CLASS}, "
           f"{', '.join(SERVE_PAGE_FUNCS)}); "
           f"native-wire lint OK ({NATIVE_WIRE_MODULE} seam, "
