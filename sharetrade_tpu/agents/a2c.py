@@ -40,8 +40,7 @@ def make_a2c_agent(model: Model, env: TradingEnv,
         params = model.init(k_params)
         return TrainState(
             params=params, opt_state=optimizer.init(params),
-            carry=precision.cast_carry(
-                batched_carry(model, num_agents), model),
+            carry=batched_carry(model, num_agents, precision),
             env_state=batched_reset(env, num_agents),
             rng=k_rng, env_steps=jnp.int32(0), updates=jnp.int32(0),
         )
@@ -50,7 +49,7 @@ def make_a2c_agent(model: Model, env: TradingEnv,
         # ONE compute-dtype weight copy per chunk update (precision.py);
         # the update applies to the fp32 masters. Identity in fp32 mode.
         params_c = precision.cast_compute(ts.params)
-        ts, traj, bootstrap, init_carry = collect_rollout(
+        ts, traj, bootstrap, replay_init = collect_rollout(
             model, env, ts, unroll, num_agents, params=params_c)
         returns = discounted_returns(traj.reward, traj.active,
                                      bootstrap, cfg.gamma)
@@ -59,7 +58,7 @@ def make_a2c_agent(model: Model, env: TradingEnv,
 
         def loss_fn(params):
             logits, values, aux = replay_forward(
-                model, params, traj, init_carry, remat=cfg.remat)
+                model, params, traj, replay_init, remat=cfg.remat)
             log_probs = jax.nn.log_softmax(logits)
             logp = jnp.take_along_axis(
                 log_probs, traj.action[..., None], axis=-1)[..., 0]

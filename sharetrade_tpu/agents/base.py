@@ -58,7 +58,13 @@ class Agent:
     ``model`` carries the policy network the learner was built around so the
     runtime evaluates exactly what was trained (rebuilding from config would
     silently evaluate a different architecture when a custom model was
-    injected)."""
+    injected).
+
+    ``replay_carry_bytes`` is set by learners whose update phase gathers
+    the unroll-start carry per minibatch (PPO): the bytes one minibatch
+    gathers, from the shapes of the model's ``replay_carry``
+    (agents/rollout.py). The orchestrator exports it as the gauge
+    ``train_replay_carry_bytes_per_minibatch``."""
 
     name: str
     init: Callable[[jax.Array], TrainState]
@@ -66,6 +72,7 @@ class Agent:
     num_agents: int
     steps_per_chunk: int
     model: Any = None
+    replay_carry_bytes: int | None = None
 
 
 def megachunk_step(step_fn: Callable[[TrainState],
@@ -212,8 +219,16 @@ def batched_reset(env: TradingEnv, num_agents: int):
                         single)
 
 
-def batched_carry(model, num_agents: int):
+def batched_carry(model, num_agents: int, precision=None):
+    """The model's carry seed for ``num_agents`` agents. ``precision``
+    (precision.py ``cast_carry``) is applied to the ONE-agent seed, before
+    the broadcast: ``agent.init`` runs eagerly, and casting the batch
+    afterwards held the float32 K/V caches beside their bf16 copy — three
+    times the carry, and the whole run's peak device memory in both
+    training cells of the benchmark (PERF.md, PR 25)."""
     carry = model.init_carry()
+    if precision is not None:
+        carry = precision.cast_carry(carry, model)
     return jax.tree.map(lambda x: jnp.broadcast_to(x, (num_agents,) + x.shape),
                         carry)
 

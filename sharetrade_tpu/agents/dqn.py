@@ -179,8 +179,7 @@ def make_dqn_agent(model: Model, env: TradingEnv,
             DQNExtras(target_params=target, replay=replay))
         return TrainState(
             params=params, opt_state=optimizer.init(params),
-            carry=precision.cast_carry(
-                batched_carry(model, num_agents), model),
+            carry=batched_carry(model, num_agents, precision),
             env_state=batched_reset(env, num_agents),
             rng=k_rng, env_steps=jnp.int32(0), updates=jnp.int32(0),
             extras=extras,

@@ -39,8 +39,7 @@ def make_pg_agent(model: Model, env: TradingEnv,
         params = model.init(k_params)
         return TrainState(
             params=params, opt_state=optimizer.init(params),
-            carry=precision.cast_carry(
-                batched_carry(model, num_agents), model),
+            carry=batched_carry(model, num_agents, precision),
             env_state=batched_reset(env, num_agents),
             rng=k_rng, env_steps=jnp.int32(0), updates=jnp.int32(0),
         )
@@ -50,7 +49,7 @@ def make_pg_agent(model: Model, env: TradingEnv,
         # rollout forwards, loss replay and backward all read it; the
         # update applies to the fp32 masters. Identity in fp32 mode.
         params_c = precision.cast_compute(ts.params)
-        ts, traj, bootstrap, init_carry = collect_rollout(
+        ts, traj, bootstrap, replay_init = collect_rollout(
             model, env, ts, unroll, num_agents, params=params_c)
         returns = discounted_returns(traj.reward, traj.active,
                                      bootstrap, cfg.gamma)
@@ -62,7 +61,7 @@ def make_pg_agent(model: Model, env: TradingEnv,
             adv = normalize_advantages_masked(adv, weight, denom)
 
         def loss_fn(params):
-            logits, _, aux = replay_forward(model, params, traj, init_carry,
+            logits, _, aux = replay_forward(model, params, traj, replay_init,
                                             remat=cfg.remat)
             logp = jnp.take_along_axis(
                 jax.nn.log_softmax(logits), traj.action[..., None], axis=-1

@@ -7,7 +7,10 @@ Python loops — XLA sees a single static program).
 Recurrence: minibatches cut across the *agent* axis, never the time axis, so
 each minibatch replays full sequences from the unroll's initial carry and
 LSTM gradients flow through time correctly (the standard sequence-preserving
-PPO+RNN scheme).
+PPO+RNN scheme). What the loop holds and gathers of that carry is the
+model's ``replay_carry`` of it (agents/rollout.py): the whole carry for an
+LSTM, ``hist``/``t``/health for the episode transformer — never its K/V
+caches, which the replay does not read.
 """
 
 from __future__ import annotations
@@ -21,7 +24,7 @@ from sharetrade_tpu.agents.base import (
 )
 from sharetrade_tpu.agents.rollout import (
     collect_rollout, gae_advantages, normalize_advantages_masked,
-    replay_forward,
+    replay_carry, replay_forward,
 )
 from sharetrade_tpu.config import LearnerConfig
 from sharetrade_tpu.env.core import TradingEnv
@@ -69,14 +72,22 @@ def make_ppo_agent(model: Model, env: TradingEnv,
             "ppo_minibatches=%d does not divide num_agents=%d; using %d",
             cfg.ppo_minibatches, num_agents, num_minibatches)
     mb_size = num_agents // num_minibatches
+    # What says the trimmed replay carry engaged, fixed at build: the bytes
+    # of unroll-start carry one minibatch gathers (the orchestrator exports
+    # it as a gauge). The episode transformer reads kilobytes a row where
+    # its whole carry is megabytes; an LSTM reads its whole carry.
+    replay_carry_bytes = sum(
+        leaf.size * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(jax.eval_shape(
+            lambda: replay_carry(
+                model, batched_carry(model, mb_size, precision)))))
 
     def init(key: jax.Array) -> TrainState:
         k_params, k_rng = jax.random.split(key)
         params = model.init(k_params)
         return TrainState(
             params=params, opt_state=optimizer.init(params),
-            carry=precision.cast_carry(
-                batched_carry(model, num_agents), model),
+            carry=batched_carry(model, num_agents, precision),
             env_state=batched_reset(env, num_agents),
             rng=k_rng, env_steps=jnp.int32(0), updates=jnp.int32(0),
         )
@@ -111,7 +122,7 @@ def make_ppo_agent(model: Model, env: TradingEnv,
         # (precision.py cast_compute — identity in fp32 mode); each
         # minibatch update below casts its own fresh copy of the
         # just-updated masters.
-        ts, traj, bootstrap, init_carry = collect_rollout(
+        ts, traj, bootstrap, replay_init = collect_rollout(
             model, env, ts, unroll, num_agents,
             params=precision.cast_compute(ts.params))
         advantages = gae_advantages(traj.reward, traj.value, traj.active,
@@ -127,9 +138,9 @@ def make_ppo_agent(model: Model, env: TradingEnv,
             # in/out shardings and the parallel layer's seam pins
             # (parallel/sharding.py constrain_train_state).
             replicated = _replicated(seam_mesh)
-            traj, init_carry, advantages, returns = jax.tree.map(
+            traj, replay_init, advantages, returns = jax.tree.map(
                 lambda x: jax.lax.with_sharding_constraint(x, replicated),
-                (traj, init_carry, advantages, returns))
+                (traj, replay_init, advantages, returns))
 
         def epoch_body(carry, _):
             params, opt_state, rng = carry
@@ -145,7 +156,7 @@ def make_ppo_agent(model: Model, env: TradingEnv,
                     idx = jax.lax.dynamic_slice_in_dim(
                         perm, mb_idx * mb_size, mb_size)
                     traj_mb = jax.tree.map(lambda x: x[:, idx], traj)
-                    carry_mb = jax.tree.map(lambda x: x[idx], init_carry)
+                    carry_mb = jax.tree.map(lambda x: x[idx], replay_init)
                     adv_mb, ret_mb = advantages[:, idx], returns[:, idx]
                 if seam_mesh is not None:
                     # Pin the GATHERED slices replicated as well: GSPMD
@@ -204,4 +215,5 @@ def make_ppo_agent(model: Model, env: TradingEnv,
         return ts, metrics
 
     return Agent(name="ppo", init=init, step=step,
-                 num_agents=num_agents, steps_per_chunk=unroll, model=model)
+                 num_agents=num_agents, steps_per_chunk=unroll, model=model,
+                 replay_carry_bytes=replay_carry_bytes)

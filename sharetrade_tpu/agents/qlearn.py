@@ -55,8 +55,7 @@ def make_qlearn_agent(model: Model, env: TradingEnv,
         return TrainState(
             params=params,
             opt_state=optimizer.init(params),
-            carry=precision.cast_carry(
-                batched_carry(model, num_agents), model),
+            carry=batched_carry(model, num_agents, precision),
             env_state=batched_reset(env, num_agents),
             rng=k_rng,
             env_steps=jnp.int32(0),

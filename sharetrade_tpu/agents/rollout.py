@@ -3,8 +3,9 @@
 One ``lax.scan`` gathers a ``(T, B, ...)`` trajectory block for the whole
 agent batch — the TPU inversion of the reference's per-step worker↔learner
 mailbox round-trips (SURVEY.md §7.2). Losses recompute the forward pass from
-the stored observations (and the unroll's *initial* recurrent carry, so
-recurrent policies differentiate through time correctly).
+the stored observations (and what the model's replay reads of the unroll's
+*initial* recurrent carry — ``replay_carry`` — so recurrent policies
+differentiate through time correctly).
 """
 
 from __future__ import annotations
@@ -44,15 +45,28 @@ def supports_precomputed_trunk(model: Model, env: TradingEnv) -> bool:
             and env.num_assets == 1 and env.step_priced is not None)
 
 
+def replay_carry(model: Model, carry):
+    """What the model's training replay reads of the unroll-start ``carry``
+    (models/core.py ``Model.replay_carry``; the whole carry where the model
+    declares nothing) — the ONE carry argument :func:`replay_forward`
+    takes. The rollout computes it once per chunk from the state the
+    unroll starts from, so whatever a learner's update phase then holds,
+    gathers per minibatch or pins across a mesh seam is this tree and not,
+    for the episode transformer, a per-agent K/V cache it never reads."""
+    return carry if model.replay_carry is None else model.replay_carry(carry)
+
+
 def collect_rollout(model: Model, env: TradingEnv,
                     ts: TrainState, unroll_len: int, num_agents: int,
                     params=None):
     """Roll the policy forward ``unroll_len`` steps.
 
-    Returns ``(new_ts, traj, bootstrap_value, init_carry)`` where ``traj``
+    Returns ``(new_ts, traj, bootstrap_value, replay_init)`` where ``traj``
     stacks :class:`StepData` along a leading time axis, ``bootstrap_value`` is
-    V(s_T) for return bootstrapping, and ``init_carry`` is the recurrent state
-    the unroll started from (needed to replay the forward pass in losses).
+    V(s_T) for return bootstrapping, and ``replay_init`` is
+    :func:`replay_carry` of the recurrent state the unroll started from
+    (what :func:`replay_forward` needs to replay the forward pass in
+    losses; the state itself for models that declare no ``replay_carry``).
 
     ``params`` overrides the weights the rollout forwards read — the
     precision policy's compute copy (precision.py cast_compute); the fp32
@@ -71,7 +85,7 @@ def collect_rollout(model: Model, env: TradingEnv,
             model, env, ts, unroll_len, num_agents, params=params)
     params = ts.params if params is None else params
     horizon = env.num_steps
-    init_carry = ts.carry
+    replay_init = replay_carry(model, ts.carry)
 
     def one_step(carry, _):
         env_state, model_carry, rng = carry
@@ -125,7 +139,7 @@ def collect_rollout(model: Model, env: TradingEnv,
     steps_taken = jnp.sum(jnp.any(traj.active > 0, axis=1)).astype(jnp.int32)
     new_ts = ts.replace(env_state=env_state, carry=model_carry, rng=rng,
                         env_steps=ts.env_steps + steps_taken)
-    return new_ts, traj, bootstrap, init_carry
+    return new_ts, traj, bootstrap, replay_init
 
 
 def _trunk_precompute(model: Model, env: TradingEnv, params, state1, carry1,
@@ -182,7 +196,6 @@ def _collect_rollout_precomputed(model: Model, env: TradingEnv,
     """
     params = ts.params if params is None else params
     horizon = env.num_steps
-    init_carry = ts.carry
     window = model.obs_dim - 2
 
     # ---- bulk precompute (everything scalar-unit-hostile hoisted out of
@@ -208,7 +221,14 @@ def _collect_rollout_precomputed(model: Model, env: TradingEnv,
     # carry, the broadcast NaN trunk makes the chunk's loss non-finite and
     # the orchestrator's detector escalates to restore — correct when the
     # whole batch is beyond a row-level heal.
+    #
+    # The replay's view of the same carry is taken HERE, beside the
+    # election: its health vector is rows_finite of the same array, so the
+    # chunk makes one is-finite pass over the K/V caches (the two identical
+    # reductions merge), outside the learners' epoch/minibatch scans —
+    # tests/test_chip_compile.py holds the compiled step to it.
     with jax.named_scope("rows_finite"):
+        replay_init = replay_carry(model, ts.carry)
         rep = jnp.argmax(
             election_health(ts.env_state, ts.carry)).astype(jnp.int32)
     take_rep = lambda x: jax.lax.dynamic_index_in_dim(x, rep, 0,
@@ -306,7 +326,7 @@ def _collect_rollout_precomputed(model: Model, env: TradingEnv,
     steps_taken = jnp.sum(jnp.any(traj.active > 0, axis=1)).astype(jnp.int32)
     new_ts = ts.replace(env_state=env_state, carry=new_model_carry, rng=rng,
                         env_steps=ts.env_steps + steps_taken)
-    return new_ts, traj, bootstrap, init_carry
+    return new_ts, traj, bootstrap, replay_init
 
 
 def greedy_rollout_precomputed(model: Model, env: TradingEnv, params,
@@ -360,13 +380,22 @@ def greedy_rollout_precomputed(model: Model, env: TradingEnv, params,
 _MAX_FOLD_ROWS = 2048
 
 
-def replay_forward(model: Model, params: Any, traj: StepData, init_carry,
+def replay_forward(model: Model, params: Any, traj: StepData, replay_init,
                    *, remat: bool = False):
     """Recompute ``(logits, values, aux)`` along a stored trajectory under
     ``params``, threading the recurrent carry — the differentiable forward
     for losses. ``aux`` is the mean of the model's auxiliary loss over the
     replay (ModelOut.aux — the MoE balance term; 0 for dense models), which
     losses weight by ``LearnerConfig.aux_loss_coef``.
+
+    ``replay_init`` is :func:`replay_carry` of the carry the unroll started
+    from — ``collect_rollout``'s fourth result, or its rows for a
+    minibatch of agents. For most models that is the carry itself (an LSTM
+    replays from every leaf of it); a model that declares
+    ``Model.replay_carry`` gets the leaves its replay reads, with row
+    health (``rows_finite`` of the whole unroll-start carry) already
+    folded in by the model's own hook where its representative election
+    needs one: nothing here, and no learner, scans a carry for NaNs.
 
     Stateless models (MLP, transformer — empty carry) have no step-to-step
     data dependence, so the (T, B) trajectory folds into one big batch
@@ -394,7 +423,7 @@ def replay_forward(model: Model, params: Any, traj: StepData, init_carry,
         fwd = model.apply_unroll_shared
         if remat:
             fwd = jax.checkpoint(fwd)
-        return fwd(params, traj.obs, init_carry)
+        return fwd(params, traj.obs, replay_init)
     if model.apply_unroll is not None:
         # The model replays a whole trajectory natively (episode-mode
         # transformer: one banded pass over the unroll's tick sequence
@@ -402,9 +431,9 @@ def replay_forward(model: Model, params: Any, traj: StepData, init_carry,
         fwd = model.apply_unroll
         if remat:
             fwd = jax.checkpoint(fwd)
-        return fwd(params, traj.obs, init_carry)
+        return fwd(params, traj.obs, replay_init)
 
-    stateless = not jax.tree.leaves(init_carry)
+    stateless = not jax.tree.leaves(replay_init)
     if stateless:
         t, b = traj.obs.shape[:2]
         # Largest divisor of T whose folded rows stay under the cap.
@@ -416,7 +445,7 @@ def replay_forward(model: Model, params: Any, traj: StepData, init_carry,
             # (fold, b, D) -> (b, fold, D) -> (b*fold, D): batch-major merge.
             flat = obs_g.swapaxes(0, 1).reshape(
                 (b * fold,) + obs_g.shape[2:])
-            outs, _ = apply_batched(model, params, flat, init_carry)
+            outs, _ = apply_batched(model, params, flat, replay_init)
             return (outs.logits.reshape(b, fold, -1).swapaxes(0, 1),
                     outs.value.reshape(b, fold).swapaxes(0, 1),
                     jnp.mean(jnp.asarray(outs.aux)))
@@ -443,7 +472,7 @@ def replay_forward(model: Model, params: Any, traj: StepData, init_carry,
         return new_carry, (outs.logits, outs.value,
                            jnp.mean(jnp.asarray(outs.aux)))
 
-    _, (logits, values, aux) = jax.lax.scan(one_step, init_carry, traj.obs)
+    _, (logits, values, aux) = jax.lax.scan(one_step, replay_init, traj.obs)
     return logits, values, jnp.mean(aux)  # (T, B, A), (T, B), scalar
 
 

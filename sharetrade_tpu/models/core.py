@@ -53,7 +53,8 @@ class Model:
     # gets vmap of `apply` via `apply_batched`.
     apply_batch: Callable[[Any, jax.Array, Any], tuple[ModelOut, Any]] | None = None
     # Optional whole-unroll training forward (params, (T, B, obs_dim) obs,
-    # unroll-start carry_batch) -> (logits (T, B, A), values (T, B), aux).
+    # unroll-start carry_batch, or its ``replay_carry`` where the model
+    # declares one) -> (logits (T, B, A), values (T, B), aux).
     # Models that can replay a trajectory more cheaply than T per-step
     # forwards provide this (the episode-mode transformer runs ONE banded
     # pass over the unroll's tick sequence); rollout.replay_forward
@@ -80,7 +81,8 @@ class Model:
     apply_rollout_head: Callable[[Any, jax.Array, jax.Array],
                                  ModelOut] | None = None
     # Optional SHARED-TRUNK training replay: same signature and output as
-    # apply_unroll, but exploiting the same agent-invariance as the
+    # apply_unroll (``carry`` is the REPLAY carry, below: the health vector
+    # ``ok`` rides in it), but exploiting the same agent-invariance as the
     # precomputed-rollout pair — every healthy agent's stored price series
     # is identical (lockstep batch over one shared series; quarantined rows
     # are zero-sanitized and loss-masked), so the banded trunk runs ONCE
@@ -94,6 +96,20 @@ class Model:
     # invariant (see agents/rollout.py agent-invariance notes).
     apply_unroll_shared: Callable[[Any, jax.Array, Any],
                                   tuple[jax.Array, jax.Array, jax.Array]] | None = None
+    # Optional REPLAY CARRY: replay_carry(unroll-start carry_batch) -> tree,
+    # every leaf batched over agents: what the model's training replay
+    # (apply_unroll / apply_unroll_shared) reads of the carry the unroll
+    # started from, with any per-row summary of the rest folded in. The
+    # rollout takes it ONCE per chunk (rollout.replay_carry) and it is the
+    # ``carry`` the replay forwards receive, so a learner that holds the
+    # unroll start across its update phase (PPO gathers it per minibatch)
+    # moves these leaves and nothing else. None = the replay reads the
+    # whole carry (LSTM: differentiated through time from every leaf).
+    # The episode transformer's replay reads ``hist`` and ``t`` only, plus
+    # ``ok`` — ``rows_finite`` of the WHOLE carry, K/V included, computed
+    # here — for the shared replay's representative election; its
+    # per-agent K/V cache (megabytes a row) stays out of the update phase.
+    replay_carry: Callable[[Any], Any] | None = None
     # Optional LINEARITY-FACTORED rollout head. When the head is affine in
     # (trunk output, portfolio features) — logits = dense(policy,
     # hn + dense(port, feats)) with no nonlinearity between — it splits
@@ -173,8 +189,9 @@ def rows_finite(tree: Any, batch: int) -> jax.Array:
     finite. THE row-finiteness predicate behind the fault-quarantine
     story — shared by the heal/election predicate
     (agents/base.election_health) and the shared-trunk replay's
-    representative election (models/transformer_episode.apply_unroll_shared)
-    so the two can never silently diverge. Leaves whose leading dim is not
+    representative election (the ``ok`` leaf of the episode transformer's
+    ``Model.replay_carry``, read by its apply_unroll_shared) so the two can
+    never silently diverge. Leaves whose leading dim is not
     ``batch`` (unbatched scalars/tables) are ignored; integer leaves pass
     trivially (isfinite is all-True on ints)."""
     ok = jnp.ones((batch,), bool)

@@ -722,8 +722,8 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
         """Training replay: ONE banded pass over [history | chunk ticks].
 
         ``obs`` is the stored (T, B, obs_dim) trajectory; ``carry`` the
-        batched episode carry at unroll START (PPO already threads exactly
-        this for recurrent policies). Returns (logits (T, B, A),
+        batched episode carry at unroll START, or its ``replay_carry``:
+        only ``t`` and ``hist`` are read. Returns (logits (T, B, A),
         values (T, B), aux scalar).
         """
         t_len, bsz = obs.shape[0], obs.shape[1]
@@ -763,13 +763,25 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
         wv = params["value"]["w"].astype(jnp.float32)     # precision-cast-ok
         return wp @ wl, bp @ wl, (wp @ wv)[:, 0], (bp @ wv)[0]
 
+    def replay_carry(carry):
+        """What the replays read of the unroll-start carry (models/core.py
+        Model.replay_carry): ``hist`` and ``t``, and ``ok`` — row health of
+        the WHOLE carry, K/V included, by THE predicate ``rows_finite`` —
+        which the shared replay's election needs of every row. Taken once
+        per chunk by the rollout; the K/V cache (L x H x window x Dh a
+        row) never enters the update phase."""
+        return {"hist": carry["hist"], "t": carry["t"],
+                "ok": rows_finite(carry, carry["t"].shape[0])}
+
     def apply_unroll_shared(params, obs, carry):
         """Training replay with the trunk's factor-B agent redundancy
         removed: every healthy agent's price series is IDENTICAL (the
         lockstep-batch agent-invariance of agents/rollout.py), so the
         banded pass of ``apply_unroll`` runs ONCE for a representative row
-        and only the portfolio-feature head runs per agent. Same signature
-        and outputs as ``apply_unroll``; gradients are exact (B identical
+        and only the portfolio-feature head runs per agent. Same outputs
+        as ``apply_unroll``; ``carry`` is the REPLAY carry
+        (``replay_carry`` above: ``hist``, ``t`` and the health vector
+        ``ok``), never the K/V cache. Gradients are exact (B identical
         trunk paths each pulled back by one agent's head cotangent equal
         one shared path pulled back by their sum).
 
@@ -784,26 +796,28 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
         every row is partially quarantined the longest-healthy row
         corrupts the fewest unmasked steps — an all-steps predicate would
         instead fall back to row 0, which could be a fully-zeroed row.
-        Rows whose unroll-start carry is non-finite are excluded outright
-        (the rollout election's carry term, agents/base.election_health):
-        a NaN carry['hist']/['t'] would poison the ONE shared banded pass
-        for every agent. If every carry is poisoned, row 0 wins and the
-        non-finite loss escalates to the orchestrator's restore — correct
-        when the whole batch is beyond a row-level heal.
+        Rows whose unroll-start carry is non-finite (``carry["ok"]``
+        False: the rollout election's carry term,
+        agents/base.election_health, over the same array) are excluded
+        outright: a NaN carry['hist']/['t'] would poison the ONE shared
+        banded pass for every agent, and a row whose K/V alone is NaN is
+        no representative the rollout would have taken either. If every
+        carry is poisoned, row 0 wins and the non-finite loss escalates to
+        the orchestrator's restore — correct when the whole batch is
+        beyond a row-level heal.
         """
-        t_len, bsz = obs.shape[0], obs.shape[1]
+        t_len = obs.shape[0]
         counts = jnp.sum(obs[:, :, window - 1] > 0, axis=0)
-        carry_ok = rows_finite(carry, bsz)
-        rep = jnp.argmax(jnp.where(carry_ok, counts, -1)).astype(jnp.int32)
+        rep = jnp.argmax(
+            jnp.where(carry["ok"], counts, -1)).astype(jnp.int32)
         obs1 = jax.lax.dynamic_index_in_dim(obs, rep, 1, keepdims=True)
-        carry1 = jax.tree.map(
-            lambda x: jax.lax.dynamic_index_in_dim(x, rep, 0, keepdims=True),
-            carry)
+        take_rep = lambda x: jax.lax.dynamic_index_in_dim(
+            x, rep, 0, keepdims=True)
         first_win = obs1[0, :, :window]                 # (1, W)
         newer = obs1[1:, :, window - 1].T               # (1, T-1)
-        t0 = carry1["t"].astype(jnp.int32)              # (1,)
+        t0 = take_rep(carry["t"]).astype(jnp.int32)     # (1,)
         hist = _pin_hist(jnp.where((t0 == 0)[:, None], first_win[:, :1],
-                                   carry1["hist"]))
+                                   take_rep(carry["hist"])))
         series = jnp.concatenate([hist, first_win, newer], axis=1)
         s_len = hist_len + window + t_len - 1
         positions = (t0[:, None] - hist_len
@@ -937,6 +951,7 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
                  apply_prefill=_prefill,
                  apply_serve_batch=_incremental_serve,
                  apply_unroll_shared=apply_unroll_shared,
+                 replay_carry=replay_carry,
                  apply_rollout_trunk=apply_rollout_trunk,
                  apply_rollout_head=apply_rollout_head,
                  rollout_head_factored=rollout_head_factored,

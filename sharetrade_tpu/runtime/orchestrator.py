@@ -385,6 +385,9 @@ class Orchestrator:
                 initial_budget=self.cfg.env.initial_budget,
                 initial_shares=self.cfg.env.initial_shares)
         self.agent = build_agent(self.cfg, self.env, mesh=self.mesh)
+        if self.agent.replay_carry_bytes is not None:
+            self.metrics.record("train_replay_carry_bytes_per_minibatch",
+                                self.agent.replay_carry_bytes)
         self._build_step()
         self._eval_fn = None   # env/model changed: retrace on next evaluate
         template = self.agent.init(jax.random.PRNGKey(self.cfg.seed))

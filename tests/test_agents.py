@@ -361,3 +361,99 @@ def test_recurrent_and_attention_policies_with_ppo(kind):
         h0 = np.asarray(ts.carry[0])
         h1 = np.asarray(ts2.carry[0])
         assert not np.allclose(h0, h1)
+
+
+# -- what the PPO minibatch loop holds of the unroll-start carry ------------
+
+def _episode_ppo_config():
+    cfg = tiny_config("ppo")
+    cfg.model.kind, cfg.model.seq_mode = "transformer", "episode"
+    cfg.model.num_layers = 2            # hist_len > 0: the carry has a hist
+    cfg.learner.ppo_minibatches = 2     # 4 agents: minibatches of 2
+    return cfg
+
+
+def _carry_bytes(carry) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(carry))
+
+
+def test_ppo_chunk_same_params_from_trimmed_or_whole_carry():
+    """The loop gathers the model's ``replay_carry`` of the unroll-start
+    carry; gathering the WHOLE carry (a test-only model whose hook keeps
+    every leaf and adds the same health vector) trains to the same
+    parameters, over a prefill chunk and a carry-crossing one: the K/V
+    caches were never an input of the replay."""
+    import dataclasses
+    from sharetrade_tpu.models.core import rows_finite
+
+    cfg = _episode_ppo_config()
+    trimmed = build_agent(cfg, tiny_env())
+    whole_model = dataclasses.replace(
+        trimmed.model, replay_carry=lambda c: {
+            **c, "ok": rows_finite(c, c["t"].shape[0])})
+    whole = build_agent(cfg, tiny_env(), model=whole_model)
+    assert whole.replay_carry_bytes > 10 * trimmed.replay_carry_bytes
+
+    results = []
+    for agent in (trimmed, whole):
+        ts = agent.init(jax.random.PRNGKey(3))
+        step = jax.jit(agent.step)
+        ts, _ = step(ts)
+        ts, metrics = step(ts)
+        assert np.isfinite(float(metrics["loss"]))
+        results.append(jax.device_get(ts.params))
+    for a, b in zip(*map(jax.tree.leaves, results)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("kind", ["transformer_episode", "lstm", "mlp"])
+def test_replay_carry_bytes_gauge(kind, tmp_path):
+    """The build-time gauge reads what one minibatch gathers of the
+    unroll-start carry: ``hist``, ``t`` and the health bit for the episode
+    transformer (its K/V caches stay out), the whole carry for an LSTM,
+    nothing for a stateless policy — and the orchestrator exports it."""
+    from sharetrade_tpu.agents.base import batched_carry
+    from sharetrade_tpu.runtime import Orchestrator
+
+    cfg = _episode_ppo_config()
+    if kind != "transformer_episode":
+        cfg.model.kind, cfg.model.seq_mode = kind, "window"
+    agent = build_agent(cfg, tiny_env())
+    mb_size = cfg.parallel.num_workers // cfg.learner.ppo_minibatches
+    carry = batched_carry(agent.model, mb_size)
+    if kind == "transformer_episode":
+        want = _carry_bytes((carry["hist"], carry["t"])) + mb_size  # + ok
+        assert _carry_bytes((carry["k"], carry["v"])) > 10 * want
+    else:
+        want = _carry_bytes(carry)
+        assert (want > 0) == (kind == "lstm")
+    assert agent.replay_carry_bytes == want
+
+    cfg.runtime.checkpoint_dir = str(tmp_path / "ckpts")
+    orch = Orchestrator(cfg)
+    try:
+        orch.send_training_data(np.linspace(10.0, 20.0, 64, dtype=np.float32))
+        assert orch.metrics.latest(
+            "train_replay_carry_bytes_per_minibatch") == want
+    finally:
+        orch.stop()
+
+
+def test_batched_carry_casts_the_seed_before_broadcasting():
+    """``agent.init`` runs eagerly: the precision policy's carry cast is
+    applied to the one-agent seed, so no float32 batch of K/V caches ever
+    exists beside its bf16 copy — leaf for leaf the same state as casting
+    the batch."""
+    from sharetrade_tpu.agents.base import batched_carry
+    from sharetrade_tpu.precision import policy_from_config
+
+    cfg = _episode_ppo_config()
+    cfg.precision.mode = "bf16_mixed"
+    precision = policy_from_config(cfg.precision)
+    model = build_agent(cfg, tiny_env()).model
+    got = batched_carry(model, 4, precision)
+    want = precision.cast_carry(batched_carry(model, 4), model)
+    assert got["k"].dtype == jnp.bfloat16 and got["hist"].dtype == jnp.float32
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

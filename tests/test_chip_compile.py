@@ -185,15 +185,51 @@ def _wide_agent(cfg, mesh=None):
     return chip_smoke.wide_agent(cfg, mesh=mesh)
 
 
-def test_agent_step_compiles_for_one_v5e(one_chip, tpu_backend):
-    agent = _wide_agent(_wide_config())
-    ts = jax.eval_shape(agent.init, jax.random.PRNGKey(0))
-    compiled = jax.jit(agent.step, donate_argnums=(0,)).lower(
-        _on(one_chip, ts)).compile()
+@pytest.fixture(scope="module")
+def wide_step(one_chip):
+    """The d=1024 PPO step compiled once for one described chip: the
+    agent, its state's shapes and the compiled program."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        agent = _wide_agent(_wide_config())
+        ts = jax.eval_shape(agent.init, jax.random.PRNGKey(0))
+        compiled = jax.jit(agent.step, donate_argnums=(0,)).lower(
+            _on(one_chip, ts)).compile()
+    return agent, ts, compiled
+
+
+def test_agent_step_compiles_for_one_v5e(wide_step):
+    _, _, compiled = wide_step
     assert _mosaic_calls(compiled) > 0
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 1024 ** 3)       # one v5e chip's HBM
+
+
+def test_agent_step_moves_no_kv_cache_per_minibatch(wide_step):
+    """The shared-trunk replay reads ``hist``, ``t`` and row health of the
+    unroll-start carry, so the update phase holds no K/V cache: nothing in
+    the compiled step is minibatch-by-K/V shaped (the parent gathered
+    ``[mb, L, H, W, Dh]`` 16 times a chunk and scanned it for NaNs each
+    time: over half of the d=1024 chunk on the chip, PERF.md PR 25), and
+    the caches are checked for finiteness at most once, by the rollout's
+    election, whose pass the replay's health vector shares."""
+    import re
+    agent, ts, compiled = wide_step
+    text = compiled.as_text()
+    batch, layers, heads, width, head_dim = ts.carry["k"].shape
+    mb = batch // 4                     # learner.ppo_minibatches
+    assert agent.replay_carry_bytes < 1 << 20
+    # XLA splits the window axis (201 = 128 + 73): match any width.
+    per_mb = re.findall(
+        rf"\[{mb},{layers},{heads},\d+,{head_dim}\]", text)
+    assert not per_mb, f"{len(per_mb)} minibatch-by-K/V shaped values"
+    checked = sum(
+        batch * layers * heads * int(w) * head_dim
+        for w in re.findall(
+            rf"= pred\[{batch},{layers},{heads},(\d+),{head_dim}\]\S* "
+            r"is-finite\(", text))
+    assert 0 < checked <= 2 * batch * layers * heads * width * head_dim
 
 
 def _window_config():

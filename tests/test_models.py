@@ -171,11 +171,16 @@ class TestEpisodeMode:
 
         for chunk in range(2):
             init_carry = ts.carry
-            ts, traj, _, carry_out = collect_rollout(
+            ts, traj, _, replay_init = collect_rollout(
                 model, env, ts, 8, agent.num_agents)
-            assert carry_out is init_carry  # replay starts from unroll start
+            # The replay starts from the unroll START: what it reads of
+            # that carry (Model.replay_carry), never the K/V caches.
+            assert set(replay_init) == {"hist", "t", "ok"}
+            assert replay_init["hist"] is init_carry["hist"]
+            assert replay_init["t"] is init_carry["t"]
+            assert np.asarray(replay_init["ok"]).all()
             logits, values, _ = replay_forward(
-                model, ts.params, traj, init_carry)
+                model, ts.params, traj, replay_init)
             logp = jnp.take_along_axis(
                 jax.nn.log_softmax(logits), traj.action[..., None],
                 axis=-1)[..., 0]
@@ -254,25 +259,30 @@ class TestEpisodeMode:
         w_agent = jnp.asarray([0.3, 1.7, 0.9])
 
         for chunk in range(2):   # prefill chunk AND a carry-crossing chunk
-            init_carry = ts.carry
-            ts, traj, _, _ = collect_rollout(model, env, ts, 8, 3)
+            # The per-agent reference replays from the WHOLE unroll-start
+            # carry, the shared path from the replay carry the rollout
+            # hands the learners.
+            whole_carry = ts.carry
+            ts, traj, _, replay_init = collect_rollout(model, env, ts, 8, 3)
 
             l_sh, v_sh, _ = model.apply_unroll_shared(
-                ts.params, traj.obs, init_carry)
-            l_pa, v_pa, _ = model.apply_unroll(ts.params, traj.obs, init_carry)
+                ts.params, traj.obs, replay_init)
+            l_pa, v_pa, _ = model.apply_unroll(
+                ts.params, traj.obs, whole_carry)
             np.testing.assert_allclose(np.asarray(l_sh), np.asarray(l_pa),
                                        atol=3e-4, err_msg=f"chunk {chunk}")
             np.testing.assert_allclose(np.asarray(v_sh), np.asarray(v_pa),
                                        atol=3e-4, err_msg=f"chunk {chunk}")
 
-            def loss(params, fwd):
-                logits, values, _ = fwd(params, traj.obs, init_carry)
+            def loss(params, fwd, carry):
+                logits, values, _ = fwd(params, traj.obs, carry)
                 lp = jax.nn.log_softmax(logits)
                 return (jnp.sum(lp[..., 0] * w_agent[None, :])
                         + jnp.sum(jnp.square(values) * w_agent[None, :]))
 
-            g_sh = jax.grad(loss)(ts.params, model.apply_unroll_shared)
-            g_pa = jax.grad(loss)(ts.params, model.apply_unroll)
+            g_sh = jax.grad(loss)(ts.params, model.apply_unroll_shared,
+                                  replay_init)
+            g_pa = jax.grad(loss)(ts.params, model.apply_unroll, whole_carry)
             for p_sh, p_pa in zip(jax.tree.leaves(g_sh),
                                   jax.tree.leaves(g_pa)):
                 # rtol accommodates backend reduction-order noise (TPU
@@ -293,15 +303,14 @@ class TestEpisodeMode:
         _, agent, env = self._setup(num_agents=3)
         model = agent.model
         ts = agent.init(jax.random.PRNGKey(0))
-        init_carry = ts.carry
-        ts, traj, _, _ = collect_rollout(model, env, ts, 8, 3)
+        ts, traj, _, replay_init = collect_rollout(model, env, ts, 8, 3)
         zeroed = traj._replace(
             obs=traj.obs.at[:, 0].set(0.0),
             active=traj.active.at[:, 0].set(0.0))
 
         l_sh, v_sh, _ = model.apply_unroll_shared(
-            ts.params, zeroed.obs, init_carry)
-        l_pa, v_pa, _ = model.apply_unroll(ts.params, traj.obs, init_carry)
+            ts.params, zeroed.obs, replay_init)
+        l_pa, v_pa, _ = model.apply_unroll(ts.params, traj.obs, replay_init)
         assert np.isfinite(np.asarray(l_sh)).all()
         assert np.isfinite(np.asarray(v_sh)).all()
         # Healthy rows replay exactly as if the zeroed row were absent.
@@ -322,16 +331,15 @@ class TestEpisodeMode:
         _, agent, env = self._setup(num_agents=3)
         model = agent.model
         ts = agent.init(jax.random.PRNGKey(0))
-        init_carry = ts.carry
-        ts, traj, _, _ = collect_rollout(model, env, ts, 8, 3)
+        ts, traj, _, replay_init = collect_rollout(model, env, ts, 8, 3)
         # Row 0 healthy through step 3, zeroed from step 4 onward.
         zeroed = traj._replace(
             obs=traj.obs.at[4:, 0].set(0.0),
             active=traj.active.at[4:, 0].set(0.0))
 
         l_sh, v_sh, _ = model.apply_unroll_shared(
-            ts.params, zeroed.obs, init_carry)
-        l_pa, v_pa, _ = model.apply_unroll(ts.params, traj.obs, init_carry)
+            ts.params, zeroed.obs, replay_init)
+        l_pa, v_pa, _ = model.apply_unroll(ts.params, traj.obs, replay_init)
         assert np.isfinite(np.asarray(l_sh)).all()
         assert np.isfinite(np.asarray(v_sh)).all()
         # Healthy rows replay exactly as if the poisoned row were absent —
@@ -372,12 +380,15 @@ class TestEpisodeMode:
         np.testing.assert_array_equal(np.asarray(ts.env_state.t[1:]),
                                       np.asarray(twin.env_state.t[1:]))
 
-    def test_nan_carry_row_not_elected_representative(self):
+    @pytest.mark.parametrize("leaf", ["k", "v", "hist"])
+    def test_nan_carry_row_not_elected_representative(self, leaf):
         """election_health ANDs model-carry finiteness into the election:
-        a row with a finite wallet but a NaN carry (K/V cache) must not be
-        elected — its carry would broadcast into the shared trunk and
-        poison every agent's windows, escalating a one-row fault to a
-        full-batch corruption."""
+        a row with a finite wallet but a NaN carry (K/V cache, or tick
+        history) must not be elected — its carry would broadcast into the
+        shared trunk and poison every agent's windows, escalating a one-row
+        fault to a full-batch corruption. The replay never reads K or V,
+        and its election refuses such a row all the same: the health
+        vector of its replay carry is rows_finite of the WHOLE carry."""
         from sharetrade_tpu.agents.rollout import collect_rollout
 
         _, agent, env = self._setup(num_agents=3)
@@ -386,13 +397,18 @@ class TestEpisodeMode:
         ts, *_ = collect_rollout(model, env, ts, 8, 3)   # chunk A: healthy
         twin = ts
 
-        k = np.asarray(ts.carry["k"]).copy()
-        k[0] = np.nan                                    # row 0 carry poisoned
-        ts = ts.replace(carry={**ts.carry, "k": jnp.asarray(k)})
+        poisoned = np.asarray(ts.carry[leaf]).copy()
+        poisoned[0] = np.nan                             # row 0 carry poisoned
+        carry = {**ts.carry, leaf: jnp.asarray(poisoned)}
+        if leaf != "hist":
+            # The NaN sits in the cache ONLY. What the replay reads of
+            # row 0 is finite, and wrong, so electing it would show in
+            # every agent's outputs and not as a NaN.
+            carry["hist"] = carry["hist"].at[0].multiply(1.5)
+        ts = ts.replace(carry=carry)
 
-        poisoned_carry = ts.carry
-        ts, traj_p, _, _ = collect_rollout(model, env, ts, 8, 3)
-        twin, traj_t, _, _ = collect_rollout(model, env, twin, 8, 3)
+        ts, traj_p, _, replay_p = collect_rollout(model, env, ts, 8, 3)
+        twin, traj_t, _, replay_t = collect_rollout(model, env, twin, 8, 3)
         assert np.isfinite(np.asarray(traj_p.obs)).all(), \
             "NaN carry broadcast into the shared trunk"
         np.testing.assert_allclose(
@@ -404,11 +420,18 @@ class TestEpisodeMode:
         # Replay-side election must skip the NaN-carry row too: every
         # row's stored obs is healthy, so an obs-only election would tie
         # at count T and elect poisoned row 0 into the ONE shared pass.
+        assert "k" not in replay_p and "v" not in replay_p
+        np.testing.assert_array_equal(np.asarray(replay_p["ok"]),
+                                      [False, True, True])
         l_sh, v_sh, _ = model.apply_unroll_shared(
-            ts.params, traj_t.obs, poisoned_carry)
-        assert np.isfinite(np.asarray(l_sh[:, 1:])).all(), \
-            "replay elected the NaN-carry representative"
-        assert np.isfinite(np.asarray(v_sh[:, 1:])).all()
+            ts.params, traj_t.obs, replay_p)
+        l_tw, v_tw, _ = model.apply_unroll_shared(
+            ts.params, traj_t.obs, replay_t)
+        np.testing.assert_allclose(
+            np.asarray(l_sh[:, 1:]), np.asarray(l_tw[:, 1:]), atol=1e-6,
+            err_msg="replay elected the NaN-carry representative")
+        np.testing.assert_allclose(
+            np.asarray(v_sh[:, 1:]), np.asarray(v_tw[:, 1:]), atol=1e-6)
 
     def test_greedy_eval_trunk_matches_incremental(self):
         """Orchestrator.evaluate()'s precomputed-trunk greedy replay must
@@ -444,9 +467,9 @@ class TestEpisodeMode:
         _, agent, env = self._setup(num_layers=1)
         model = agent.model
         ts = agent.init(jax.random.PRNGKey(1))
-        init_carry = ts.carry
-        ts, traj, _, _ = collect_rollout(model, env, ts, 8, agent.num_agents)
-        logits, values, _ = replay_forward(model, ts.params, traj, init_carry)
+        ts, traj, _, replay_init = collect_rollout(
+            model, env, ts, 8, agent.num_agents)
+        logits, values, _ = replay_forward(model, ts.params, traj, replay_init)
         logp = jnp.take_along_axis(
             jax.nn.log_softmax(logits), traj.action[..., None], axis=-1)[..., 0]
         np.testing.assert_allclose(np.asarray(logp), np.asarray(traj.logp),
@@ -495,10 +518,9 @@ class TestEpisodeMode:
             jax.random.PRNGKey(1))["blocks"][0]   # FFN is actually MoE
 
         for chunk in range(2):
-            init_carry = ts.carry
-            ts, traj, _, _ = collect_rollout(model, env, ts, 8, 3)
+            ts, traj, _, replay_init = collect_rollout(model, env, ts, 8, 3)
             logits, values, aux = replay_forward(
-                model, ts.params, traj, init_carry)
+                model, ts.params, traj, replay_init)
             logp = jnp.take_along_axis(
                 jax.nn.log_softmax(logits), traj.action[..., None],
                 axis=-1)[..., 0]
