@@ -15,6 +15,14 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 
 
+# What a module under chipbench/models/ exports (chipbench/README.md, "To
+# add a model family").
+MODEL_INTERFACE = ("sizes", "history", "init_params", "trunk",
+                   "program_cache", "further_numbers",
+                   "train_flops_per_agent_step", "serve_warm_step_flops",
+                   "replay_seq_len")
+
+
 class Refused(Exception):
     """The run cannot be made here (no chip, unknown cell): exit code 2, no
     result line."""
@@ -29,7 +37,9 @@ class Manifest:
     """``BENCHMARK.json`` plus the data files it names. ``data_dir`` holds
     ``configs/``, ``traffic/``, ``metrics/`` and ``limits/``; everything
     that belongs to one configuration, one traffic mix or one per-layer
-    metric is found there by name."""
+    metric is found there by name. A reader and a model family are code,
+    found by name in the packages ``chipbench.readers`` and
+    ``chipbench.models``."""
 
     def __init__(self, benchmark_json: str | None = None,
                  data_dir: str | None = None):
@@ -46,6 +56,23 @@ class Manifest:
 
     def config(self, name: str) -> dict:
         return load_json(os.path.join(self.data_dir, "configs", name + ".json"))
+
+    def model(self, config_doc: dict):
+        """The module of the configuration's model family: the file under
+        ``chipbench/models/`` that its ``model`` key names. No default
+        stands in for a missing key or module."""
+        name = config_doc.get("model")
+        if not name:
+            raise Refused("the configuration's file names no model")
+        try:
+            module = importlib.import_module("chipbench.models." + name)
+        except ModuleNotFoundError as exc:
+            raise Refused(f"no model family {name!r}: {exc}") from exc
+        missing = [m for m in MODEL_INTERFACE if not callable(
+            getattr(module, m, None))]
+        if missing:
+            raise Refused(f"model family {name!r} lacks {missing}")
+        return module
 
     def traffic(self, name: str) -> dict:
         return load_json(os.path.join(self.data_dir, "traffic", name + ".json"))
@@ -189,16 +216,19 @@ def assemble(ok: bool, attempted: int, failed: int, device: dict, peak: int,
 
 
 def open_cell(workload: str, suffix: str):
-    """What the chip tools share: manifest, cell, traffic, the compile cache,
-    the device check and a fresh working directory."""
+    """What the chip tools share: the cell, its configuration's file, its
+    traffic and its model family, the compile cache, the device check and a
+    fresh working directory."""
     from sharetrade_tpu.utils.runtime_env import configure_compile_cache
     manifest = Manifest()
     cell = manifest.cell(workload)
     traffic = manifest.traffic(cell["traffic"])
+    config_doc = manifest.config(cell["config"])
+    model = manifest.model(config_doc)
     configure_compile_cache()
     device = require_chip(cell["chips"])
     fresh_cwd(cell["name"] + suffix)
-    return manifest, cell, traffic, device
+    return cell, config_doc, traffic, model, device
 
 
 def percentile(values, q: float) -> float:

@@ -52,18 +52,34 @@ def median_leaf_gap(program: dict, reference: dict,
     return statistics.median(gaps)
 
 
-def cache_error(program, reference) -> float:
-    """The widest, over keys and values and over the layers, of the norm of
-    the difference between the program's cache and the reference's against
-    the reference's norm: (2, L, H, W, D) arrays in tick order. It is the
-    first chunk's rollout trunk alone, before any update: the one number
-    here that the precision moves and the training's fork does not."""
+def cache_errors(program: dict, reference: dict) -> dict:
+    """{name: per-layer array} of the norm of the difference between the
+    program's cache and the reference's against the reference's norm. The
+    named arrays are the model's own (``{"k", "v"}`` of (L, H, W, D) for
+    the episode transformer), the layer axis first, in tick order; a name
+    the program's side lacks reads infinite."""
     import numpy as np
-    p, r = np.asarray(program, np.float64), np.asarray(reference, np.float64)
-    axes = (2, 3, 4)
-    err = np.sqrt(np.sum(np.square(p - r), axis=axes))
-    return float(np.max(err / np.maximum(
-        np.sqrt(np.sum(np.square(r), axis=axes)), 1e-30)))
+    out = {}
+    for name, ref in reference.items():
+        if name not in program:
+            out[name] = np.asarray([np.inf])
+            continue
+        p, r = (np.asarray(x, np.float64) for x in (program[name], ref))
+        axes = tuple(range(1, r.ndim))
+        err = np.sqrt(np.sum(np.square(p - r), axis=axes))
+        out[name] = err / np.maximum(
+            np.sqrt(np.sum(np.square(r), axis=axes)), 1e-30)
+    return out
+
+
+def cache_error(program: dict, reference: dict) -> float:
+    """The widest of ``cache_errors`` over the names and the layers (a NaN
+    anywhere reads NaN). It is the first chunk's rollout trunk alone, before
+    any update: the one number here that the precision moves and the
+    training's fork does not."""
+    import numpy as np
+    return float(np.max([np.max(by_layer) for by_layer in cache_errors(
+        program, reference).values()]))
 
 
 def shares_gap(program, reference) -> float:
@@ -78,16 +94,18 @@ def shares_gap(program, reference) -> float:
     return float(np.mean(np.abs(p - r)) / max(np.mean(np.abs(r)), 1.0))
 
 
-def training_numbers(program: dict, reference: dict) -> dict:
+def training_numbers(program: dict, reference: dict, model) -> dict:
     """``program`` and ``reference``: ``losses`` (one per step), ``grad``
-    and ``change`` ({leaf: norm}), and after the first step ``kv`` (the
-    rolling cache) and ``shares`` (per agent). The later steps' losses are
-    not among the numbers: this configuration's training forks after its
-    first chunk (PERF.md, section 2), so the first step's loss stands for
-    them."""
+    and ``change`` ({leaf: norm}), and after the first step ``cache`` (the
+    rolling cache, the model's named arrays) and ``shares`` (per agent). The
+    later steps' losses are not among the numbers: this configuration's
+    training forks after its first chunk (PERF.md, section 2), so the first
+    step's loss stands for them. ``model.further_numbers`` adds what the
+    family compares besides; ``judge`` holds a number only where the cell's
+    limits name it."""
     moved = moved_leaves(reference["grad"])
     return {
-        "kv_err": cache_error(program["kv"], reference["kv"]),
+        "kv_err": cache_error(program["cache"], reference["cache"]),
         "shares_gap": shares_gap(program["shares"], reference["shares"]),
         "loss_step1": relative_gap(program["losses"][0],
                                    reference["losses"][0]),
@@ -97,7 +115,8 @@ def training_numbers(program: dict, reference: dict) -> dict:
             program["change"], reference["change"], keep=moved),
         "grad_worst_gap": worst_leaf_gap(program["grad"], reference["grad"]),
         "change_worst_gap": worst_leaf_gap(
-            program["change"], reference["change"], keep=moved)}
+            program["change"], reference["change"], keep=moved),
+        **model.further_numbers(program, reference)}
 
 
 def judge(numbers: dict, limits: dict) -> tuple[bool, dict]:

@@ -1,12 +1,16 @@
-"""Plain reference of the episode transformer and of one PPO chunk.
+"""Plain reference of what is the system's and not one model's: the
+environment, the heads linear in the wallet, GAE, one PPO chunk, AdaGrad and
+the planted faults. The trunk is the configuration's own model's, handed in
+as ``model`` (a module under ``chipbench/models/``).
 
 Straightforward ``jax.numpy`` in float32 with every matrix product at
 ``highest`` precision: no kernels, no cache, no shared-trunk tricks beyond
-the algebra the architecture states (every agent reads the same price
-series, so the trunk is a function of the series alone and the heads are
-linear in the portfolio features). Imports nothing of the program and takes
-nothing the program made: weights, noise and minibatch order are re-derived
-from the seed by the configuration's own recipe (documented at each step).
+the algebra every episode-mode policy here states (every agent reads the
+same price series, so the trunk is a function of the series alone and the
+heads are linear in the portfolio features). Imports nothing of the program
+and takes nothing the program made: weights, noise and minibatch order are
+re-derived from the seed by the configuration's own recipe (documented at
+each step).
 
 ``quant`` puts the control in the program's place: the same computation with
 the operands of every matrix product of the trunk and the heads (dense
@@ -40,93 +44,9 @@ def dense(p, x, quant=None):
     return jnp.dot(x, w, precision=HI) + p["b"]
 
 
-def layer_norm(x, p):
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mean) * jax.lax.rsqrt(var + 1e-6) * p["scale"] + p["bias"]
-
-
-def rope(x, positions, base=10000.0):
-    """x (H, S, D), positions (S,) absolute tick indices."""
-    half = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions[None, :, None].astype(jnp.float32) * freqs
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def init_params(key, s):
-    """The configuration's initialisation: He-normal denses, 0.02 / 0.01
-    scaled port / policy heads, 0.02/L scaled residual projections, keys
-    split once into 5 + 6L and used in the published order."""
-    d, layers = s["heads"] * s["head_dim"], s["layers"]
-    keys = jax.random.split(key, 5 + 6 * layers)
-
-    def dn(k, i, o, scale=None):
-        std = jnp.sqrt(2.0 / i) if scale is None else scale
-        w = jax.random.normal(k, (i, o), jnp.float32) * jnp.asarray(
-            std, jnp.float32)
-        return {"w": w, "b": jnp.zeros((o,), jnp.float32)}
-
-    def ln():
-        return {"scale": jnp.ones((d,), jnp.float32),
-                "bias": jnp.zeros((d,), jnp.float32)}
-
-    params = {"embed": dn(keys[0], 3, d), "port": dn(keys[1], 3, d, 0.02),
-              "policy": dn(keys[2], d, s["actions"], 0.01),
-              "value": dn(keys[3], d, 1), "final_ln": ln(), "blocks": []}
-    for i in range(layers):
-        k = keys[5 + 6 * i: 5 + 6 * (i + 1)]
-        params["blocks"].append({
-            "ln1": ln(), "qkv": dn(k[0], d, 3 * d),
-            "proj": dn(k[1], d, d, 0.02 / layers), "ln2": ln(),
-            "mlp_in": dn(k[2], d, 4 * d),
-            "mlp_out": dn(k[3], 4 * d, d, 0.02 / layers)})
-    return params
-
-
-def trunk(params, series, positions, s, quant=None, want_kv=False):
-    """Banded causal transformer over one (S,) tick series -> (S, d)
-    post-final-LN hidden states. Each query sees itself and the
-    ``window - 1`` ticks before it. With ``want_kv`` also every layer's
-    rotated keys and its values as attention reads them, (L, H, S, D)
-    each: what a rolling cache of the series would hold."""
-    heads, hd, window = s["heads"], s["head_dim"], s["window"]
-    d, n = heads * hd, series.shape[0]
-    logp = jnp.log(jnp.maximum(series, EPS))
-    ret = jnp.concatenate([jnp.zeros((1,)), logp[1:] - logp[:-1]])
-    x = dense(params["embed"],
-              jnp.stack([ret, jnp.abs(ret), jnp.zeros_like(ret)], -1), quant)
-    row, col = jnp.arange(n)[:, None], jnp.arange(n)[None, :]
-    band = (col <= row) & (col > row - window)
-    keys, values = [], []
-    for blk in params["blocks"]:
-        h = layer_norm(x, blk["ln1"])
-        qkv = dense(blk["qkv"], h, quant).reshape(n, 3, heads, hd)
-        q, k, v = (qkv[:, j].transpose(1, 0, 2) for j in range(3))
-        q, k = rope(q, positions), rope(k, positions)
-        if quant is not None:
-            q, k, v = quant(q), quant(k), quant(v)
-        keys.append(k)
-        values.append(v)
-        sc = jnp.einsum("hqd,hkd->hqk", q, k, precision=HI) * hd ** -0.5
-        pr = jax.nn.softmax(jnp.where(band[None], sc, -jnp.inf), axis=-1)
-        if quant is not None:
-            pr = quant(pr)
-        att = jnp.einsum("hqk,hkd->hqd", pr, v, precision=HI)
-        x = x + dense(blk["proj"], att.transpose(1, 0, 2).reshape(n, d), quant)
-        h = layer_norm(x, blk["ln2"])
-        x = x + dense(blk["mlp_out"],
-                      jax.nn.gelu(dense(blk["mlp_in"], h, quant)), quant)
-    hn = layer_norm(x, params["final_ln"])
-    return (hn, jnp.stack(keys), jnp.stack(values)) if want_kv else hn
-
-
-def series_at(prices, t0, length, s):
+def series_at(prices, t0, length, hist):
     """Ticks ``t0 - hist .. t0 - hist + length - 1`` with the episode start
     left-padded by the first price, and their absolute positions."""
-    hist = (s["layers"] - 1) * (s["window"] - 1)
     idx = t0 - hist + jnp.arange(length)
     return prices[jnp.maximum(idx, 0)], idx
 
@@ -155,11 +75,11 @@ def heads_at(base_l, base_v, fold, feats):
             base_v + jnp.dot(feats, w_pv, precision=HI) + b_pv)
 
 
-def init_state(key, s, initial_budget=2400.0):
+def init_state(key, s, model, initial_budget=2400.0):
     """``key`` = PRNGKey(seed), split into the parameter key and the run's
     stream."""
     k_params, k_rng = jax.random.split(key)
-    params = init_params(k_params, s)
+    params = model.init_params(k_params, s)
     b = s["agents"]
     return {
         "params": params,
@@ -167,17 +87,17 @@ def init_state(key, s, initial_budget=2400.0):
         "rng": k_rng, "t": jnp.int32(0),
         "budget": jnp.full((b,), initial_budget, jnp.float32),
         "shares": jnp.zeros((b,), jnp.float32),
-        "share_value": jnp.zeros((b,), jnp.float32),
-        "kv": jnp.zeros((2, s["layers"], s["heads"], s["window"],
-                         s["head_dim"]), jnp.float32)}
+        "share_value": jnp.zeros((b,), jnp.float32)}
 
 
-def ppo_chunk(state, prices, s, lr, *, gamma, lam, clip_eps, value_coef,
-              entropy_coef, quant=None, fault=None):
+def ppo_chunk(state, prices, s, model, lr, *, gamma, lam, clip_eps,
+              value_coef, entropy_coef, quant=None, fault=None):
     """One chunk: ``unroll`` env steps of every agent under the current
     policy, GAE, then epochs x minibatches clipped-surrogate updates with
-    AdaGrad. Returns (state, metrics): the means over the updates of the
-    total loss and its parts, and the rollout's summed reward.
+    AdaGrad. Returns (state, metrics, cache): the means over the updates of
+    the total loss and its parts and the rollout's summed reward; and what
+    the episode's rolling cache holds once the unroll is over, the model's
+    own named arrays with the layer axis first.
 
     ``fault`` plants one of the faults the benchmark's comparison has to
     catch: ``half_batch`` (half of the agents left out of the step: they do
@@ -188,17 +108,16 @@ def ppo_chunk(state, prices, s, lr, *, gamma, lam, clip_eps, value_coef,
     section 2), ``unchanged`` (the step returns its state unchanged).
     """
     t_len, b, w, a = s["unroll"], s["agents"], s["window"], s["actions"]
-    hist = (s["layers"] - 1) * (w - 1)
+    hist = model.history(s)
     params, t0 = state["params"], state["t"]
     q0 = hist + w - 1
 
-    # ---- rollout: the trunk over [history | window | the unroll's ticks]
-    series, pos = series_at(prices, t0, hist + w + t_len, s)
-    hn, keys, values = trunk(params, series, pos, s, quant, want_kv=True)
+    # ---- rollout: the trunk over [history | window | the unroll's ticks],
+    # and the rolling cache as it stands before the bootstrap row
+    series, pos = series_at(prices, t0, hist + w + t_len, hist)
+    hn, cache = model.trunk(params, series, pos, s, quant,
+                            cache_before=hist + w + t_len - 1)
     hn = hn[q0 + jnp.arange(t_len + 1)]
-    # What the episode's rolling cache holds once the unroll is over: the
-    # ``window`` ticks before the bootstrap row, in tick order.
-    kv = jnp.stack([keys, values])[:, :, :, -1 - w:-1]
     base_l, base_v, fold = head_terms(params, hn, quant)
     anchors = series[q0 + jnp.arange(t_len + 1)]      # newest tick of window i
     trade = anchors[1:]                               # the tick after it
@@ -208,7 +127,8 @@ def ppo_chunk(state, prices, s, lr, *, gamma, lam, clip_eps, value_coef,
     live = jnp.ones((b,), jnp.float32)
     if fault == "half_batch":
         live = (jnp.arange(b) % 2 == 0).astype(jnp.float32)
-        kv = kv * jnp.mean(live)     # the others' caches stay at their zeros
+        # the others' caches stay at their zeros
+        cache = jax.tree.map(lambda x: x * jnp.mean(live), cache)
 
     def env_step(carry, xs):
         budget, shares, share_value = carry
@@ -259,7 +179,8 @@ def ppo_chunk(state, prices, s, lr, *, gamma, lam, clip_eps, value_coef,
     mb_size = b // mbs
 
     def loss_fn(p, idx):
-        hq = trunk(p, r_series, r_pos, s, quant)[q0 + jnp.arange(t_len)]
+        hq = model.trunk(p, r_series, r_pos, s, quant)[
+            q0 + jnp.arange(t_len)]
         bl, bv, fd = head_terms(p, hq, quant)
         feats = port_feats(tb[:, idx], tsh[:, idx], r_anchor[:, None])
         logits, values = heads_at(bl[:, None], bv[:, None], fd, feats)
@@ -300,13 +221,12 @@ def ppo_chunk(state, prices, s, lr, *, gamma, lam, clip_eps, value_coef,
     (new_params, acc, rng), losses = jax.lax.scan(
         epoch_body, (params, state["acc"], rng), None, length=s["epochs"])
     new = {"params": new_params, "acc": acc, "rng": rng, "t": t0 + t_len,
-           "budget": budget, "shares": shares, "share_value": share_value,
-           "kv": kv}
+           "budget": budget, "shares": shares, "share_value": share_value}
     if fault == "unchanged":
-        new = state
+        new, cache = state, jax.tree.map(jnp.zeros_like, cache)
     total, pol, val, ent = (jnp.mean(x) for x in losses)
     return new, {"loss": total, "policy_loss": pol, "value_loss": val,
-                 "entropy": ent, "reward_sum": jnp.sum(reward)}
+                 "entropy": ent, "reward_sum": jnp.sum(reward)}, cache
 
 
 def leaf_norms(tree):

@@ -12,19 +12,20 @@ import numpy as np
 from chipbench.harness import common, flops, loadgen, reference
 
 
-def reference_logits(params, ticks, budget, shares, sizes: dict, quant=None):
-    """The plain reference over one session's whole history: the banded
-    pass over [first-price pads | first window | the ticks that followed],
-    read at every step with that step's wallet. ``ticks`` holds
+def reference_logits(params, ticks, budget, shares, model, sizes: dict,
+                     quant=None):
+    """The plain reference over one session's whole history: ``model``'s
+    causal pass over [first-price pads | first window | the ticks that
+    followed], read at every step with that step's wallet. ``ticks`` holds
     ``window + n - 1`` prices, ``budget`` and ``shares`` ``n`` entries;
-    rows past the session's served steps are padding the causal band keeps
+    rows past the session's served steps are padding that causality keeps
     out of every earlier row. -> (n, A)"""
     import jax.numpy as jnp
     w, n = sizes["window"], budget.shape[0]
-    hist = (sizes["layers"] - 1) * (w - 1)
+    hist = model.history(sizes)
     series = jnp.concatenate([jnp.full((hist,), ticks[0]), ticks])
     positions = jnp.arange(-hist, w + n - 1)
-    hn = reference.trunk(params, series, positions, sizes, quant)
+    hn = model.trunk(params, series, positions, sizes, quant)
     q = hist + w - 1 + jnp.arange(n)
     base_l, base_v, fold = reference.head_terms(params, hn[q], quant)
     feats = reference.port_feats(budget, shares, series[q])
@@ -32,8 +33,8 @@ def reference_logits(params, ticks, budget, shares, sizes: dict, quant=None):
     return logits
 
 
-def serving_numbers(sample: list[loadgen.Session], seed: int, sizes: dict,
-                    quant=None, alter: bool = False) -> dict:
+def serving_numbers(sample: list[loadgen.Session], seed: int, model,
+                    sizes: dict, quant=None, alter: bool = False) -> dict:
     """Over a sample of sessions: ``logit_gap``, the widest gap by which a
     served action's reference logit lies below the reference's best, and
     ``logit_err``, the widest distance between a served logit and the
@@ -45,11 +46,12 @@ def serving_numbers(sample: list[loadgen.Session], seed: int, sizes: dict,
 
     import jax
     k_params, _ = jax.random.split(jax.random.PRNGKey(seed))
-    params = reference.init_params(k_params, sizes)
+    params = model.init_params(k_params, sizes)
     w = sizes["window"]
     pad = -(-max(len(s.steps) for s in sample) // 64) * 64
-    fns = {q: jax.jit(functools.partial(reference_logits, sizes=sizes,
-                                        quant=q)) for q in {None, quant}}
+    fns = {q: jax.jit(functools.partial(reference_logits, model=model,
+                                        sizes=sizes, quant=q))
+           for q in {None, quant}}
     gap = err = 0.0
     for sess in sample:
         n = len(sess.steps)
@@ -137,7 +139,7 @@ def serve_sessions(cfg, traffic: dict, seed: int, seconds: float):
     return sessions
 
 
-def run(cfg, traffic: dict, limits: dict, *, seed: int, seconds: float,
+def run(cfg, traffic: dict, limits: dict, *, model, seed: int, seconds: float,
         trace: bool, t_start: float, out_dir: str, device: dict, peaks: dict,
         readers) -> tuple[dict, dict]:
     """One run of a serving cell -> (result, compared)."""
@@ -146,7 +148,7 @@ def run(cfg, traffic: dict, limits: dict, *, seed: int, seconds: float,
 
     from chipbench.harness import correct, trace_reduce
 
-    sizes = flops.model_sizes(cfg)
+    sizes = flops.sizes(cfg, model)
     load = traffic["load"]
     due = loadgen.arrival_times(seed, load["rate"], seconds)
     engine, sessions, cold_failed = start_engine(cfg, traffic, seed, len(due))
@@ -193,7 +195,7 @@ def run(cfg, traffic: dict, limits: dict, *, seed: int, seconds: float,
         "setup_s": setup_s}
 
     sample = draw_sample(sessions, seed, load["check_sessions"])
-    numbers = serving_numbers(sample, seed, sizes)
+    numbers = serving_numbers(sample, seed, model, sizes)
     ok, compared = correct.judge(numbers, limits)
     ok = ok and all_answered and failed == 0 and in_window > 0
 

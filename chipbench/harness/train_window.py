@@ -28,36 +28,37 @@ def window_rate(rows: list[tuple[float, float]], t0: float, t1: float,
     return steps * agents / (inside[-1][0] - inside[0][0]), len(inside) - 1
 
 
-def reference_training(sizes: dict, learner, prices, seed: int, *,
+def reference_training(model, sizes: dict, learner, prices, seed: int, *,
                        steps: int = CHECK_STEPS, quant=None, fault=None,
                        initial_budget: float = 2400.0) -> dict:
     """Losses, first-step gradient norms and the parameters' change over
-    ``steps`` chunks of the plain reference (or of the control, or of the
-    reference with a fault planted)."""
+    ``steps`` chunks of the plain reference with ``model``'s trunk (or of
+    the control, or of the reference with a fault planted)."""
     import jax
     import jax.numpy as jnp
-    key = (tuple(sorted(sizes.items())), learner.learning_rate, learner.gamma,
-           learner.gae_lambda, learner.clip_eps, learner.value_coef,
-           learner.entropy_coef, quant, fault)
+    key = (model, tuple(sorted(sizes.items())), learner.learning_rate,
+           learner.gamma, learner.gae_lambda, learner.clip_eps,
+           learner.value_coef, learner.entropy_coef, quant, fault)
     if key not in _CHUNKS:
         _CHUNKS[key] = jax.jit(functools.partial(
-            reference.ppo_chunk, s=sizes, lr=learner.learning_rate,
+            reference.ppo_chunk, s=sizes, model=model,
+            lr=learner.learning_rate,
             gamma=learner.gamma, lam=learner.gae_lambda,
             clip_eps=learner.clip_eps, value_coef=learner.value_coef,
             entropy_coef=learner.entropy_coef, quant=quant, fault=fault))
     chunk = _CHUNKS[key]
     t_ref = time.time()
     state = jax.jit(functools.partial(
-        reference.init_state, s=sizes, initial_budget=initial_budget))(
-            jax.random.PRNGKey(seed))
+        reference.init_state, s=sizes, model=model,
+        initial_budget=initial_budget))(jax.random.PRNGKey(seed))
     p0 = state["params"]
     prices = jnp.asarray(prices)
     rows, first = [], None
     for k in range(steps):
-        state, metrics = chunk(state, prices)
+        state, metrics, cache = chunk(state, prices)
         rows.append(metrics)
         if k == 0:
-            first = (jax.jit(reference.grad_rss)(state["acc"]), state["kv"],
+            first = (jax.jit(reference.grad_rss)(state["acc"]), cache,
                      state["shares"])
     change = jax.jit(reference.change_norms)(state["params"], p0)
     rows, first = jax.device_get((rows, first))
@@ -67,29 +68,20 @@ def reference_training(sizes: dict, learner, prices, seed: int, *,
 
 
 def _readings(rows, first, change) -> dict:
-    grad, kv, shares = first
+    grad, cache, shares = first
     return {"losses": [float(r["loss"]) for r in rows],
             "metrics": [{k: float(v) for k, v in r.items()} for r in rows],
             "grad": correct.flat_norms(grad),
             "change": correct.flat_norms(change),
-            "kv": kv, "shares": shares}
+            "cache": cache, "shares": shares}
 
 
-def cache_in_tick_order(carry):
-    """The episode's rolling K/V cache as the program's state holds it
-    ((B, L, H, W, D) rings, tick j at slot j mod W, ticks t - 1 .. t + W - 2)
-    -> (2, L, H, W, D) in float32: the mean over the agents, in tick order."""
-    import jax.numpy as jnp
-    window = carry["k"].shape[3]
-    slots = (carry["t"][0] - 1 + jnp.arange(window)) % window
-    return jnp.stack([jnp.mean(carry[n].astype(jnp.float32), axis=0)
-                      for n in ("k", "v")])[:, :, :, slots]
-
-
-def drive_first_steps(orc, steps: int = CHECK_STEPS) -> dict:
+def drive_first_steps(orc, model, steps: int = CHECK_STEPS) -> dict:
     """The program's first chunks through the window's own compiled step and
     state (``orc._step_fn`` on ``orc._ts``, committed back as the dispatcher
-    commits it), keeping what the comparison needs."""
+    commits it), keeping what the comparison needs; ``model.program_cache``
+    brings the carry's rolling cache into the reference's names and tick
+    order."""
     import jax
     import jax.numpy as jnp
     p0 = jax.tree.map(jnp.copy, orc._ts.params)
@@ -103,20 +95,20 @@ def drive_first_steps(orc, steps: int = CHECK_STEPS) -> dict:
         if k == 0:       # read now: the next step is given this state
             first = jax.device_get((
                 jax.jit(reference.grad_rss)(ts.opt_state[0].sum_of_squares),
-                jax.jit(cache_in_tick_order)(ts.carry), ts.env_state.shares))
+                jax.jit(model.program_cache)(ts.carry), ts.env_state.shares))
     change = jax.jit(reference.change_norms)(orc._ts.params, p0)
     rows = jax.device_get(rows)
     return _readings(rows, first, change)
 
 
-def run(cfg, traffic: dict, limits: dict, *, seed: int, seconds: float,
+def run(cfg, traffic: dict, limits: dict, *, model, seed: int, seconds: float,
         trace: bool, t_start: float, out_dir: str, device: dict, peaks: dict,
         readers) -> tuple[dict, dict]:
     """One run of a training cell -> (result, compared)."""
     from sharetrade_tpu.runtime.orchestrator import Orchestrator
     from chipbench.harness import trace_reduce
 
-    sizes = flops.model_sizes(cfg)
+    sizes = flops.sizes(cfg, model)
     agents = cfg.parallel.num_workers
     prices = common.make_prices(traffic["prices"])
     if trace:
@@ -126,7 +118,7 @@ def run(cfg, traffic: dict, limits: dict, *, seed: int, seconds: float,
     orc = Orchestrator(cfg)
     orc.send_training_data(prices)
     stages["build_and_init"] = time.time() - t_start
-    program = drive_first_steps(orc)
+    program = drive_first_steps(orc, model)
     stages["compile_and_first_steps"] = time.time() - t_start
     orc.start_training(background=True)
 
@@ -172,9 +164,9 @@ def run(cfg, traffic: dict, limits: dict, *, seed: int, seconds: float,
     orc._ts = None
     del orc
     gc.collect()
-    ref = reference_training(sizes, cfg.learner, prices, seed,
+    ref = reference_training(model, sizes, cfg.learner, prices, seed,
                              initial_budget=cfg.env.initial_budget)
-    numbers = correct.training_numbers(program, ref)
+    numbers = correct.training_numbers(program, ref, model)
     ok, compared = correct.judge(numbers, limits)
     ok = ok and failed == 0 and chunks >= 1 and math.isfinite(rate)
 

@@ -1,17 +1,21 @@
-"""Readers of whole-step quantities."""
+"""Readers of whole-step quantities. The operation counts are the
+configuration's own model's (``context["model"]``, a module under
+``chipbench/models/``); the peak is that of all the chips the cell holds."""
 
-from chipbench.harness import flops
+
+def peak_flops(context) -> float:
+    return context["chips"] * context["peaks"]["bf16_flops"]
 
 
 def train_mfu(context):
-    """The training step's share of the chip's bf16 peak: the model's
-    operations per agent-step (chipbench/harness/flops.py) times the
-    measured agent-steps per second, over the peak, in percent."""
+    """The training step's share of the cell's chips' bf16 peak: the
+    model's operations per agent-step times the measured agent-steps per
+    second, over (chips x the chip's peak), in percent."""
     rate = context["values"].get("agent_steps_per_s")
     if not rate or rate != rate:
         return None
-    per_step = flops.episode_train_flops_per_agent_step(context["sizes"])
-    return 100.0 * per_step * rate / context["peaks"]["bf16_flops"]
+    per_step = context["model"].train_flops_per_agent_step(context["sizes"])
+    return 100.0 * per_step * rate / peak_flops(context)
 
 
 def rows_per_tick(context):
@@ -26,12 +30,12 @@ def rows_per_tick(context):
 
 
 def serve_tick_mfu(context, patterns):
-    """The warm tick's share of the chip's bf16 peak: the model's
+    """The warm tick's share of the cell's chips' bf16 peak: the model's
     operations for the rows a tick carries (``rows_per_tick``) over (the
     device seconds of one run of the tick program, the mean over its runs in
-    the traced stretch, x the peak), in percent. Rows and seconds are both
-    per tick, so how long the profiler took to start and stop is not in
-    it."""
+    the traced stretch, x chips x the chip's peak), in percent. Rows and
+    seconds are both per tick, so how long the profiler took to start and
+    stop is not in it."""
     trace = context["trace"]
     names = [n for n in trace.module_seconds if any(p in n for p in patterns)]
     seconds = sum(trace.module_seconds[n] for n in names)
@@ -39,5 +43,5 @@ def serve_tick_mfu(context, patterns):
     rows = rows_per_tick(context)
     if not seconds or not runs or not rows:
         return None
-    ops = flops.serve_warm_step_flops(context["sizes"]) * rows
-    return 100.0 * ops / (seconds / runs * context["peaks"]["bf16_flops"])
+    ops = context["model"].serve_warm_step_flops(context["sizes"]) * rows
+    return 100.0 * ops / (seconds / runs * peak_flops(context))

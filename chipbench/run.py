@@ -52,6 +52,7 @@ def main(argv=None, manifest=None) -> int:
         cell = manifest.cell(args.workload)
         traffic = manifest.traffic(cell["traffic"])
         config_doc = manifest.config(cell["config"])
+        model = manifest.model(config_doc)
         limits = manifest.limits(cell["name"])["limits"]
         # The compile cache before anything touches the backend: where
         # JAX_COMPILATION_CACHE_DIR is set nothing is set in code, else the
@@ -68,10 +69,12 @@ def main(argv=None, manifest=None) -> int:
     driver = importlib.import_module(
         "chipbench.harness." + traffic["kind"] + "_window")
     result, compared = driver.run(
-        cfg, traffic, limits, seed=args.seed, seconds=args.seconds,
-        trace=bool(args.trace), t_start=T_START, out_dir=out_dir,
-        device=device, peaks=peaks_for(device["kind"]),
-        readers=lambda ctx: per_layer_values(manifest, cell["name"], ctx))
+        cfg, traffic, limits, model=model, seed=args.seed,
+        seconds=args.seconds, trace=bool(args.trace), t_start=T_START,
+        out_dir=out_dir, device=device, peaks=peaks_for(device["kind"]),
+        readers=lambda ctx: per_layer_values(
+            manifest, cell["name"],
+            dict(ctx, model=model, chips=cell["chips"])))
     wanted = {m["name"] for m in manifest.metrics_for(
         cell["name"], "per_layer" if args.trace else "end_to_end")}
     result["metrics"] = with_units(

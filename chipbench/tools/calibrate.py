@@ -26,17 +26,16 @@ def _detail(side, ref):
 
     import numpy as np
 
-    def plain(d):
-        return {k: v for k, v in d.items() if k not in ("kv", "shares")}
+    from chipbench.harness import correct
 
-    def by_layer(x):
-        return np.sqrt(np.sum(np.square(np.asarray(x, np.float64)),
-                              axis=(2, 3, 4)))
+    def plain(d):
+        return {k: v for k, v in d.items() if k not in ("cache", "shares")}
 
     out = {"losses": side["losses"], "ref_losses": ref["losses"],
            "side": plain(side), "ref": plain(ref),
-           "kv_err_by_layer": (by_layer(side["kv"] - ref["kv"])
-                               / by_layer(ref["kv"])).tolist(),
+           "kv_err_by_layer": {
+               name: by_layer.tolist() for name, by_layer in
+               correct.cache_errors(side["cache"], ref["cache"]).items()},
            "shares_mean": [float(np.mean(np.abs(side["shares"]))),
                            float(np.mean(np.abs(ref["shares"])))],
            "shares_same": float(np.mean(side["shares"] == ref["shares"]))}
@@ -58,61 +57,55 @@ def bf16_round(x):
     return x + jax.lax.stop_gradient(q - x)
 
 
-def train_readings(cell, cfg, traffic, seed, what):
+def train_readings(model, cfg, traffic, seed, what):
     from sharetrade_tpu.runtime.orchestrator import Orchestrator
     from chipbench.harness import common, correct, flops, reference
     from chipbench.harness import train_window as tw
-    sizes = flops.model_sizes(cfg)
+    sizes = flops.sizes(cfg, model)
     prices = common.make_prices(traffic["prices"])
-    budget = cfg.env.initial_budget
-    ref = tw.reference_training(sizes, cfg.learner, prices, seed,
-                                initial_budget=budget)
+
+    def reference_side(**kwargs):
+        return tw.reference_training(
+            model, sizes, cfg.learner, prices, seed,
+            initial_budget=cfg.env.initial_budget, **kwargs)
+
+    ref = reference_side()
+
+    def reading(side):
+        return dict(correct.training_numbers(side, ref, model),
+                    detail=_detail(side, ref))
+
     if "program" in what:
         orc = Orchestrator(cfg)
         orc.send_training_data(prices)
-        program = tw.drive_first_steps(orc)
+        program = tw.drive_first_steps(orc, model)
         orc.stop()
         orc._ts = None
         del orc
         gc.collect()
-        yield "program", dict(correct.training_numbers(program, ref),
-                              detail=_detail(program, ref))
+        yield "program", reading(program)
     if "bf16" in what:
-        witness = tw.reference_training(
-            sizes, cfg.learner, prices, seed, quant=bf16_round,
-            initial_budget=budget)
-        yield "bf16_reference", dict(
-            correct.training_numbers(witness, ref),
-            detail=_detail(witness, ref))
+        yield "bf16_reference", reading(reference_side(quant=bf16_round))
     if "control" in what:
-        control = tw.reference_training(
-            sizes, cfg.learner, prices, seed, quant=reference.int8_quant,
-            initial_budget=budget)
-        yield "control", dict(correct.training_numbers(control, ref),
-                              detail=_detail(control, ref))
+        yield "control", reading(reference_side(quant=reference.int8_quant))
     if "faults" in what:
         for fault in ("half_batch", "token", "token16", "unchanged"):
-            broken = tw.reference_training(
-                sizes, cfg.learner, prices, seed, fault=fault,
-                initial_budget=budget)
-            yield "fault:" + fault, dict(
-                correct.training_numbers(broken, ref),
-                detail=_detail(broken, ref))
+            yield "fault:" + fault, reading(reference_side(fault=fault))
 
 
-def serve_readings(cell, cfg, traffic, seed, what, seconds):
+def serve_readings(model, cfg, traffic, seed, what, seconds):
     from chipbench.harness import flops, reference
     from chipbench.harness import serve_window as sw
-    sizes = flops.model_sizes(cfg)
+    sizes = flops.sizes(cfg, model)
     sessions = sw.serve_sessions(cfg, traffic, seed, seconds)
     sample = sw.draw_sample(sessions, seed, traffic["load"]["check_sessions"])
     if "program" in what:
-        yield "program", sw.serving_numbers(sample, seed, sizes)
+        yield "program", sw.serving_numbers(sample, seed, model, sizes)
     if "control" in what:
-        yield "control", sw.serving_numbers(sample, seed, sizes,
+        yield "control", sw.serving_numbers(sample, seed, model, sizes,
                                             quant=reference.int8_quant)
     if "faults" in what:
-        yield "fault:answer", sw.serving_numbers(sample, seed, sizes,
+        yield "fault:answer", sw.serving_numbers(sample, seed, model, sizes,
                                                  alter=True)
 
 
@@ -124,16 +117,15 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=6.0)
     args = ap.parse_args(argv)
     from chipbench.harness import common
-    manifest, cell, traffic, device = common.open_cell(args.workload,
-                                                       ".calibrate")
+    cell, config_doc, traffic, model, device = common.open_cell(
+        args.workload, ".calibrate")
     what = args.what.split(",")
     for seed in (int(s) for s in args.seeds.split(",")):
-        cfg = common.build_config(manifest.config(cell["config"]), traffic,
-                                  seed)
+        cfg = common.build_config(config_doc, traffic, seed)
         if traffic["kind"] == "train":
-            readings = train_readings(cell, cfg, traffic, seed, what)
+            readings = train_readings(model, cfg, traffic, seed, what)
         else:
-            readings = serve_readings(cell, cfg, traffic, seed, what,
+            readings = serve_readings(model, cfg, traffic, seed, what,
                                       args.seconds)
         for name, numbers in readings:
             print(json.dumps({"cell": cell["name"], "seed": seed,

@@ -27,10 +27,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     from chipbench.harness import common, loadgen
     from chipbench.harness import serve_window as sw
-    manifest, cell, traffic, device = common.open_cell(args.workload,
-                                                       ".sweep")
-    cfg = common.build_config(manifest.config(cell["config"]), traffic,
-                              args.seed)
+    _, config_doc, traffic, _, device = common.open_cell(args.workload,
+                                                         ".sweep")
+    cfg = common.build_config(config_doc, traffic, args.seed)
     rates = [float(r) for r in args.rates.split(",")]
     total = int(sum(rates) * args.seconds)
     engine, sessions, cold_failed = sw.start_engine(cfg, traffic, args.seed,

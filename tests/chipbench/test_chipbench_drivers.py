@@ -7,17 +7,27 @@ import pytest
 import chipbench_toy as toy
 
 
-def test_training_cell_runs_and_proves_correct(tmp_path, monkeypatch, capsys):
-    manifest = toy.make_toy(tmp_path, monkeypatch)
+@pytest.fixture(params=toy.FAMILIES)
+def family(request):
+    """The name of a model family: the benchmark's own, then the one that
+    the tests add as files (tests/chipbench/families/)."""
+    return request.param
+
+
+def test_training_cell_runs_and_proves_correct(family, tmp_path, monkeypatch,
+                                               capsys):
+    before = toy.benchmark_files()
+    manifest = toy.make_toy(tmp_path, monkeypatch, family)
     rc, line = toy.run_cell(manifest, "toy_train", capsys,
                             seed=2 ** 31 + 11)
     assert rc == 0 and line["correct"] is True, line
+    assert toy.benchmark_files() == before     # the family came as files
     assert line["failed"] == 0 and line["attempted"] >= 2
     assert set(line["metrics"]) == {"agent_steps_per_s", "setup_s"}
     assert line["metrics"]["agent_steps_per_s"]["value"] > 0
     assert line["device"]["platform"] == "cpu"     # named, never hidden
     assert list(line)[-1] == "compared"
-    assert set(line["compared"]) == set(toy.TOY_LIMITS_TRAIN)
+    assert set(line["compared"]) == set(toy.train_limits(family))
     assert all(v["value"] <= v["limit"] for v in line["compared"].values())
 
 
@@ -82,9 +92,9 @@ def _state_unchanged(monkeypatch):
 
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
                                    "token_altered"])
-def test_training_faults_come_out_not_correct(fault, tmp_path, monkeypatch,
-                                              capsys):
-    manifest = toy.make_toy(tmp_path, monkeypatch)
+def test_training_faults_come_out_not_correct(fault, family, tmp_path,
+                                              monkeypatch, capsys):
+    manifest = toy.make_toy(tmp_path, monkeypatch, family)
     {"state_unchanged": _state_unchanged, "half_batch": _half_batch,
      "token_altered": _token_altered}[fault](monkeypatch)
     rc, line = toy.run_cell(manifest, "toy_train", capsys, seed=5)
@@ -100,17 +110,20 @@ def test_training_faults_come_out_not_correct(fault, tmp_path, monkeypatch,
         assert line["compared"]["kv_err"]["value"] <= 1e-3
 
 
-def test_serving_cell_runs_and_proves_correct(tmp_path, monkeypatch, capsys):
-    manifest = toy.make_toy(tmp_path, monkeypatch)
+def test_serving_cell_runs_and_proves_correct(family, tmp_path, monkeypatch,
+                                              capsys):
+    before = toy.benchmark_files()
+    manifest = toy.make_toy(tmp_path, monkeypatch, family)
     rc, line = toy.run_cell(manifest, "toy_serve", capsys, seed=2 ** 31 + 3)
     assert rc == 0 and line["correct"] is True, line
+    assert toy.benchmark_files() == before
     assert line["attempted"] == 300 and line["failed"] == 0
     assert set(line["metrics"]) >= {"setup_s"}
     assert set(line["compared"]) == set(toy.TOY_LIMITS_SERVE)
 
 
-def test_an_altered_answer_comes_out_not_correct(tmp_path, monkeypatch,
-                                                 capsys):
+def test_an_altered_answer_comes_out_not_correct(family, tmp_path,
+                                                 monkeypatch, capsys):
     from sharetrade_tpu.serve.engine import ServeEngine
     sound = ServeEngine._warm_program
 
@@ -119,7 +132,7 @@ def test_an_altered_answer_comes_out_not_correct(tmp_path, monkeypatch,
         return (actions + 1) % 3, logits, value, new_pool
 
     monkeypatch.setattr(ServeEngine, "_warm_program", altered)
-    manifest = toy.make_toy(tmp_path, monkeypatch)
+    manifest = toy.make_toy(tmp_path, monkeypatch, family)
     rc, line = toy.run_cell(manifest, "toy_serve", capsys, seed=9)
     assert rc == 0 and line["correct"] is False, line
     assert (line["compared"]["logit_gap"]["value"]

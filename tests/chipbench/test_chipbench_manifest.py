@@ -1,5 +1,7 @@
 """The manifest is consistent and the harness is driven by data."""
 
+import ast
+import glob
 import json
 import os
 import re
@@ -7,6 +9,7 @@ import shutil
 
 import pytest
 
+import chipbench_toy as toy
 from chipbench.harness import common
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -59,6 +62,7 @@ def test_every_cells_files_are_found_by_name(cell):
     assert len(entry["why"]) <= 200 and "\n" not in entry["why"]
     cfg = common.build_config(config, traffic, seed=2 ** 31 + 5)
     assert cfg.seed == 2 ** 31 + 5
+    assert callable(manifest.model(config).trunk)
     reported = {m["name"] for m in manifest.metrics_for(cell, "end_to_end")}
     assert "setup_s" in reported and len(reported) >= 2
     assert manifest.metrics_for(cell, "per_layer")
@@ -121,6 +125,107 @@ def test_a_cell_and_a_metric_can_be_added_as_files(tmp_path):
     assert fn({"histograms": snap}, **args) == 1.6   # rank 4: 3 of 5 into 1..2
     assert all(os.path.getmtime(os.path.join(dp, p)) == before[p]
                for dp, _, fs in os.walk(data) for p in fs if p in before)
+
+
+@pytest.mark.parametrize("config", [c["name"] for c in DOC["configs"]])
+def test_every_configuration_names_a_model_family_with_the_whole_interface(
+        config):
+    from chipbench.harness import flops
+    manifest = common.Manifest()
+    doc = manifest.config(config)
+    model = manifest.model(doc)
+    assert model.__name__ == "chipbench.models." + doc["model"]
+    assert os.path.dirname(model.__file__) == os.path.join(
+        common.BENCH_DIR, "models")
+    assert all(callable(getattr(model, member))
+               for member in common.MODEL_INTERFACE)
+    cfg = common.build_config(doc, {}, seed=1)
+    sizes = flops.sizes(cfg, model)
+    assert model.history(sizes) >= 0
+    assert model.replay_seq_len(sizes) == (
+        model.history(sizes) + sizes["window"] + sizes["unroll"] - 1)
+    assert model.train_flops_per_agent_step(sizes) > 0
+    assert model.serve_warm_step_flops(sizes) > 0
+
+
+@pytest.mark.parametrize("doc,why", [
+    ({}, "names no model"),
+    ({"model": "no_such_family"}, "no model family"),
+    ({"model": "half_a_family"}, "lacks")])
+def test_a_configuration_without_a_whole_model_family_is_refused(
+        doc, why, tmp_path, monkeypatch):
+    import chipbench.models
+    (tmp_path / "half_a_family.py").write_text(
+        "def sizes(cfg):\n    return {}\n")
+    monkeypatch.setattr(chipbench.models, "__path__",
+                        [*chipbench.models.__path__, str(tmp_path)])
+    with pytest.raises(common.Refused, match=why):
+        common.Manifest().model(doc)
+
+
+def test_a_model_family_can_be_added_as_files(tmp_path, monkeypatch):
+    """A later ``model_config`` PR adds its family as a module of its own
+    and a configuration that names it; no file that exists is edited. (Both
+    drivers run such a family, sound and broken, in
+    test_chipbench_drivers.py and test_chipbench_control.py.)"""
+    from chipbench.harness import flops
+    before = toy.benchmark_files()
+    manifest = toy.make_toy(tmp_path, monkeypatch, "stacked_kv")
+    doc = manifest.config(manifest.cell("toy_train")["config"])
+    model = manifest.model(doc)
+    assert os.path.dirname(model.__file__) == toy.FAMILY_DIR
+    sizes = flops.sizes(common.build_config(doc, {}, seed=1), model)
+    assert {"depth", "width", "head_count"} <= set(sizes)
+    assert not {"layers", "heads", "head_dim"} & set(sizes)
+    assert manifest.limits("toy_train")["limits"]["newest_tick_err"] == 1e-3
+    assert toy.benchmark_files() == before
+
+
+def _spells_a_model(tree: ast.AST) -> list[str]:
+    """What in a file of the shared harness belongs to one model family: an
+    import of a module under ``chipbench.models``, a model's size by its
+    name, or the program's carry read by its arrays' names."""
+    sizes = {"layers", "heads", "head_dim", "num_layers", "num_heads"}
+    found = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and (node.module or "").startswith(
+                "chipbench.models"):
+            found.append(f"from {node.module} import ...")
+        elif isinstance(node, ast.Import):
+            found += [f"import {a.name}" for a in node.names
+                      if a.name.startswith("chipbench.models")]
+        elif isinstance(node, ast.Constant) and node.value in sizes:
+            found.append(repr(node.value))
+        elif isinstance(node, ast.Attribute) and node.attr in sizes:
+            found.append("." + node.attr)
+        elif (isinstance(node, ast.Subscript)
+              and isinstance(node.value, ast.Name)
+              and node.value.id == "carry"):
+            found.append("carry[...]")
+    return found
+
+
+SHARED = sorted(
+    os.path.relpath(p, common.ROOT) for pattern in
+    ("run.py", "harness/*.py", "readers/step.py", "tools/*.py")
+    for p in glob.glob(os.path.join(common.BENCH_DIR, pattern))
+    if not p.endswith("__init__.py"))
+
+
+@pytest.mark.parametrize("path", SHARED)
+def test_the_shared_harness_spells_no_models_name_or_sizes(path):
+    with open(os.path.join(common.ROOT, path)) as fh:
+        assert _spells_a_model(ast.parse(fh.read())) == []
+
+
+def test_the_scan_finds_what_the_drivers_spelt_before():
+    spelt = _spells_a_model(ast.parse(
+        "from chipbench.models import episode_transformer\n"
+        "import chipbench.models.episode_transformer\n"
+        "d = s['heads'] * s['head_dim']\n"
+        "n = cfg.model.num_layers\n"
+        "w = carry['k'].shape[3]\n"))
+    assert len(spelt) == 6 and "carry[...]" in spelt and ".num_layers" in spelt
 
 
 def test_unknown_cell_and_missing_chip_are_refused(capsys):

@@ -9,6 +9,7 @@ import pytest
 import chipbench_toy as toy
 from chipbench.harness import common, trace_reduce
 from chipbench.harness.peaks import peaks_for
+from chipbench.models import episode_transformer
 from chipbench.readers import device, kernels
 
 FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures",
@@ -47,7 +48,7 @@ def test_kernel_patterns_find_the_flash_kernels(rows):
     assert events == len(wanted) >= 1
     assert seconds == pytest.approx(sum(r[4] for r in wanted) / 1e9)
     share = kernels.attention_roofline(
-        {"trace": summary, "sizes": D1024,
+        {"trace": summary, "sizes": D1024, "model": episode_transformer,
          "peaks": peaks_for("TPU v5 lite")}, **args)
     assert 0 < share < 100
 
@@ -86,5 +87,5 @@ def test_a_reader_with_nothing_to_read_returns_nothing():
         [["/device:TPU:0", "XLA Ops", "%fusion.1 = y", 0, 10]])
     args = common.Manifest().reader("attention_roofline")[1]
     assert kernels.attention_roofline(
-        {"trace": summary, "sizes": D1024,
+        {"trace": summary, "sizes": D1024, "model": episode_transformer,
          "peaks": peaks_for("TPU v5 lite")}, **args) is None
