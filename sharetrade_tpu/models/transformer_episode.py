@@ -626,15 +626,18 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
         (layer norm → qkv → RoPE at per-row absolute positions → ring
         write → cache attention → FFN → heads), with the ONE lockstep
         dependency removed: the ring slot is computed PER ROW
-        (``mod(t_i - 1, window)``) and the cache write is a vmapped
-        ``dynamic_update_slice``, so each session writes its own slot
-        regardless of where its neighbors sit in their episodes. Kept as a
-        separate function rather than generalizing ``_incremental``: the
-        training path's scalar-slot write is part of the pinned fp32
-        golden trajectory (tests/golden/), and a scatter-lowered write
-        there would change the compiled program for zero training
-        benefit. Every row must be WARM (t >= 1) — cold rows belong to
-        the batched prefill."""
+        (``mod(t_i - 1, window)``) and the cache write is a select over
+        the window axis, so each session writes its own slot regardless of
+        where its neighbors sit in their episodes. A select, not a vmapped
+        ``dynamic_update_slice``: that is a scatter, which the TPU
+        compiler runs as one ``while`` loop over the batch for every layer
+        and cache, eight tiny operations a row (43% of a warm tick on the
+        v5e and 4,096 device events — PERF.md, PR 29); the select moves the
+        same bytes in one pass over the layer's rows. Kept as a separate
+        function rather than generalizing ``_incremental``: the training
+        path's scalar-slot write is part of the pinned fp32 golden
+        trajectory (tests/golden/). Every row must be WARM (t >= 1) —
+        cold rows belong to the batched prefill."""
         bsz = obs.shape[0]
         dtype = compute_dtype(params)
         new, prev = obs[:, window - 1], obs[:, window - 2]
@@ -646,6 +649,8 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
         slots = jnp.mod(carry["t"] - 1, window).astype(jnp.int32)   # (B,)
 
         k_cache, v_cache = carry["k"], carry["v"]     # (B, L, H, W, Dh)
+        at_slot = (jnp.arange(k_cache.shape[3], dtype=jnp.int32)
+                   == slots[:, None])[:, None, :, None]   # (B, 1, W, 1)
         aux = jnp.float32(0.0)
         for li, blk in enumerate(blocks_of(params)):
             h = _layer_norm(x, blk["ln1"]["scale"], blk["ln1"]["bias"])
@@ -654,15 +659,10 @@ def episode_transformer_policy(obs_dim: int = 203, num_actions: int = 3, *,
             q = _rope(q, pos)
             k = _rope(k, pos)
 
-            def write_ring(cache, row, slot, _li=li):
-                # cache (L, H, W, Dh) one session; row (H, 1, Dh).
-                zero = jnp.int32(0)
-                return jax.lax.dynamic_update_slice(
-                    cache, row[None], (jnp.int32(_li), zero, slot, zero))
-
-            k_cache = jax.vmap(write_ring)(k_cache, k, slots)
-            v_cache = jax.vmap(write_ring)(v_cache, v, slots)
-            k_all, v_all = k_cache[:, li], v_cache[:, li]
+            k_all = jnp.where(at_slot, k, k_cache[:, li])    # (B, H, W, Dh)
+            v_all = jnp.where(at_slot, v, v_cache[:, li])
+            k_cache = k_cache.at[:, li].set(k_all)
+            v_cache = v_cache.at[:, li].set(v_all)
             s = jnp.einsum("bhqd,bhkd->bhqk", q, k_all,
                            preferred_element_type=jnp.float32) * sm_scale
             probs = jax.nn.softmax(s, axis=-1).astype(v_all.dtype)

@@ -187,13 +187,14 @@ def migrated_traces(spans: list[dict]) -> list[dict]:
     span annotated ``migrate``) — the kill-correlation surface the fleet
     soak asserts on: each returned trace carries the set of engine procs
     whose spans made it into the record."""
+    # Only the migrated traces are stitched: stitching scans every span it
+    # is given, and a soak journals tens of thousands of traces.
+    migrated = {s["trace"] for s in spans if s["name"] == "relay_attempt"
+                and s.get("note", "").startswith("migrate")}
+    spans = [s for s in spans if s["trace"] in migrated]
     out: list[dict] = []
     for tid in trace_ids(spans):
         stitched = stitch(spans, tid)
-        attempts = [s for s in stitched["spans"]
-                    if s["name"] == "relay_attempt"]
-        if not any(s.get("note", "").startswith("migrate") for s in attempts):
-            continue
         stitched["engines"] = sorted(
             {s["proc"] for s in stitched["spans"]
              if s["proc"].startswith("engine-")})

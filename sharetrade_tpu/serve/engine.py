@@ -178,6 +178,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from sharetrade_tpu.config import ConfigError, ServeConfig
 from sharetrade_tpu.models.core import apply_batched
@@ -202,6 +203,31 @@ _SPILL_TICK = object()
 #: without escaping (the fast path — harness/CLI ids are all of this
 #: shape); anything else routes through json.dumps.
 _SID_SAFE = re.compile(r"[A-Za-z0-9_\-#.:]*\Z").match
+
+
+def _gather_rows(pool, idx):
+    """Rows ``idx`` of every leaf of the arena ``pool``: bit for bit
+    ``jax.tree.map(lambda x: x[idx], pool)`` for in-bounds indices, unique
+    or repeated, over any carry pytree (leaves of any rank >= 1, any
+    dtype), reading ``len(idx)`` rows of each leaf and nothing else.
+
+    Not ``x[idx]``: that is an XLA gather, and where a trailing axis does
+    not fill its tile the TPU compiler relayouts the WHOLE leaf first (a
+    window of 201 became a 128-wide and a 73-wide copy of every row of the
+    arena, 7 GB read and written for the 211 MB asked for: three quarters
+    of a warm tick on the v5e — PERF.md, PR 29). A row is contiguous in
+    any layout, so one ``dynamic_slice`` a row is a plain copy, the
+    read-side twin of the per-row update ``.at[idx].set`` lowers to."""
+    def copy_row(i, rows):
+        return jax.tree.map(
+            lambda x, out: lax.dynamic_update_slice_in_dim(
+                out, lax.dynamic_slice_in_dim(x, idx[i], 1, axis=0), i,
+                axis=0),
+            pool, rows)
+
+    batch = idx.shape[0]
+    return lax.fori_loop(0, batch, copy_row, jax.tree.map(
+        lambda x: jnp.empty((batch,) + x.shape[1:], x.dtype), pool))
 
 
 class ServeRejected(RuntimeError):
@@ -647,6 +673,10 @@ class ServeEngine:
         #: Spill tier on only with a configured arena directory AND a
         #: live warm tier to overflow from / adopt into.
         self._spill_enabled = bool(cfg.spill_dir) and self._warm_enabled
+        # What a tick's gather must move, from the carry's shapes: the
+        # compiled programs are held to it (tests/test_chip_compile.py).
+        self._registry.record("serve_tick_gather_bytes",
+                              cfg.max_batch * self._carry_nbytes)
         self._build_arena_and_programs()
 
         # Live tunable knobs (tuned-knob-ok: seeded from config — the
@@ -934,7 +964,7 @@ class ServeEngine:
         """One incremental step for a warm batch: gather slot carries,
         per-row-clock serve step, scatter back. THE steady-state program."""
         with jax.named_scope("gather"):
-            rows = jax.tree.map(lambda x: x[idx], pool)
+            rows = _gather_rows(pool, idx)
         with jax.named_scope("model"):
             out, new_rows = self.model.apply_serve_batch(params, obs, rows)
         with jax.named_scope("scatter"):
@@ -958,7 +988,7 @@ class ServeEngine:
         """Batch-gather the tick's eviction victims' carry rows (page-out
         step 1). Async device compute, never a readback — legal on the
         dispatch thread; the CONSUMER device_gets the result."""
-        return jax.tree.map(lambda x: x[idx], pool)
+        return _gather_rows(pool, idx)
 
     def _install_program(self, pool, rows, idx):
         """Scatter parked carries back into their (re-)admitted slots
@@ -970,7 +1000,7 @@ class ServeEngine:
         """Single program for models without a prefill/incremental split:
         cold rows take a fresh init carry in-program, everything else runs
         ``apply_batched`` (no cross-row constraint to honor)."""
-        rows = jax.tree.map(lambda x: x[idx], pool)
+        rows = _gather_rows(pool, idx)
 
         def reset_cold(init_row, row):
             mask = cold.reshape((-1,) + (1,) * (row.ndim - 1))

@@ -287,13 +287,15 @@ def wide_engine():
     engine.stop(drain=False)
 
 
-@pytest.mark.parametrize("program", ["warm", "cold", "park", "install"])
-def test_serve_program_compiles_for_v5e(one_chip, tpu_backend, wide_engine,
-                                        program):
-    engine, cfg = wide_engine
+def _compile_serve_program(one_chip, engine, cfg, program, slots=None):
+    """One of the engine's device programs compiled for the described
+    chip, over an arena of ``slots`` + ``max_batch`` rows (the engine's
+    own by default; the programs take any number of rows)."""
     batch = cfg.serve.max_batch
     params = _on(one_chip, engine._live.params)
-    pool = _on(one_chip, engine._pool)
+    n_arena = (cfg.serve.slots if slots is None else slots) + batch
+    pool = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        (n_arena,) + x.shape[1:], x.dtype, sharding=one_chip), engine._pool)
     obs = jax.ShapeDtypeStruct((batch, cfg.env.window + 2), jnp.float32,
                                sharding=one_chip)
     idx = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
@@ -308,7 +310,14 @@ def test_serve_program_compiles_for_v5e(one_chip, tpu_backend, wide_engine,
         "install": (jax.jit(engine._install_program, donate_argnums=(0,)),
                     (pool, rows, idx)),
     }[program]
-    compiled = fn.lower(*args).compile()
+    return fn.lower(*args).compile(), pool
+
+
+@pytest.mark.parametrize("program", ["warm", "cold", "park", "install"])
+def test_serve_program_compiles_for_v5e(one_chip, tpu_backend, wide_engine,
+                                        program):
+    engine, cfg = wide_engine
+    compiled, _ = _compile_serve_program(one_chip, engine, cfg, program)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < 16 * 1024 ** 3)
@@ -316,3 +325,32 @@ def test_serve_program_compiles_for_v5e(one_chip, tpu_backend, wide_engine,
         # The batched prefill attends L*(window-1)+1 rows per session
         # through the local flash kernel.
         assert _mosaic_calls(compiled) > 0
+
+
+@pytest.mark.parametrize("program", ["warm", "park"])
+def test_serve_gather_holds_no_arena_sized_temporary(one_chip, tpu_backend,
+                                                     wide_engine, program):
+    """The programs that gather from the arena read the ``max_batch`` rows
+    they are given and nothing else: at 256 slots for 8 rows a tick the
+    compiled program's temporaries stay under a quarter of the arena, and
+    no value holds every row of a K/V leaf over a part of the window (the
+    parent's ``x[idx]`` sliced each whole leaf into a 128-wide and a
+    73-wide copy first: a third of the arena as temporaries at this shape,
+    290 and 277 MB of 870 MB, and 73% of a warm tick on the chip; PERF.md
+    PR 29)."""
+    import re
+    engine, cfg = wide_engine
+    compiled, pool = _compile_serve_program(one_chip, engine, cfg, program,
+                                            slots=256)
+    arena_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in jax.tree.leaves(pool))
+    gather_bytes = engine.registry.latest("serve_tick_gather_bytes")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < arena_bytes // 4
+    # At most the gathered rows and the model's new ones.
+    assert temp <= 2 * gather_bytes
+    n_arena, layers, heads, window, head_dim = pool["k"].shape
+    widths = {int(w) for w in re.findall(
+        rf"\[{n_arena},{layers},{heads},(\d+),{head_dim}\]",
+        compiled.as_text())}
+    assert widths <= {window}, f"whole-arena slices of widths {widths}"

@@ -48,6 +48,7 @@ from sharetrade_tpu.models.transformer_episode import (
     episode_transformer_policy,
 )
 from sharetrade_tpu.serve import ServeEngine, SlotPool, WeightSwapWatcher
+from sharetrade_tpu.serve.engine import _gather_rows
 from sharetrade_tpu.utils.metrics import MetricsRegistry
 
 WINDOW = 8
@@ -266,6 +267,126 @@ def test_steady_state_is_one_program_per_tick(episode_model,
         assert counters["serve_batches_total"] == batches0 + 3
     finally:
         engine.stop()
+
+
+def _carry_of(kind):
+    """One session's carry of ``kind``: the episode transformer's with K/V
+    in bf16 over a 21-wide window (no multiple of the bf16 tile's 16
+    sublanes: the case whose gather sliced the whole arena on the chip),
+    an LSTM's pair of vectors, or the MLP's empty tree."""
+    if kind == "episode_bf16_w21":
+        carry = episode_transformer_policy(
+            obs_dim=23, num_layers=2, num_heads=2, head_dim=8).init_carry()
+        return dict(carry, k=carry["k"].astype(jnp.bfloat16),
+                    v=carry["v"].astype(jnp.bfloat16))
+    return build_model(ModelConfig(kind=kind, hidden_dim=8), OBS_DIM,
+                       head="ac").init_carry()
+
+
+@pytest.mark.parametrize("idx", [
+    pytest.param([7, 0, 11, 15, 16], id="unique_with_scratch_rows"),
+    pytest.param([4, 9, 12, 12, 12], id="park_padding_repeated"),
+])
+@pytest.mark.parametrize("kind", ["episode_bf16_w21", "lstm", "mlp"])
+def test_gather_rows_is_x_idx_bit_for_bit(kind, idx):
+    """The arena's gather moves bytes: every leaf of any carry tree, for
+    the tick's unique indices (live slots, then scratch rows ``slots + i``
+    for padding) and for the park program's repeated padding index, is
+    bit for bit what ``x[idx]`` returns."""
+    rows, rng = 12 + 5, np.random.default_rng(29)     # slots + max_batch
+
+    def distinct_rows(x):
+        shape = (rows,) + np.shape(x)
+        if jnp.issubdtype(x.dtype, jnp.integer):
+            return jnp.asarray(rng.integers(0, 1 << 20, shape), x.dtype)
+        return jnp.asarray(rng.standard_normal(shape), x.dtype)
+
+    pool = jax.tree.map(distinct_rows, _carry_of(kind))
+    idx = jnp.asarray(idx, jnp.int32)
+    got = jax.jit(_gather_rows)(pool, idx)
+    want = jax.tree.map(lambda x: x[idx], pool)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_serve_step_writes_only_each_rows_ring_slot(episode_model,
+                                                    episode_params, prices):
+    """``apply_serve_batch`` at heterogeneous episode clocks: every row's
+    new K/V differs from its old one in the ring slot of ITS clock, in
+    every layer, and nowhere else (bit for bit), and its clock advances."""
+    rows = 5
+    obs = jnp.stack([jnp.asarray(obs_at(prices, 4 * i, 0))
+                     for i in range(rows)])
+    _, carry = episode_model.apply_prefill(episode_params, obs)
+    t = carry["t"] + jnp.asarray([0, 3, WINDOW - 1, WINDOW, 2 * WINDOW + 1],
+                                 carry["t"].dtype)
+    carry = dict(carry, t=t)
+    _, new = jax.jit(episode_model.apply_serve_batch)(
+        episode_params, obs + 1.0, carry)
+    window = carry["k"].shape[3]
+    slots = np.mod(np.asarray(t) - 1, window)
+    assert np.array_equal(np.asarray(new["t"]), np.asarray(t) + 1)
+    for name in ("k", "v"):
+        before, after = np.asarray(carry[name]), np.asarray(new[name])
+        changed = (before != after).any(axis=(2, 4))      # (rows, L, W)
+        want = np.zeros_like(changed)
+        want[np.arange(rows), :, slots] = True
+        assert np.array_equal(changed, want), name
+
+
+def test_warm_tick_touches_only_the_rows_it_names(episode_model,
+                                                  episode_params, prices):
+    """A warm tick rewrites the arena rows of the sessions it serves (and
+    scratch rows for its padding) and leaves every other row, resident or
+    free, bit for bit as it was."""
+    slots = 8
+    engine = ServeEngine(
+        episode_model,
+        ServeConfig(max_batch=4, slots=slots, batch_timeout_ms=50.0),
+        episode_params)
+    engine.warmup()
+    try:
+        sids = [f"r{i}" for i in range(5)]
+        for tick in range(2):                 # admit + warm everyone
+            handles = [engine.submit(s, obs_at(prices, 4 * i, tick))
+                       for i, s in enumerate(sids)]
+            assert all(h.wait(30.0) for h in handles)
+        before = jax.tree.map(np.asarray, engine._pool)
+        served = sids[:2]
+        handles = [engine.submit(s, obs_at(prices, 4 * i, 2))
+                   for i, s in enumerate(served)]
+        assert all(h.wait(30.0) for h in handles)
+        after = jax.tree.map(np.asarray, engine._pool)
+        named = sorted(engine._slots.lookup(s) for s in served)
+        others = [r for r in range(slots) if r not in named]
+        assert len(others) == slots - 2
+        for b, a in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+            assert np.array_equal(b[others], a[others])
+        assert np.array_equal(after["t"][named], before["t"][named] + 1)
+        assert not np.array_equal(after["k"][named], before["k"][named])
+    finally:
+        engine.stop()
+
+
+def test_serve_tick_gather_bytes_gauge(episode_model, episode_params,
+                                       mlp_model, mlp_params):
+    """``serve_tick_gather_bytes``: what one tick's gather must move,
+    ``max_batch`` rows of every leaf of the arena, exported at build."""
+    for model, params in ((episode_model, episode_params),
+                          (mlp_model, mlp_params)):
+        registry = MetricsRegistry()
+        engine = ServeEngine(model, ServeConfig(max_batch=4, slots=8),
+                             params, registry=registry)
+        try:
+            row_bytes = sum(x[0].nbytes
+                            for x in jax.tree.leaves(engine._pool))
+            assert (row_bytes > 0) == (model is episode_model)
+            assert (registry.latest("serve_tick_gather_bytes")
+                    == 4 * row_bytes)
+        finally:
+            engine.stop()
 
 
 def test_dispatch_fault_fails_batch_not_engine(episode_model,
