@@ -1,101 +1,19 @@
-"""Measure every BASELINE.json config on the attached chip.
+"""The repo's named training configurations, and nothing else.
 
-Prints one JSON line per config (same schema as bench.py) and a summary
-table. bench.py stays the driver's single-line headline; this fills the
-BASELINE.md measurement table across the config ladder.
-
-Usage: python benchmarks/run_all.py [--quick]
+``make_configs()`` is what ``BENCHMARK.json`` and ``chipbench/configs/*.json``
+cite as the source of the two benchmark configurations
+(``ppo_tr_episode_large_d1024``, ``ppo_tr_episode_b512_u1024_bf16``;
+``tests/test_config.py`` holds the benchmark's files to it) and what
+``chip_smoke.py`` imports for its wide-model run. The runner that once timed
+every entry here went with the rest of the pre-chip measurement stack
+(PR 30): ``python3 -m chipbench.run`` measures, ``PERF_LEDGER.jsonl`` and
+PERF.md record. Moving this function needs a ``benchmark`` issue, because
+the files that cite its path are the benchmark's.
 """
 
 from __future__ import annotations
 
-import argparse
-import json
-import sys
-import time
-
-import jax
-
-from sharetrade_tpu.agents import build_agent
 from sharetrade_tpu.config import FrameworkConfig
-from sharetrade_tpu.data.synthetic import synthetic_price_series
-from sharetrade_tpu.env import trading
-from sharetrade_tpu.utils.flops import mfu, train_flops_per_agent_step
-
-REFERENCE_CEILING = 58_450 / 1_005.0  # see bench.py derivation
-
-
-def bench_config(name: str, cfg: FrameworkConfig, *, chunks: int) -> dict:
-    series = synthetic_price_series(length=cfg.data.synthetic_length)
-    env_params = trading.env_from_prices(
-        series.prices, window=cfg.env.window,
-        initial_budget=cfg.env.initial_budget)
-    mesh = None
-    if cfg.parallel.mesh_shape:
-        # Mesh-sharded rows (dp x tp) ride ParallelConfig.mesh_shape; they
-        # need the full device complement and are skipped otherwise (the
-        # bench host has one chip; the multi-chip path is validated by the
-        # CPU-mesh tests and the driver's dryrun).
-        from sharetrade_tpu.parallel import build_mesh
-        import numpy as _np
-        needed = int(_np.prod(list(cfg.parallel.mesh_shape.values())))
-        if needed > jax.device_count():
-            return {"metric": f"{name}_agent_steps_per_sec_per_chip",
-                    "precision": cfg.precision.mode,
-                    "skipped": f"needs {needed} devices, have "
-                               f"{jax.device_count()}"}
-        mesh = build_mesh(cfg.parallel)
-    agent = build_agent(cfg, env_params, mesh=mesh)
-    if mesh is not None:
-        from sharetrade_tpu.parallel import make_parallel_step, mlp_tp_rules
-        rules = mlp_tp_rules() if "tp" in mesh.axis_names else None
-        place, step = make_parallel_step(agent, mesh, param_rules=rules)
-        init = lambda key: place(agent.init(key))  # noqa: E731
-    else:
-        step = jax.jit(agent.step, donate_argnums=0)
-        init = agent.init
-
-    ts = init(jax.random.PRNGKey(0))
-    ts, _ = step(ts)                       # compile + warm chunk
-    jax.block_until_ready(ts.params)
-
-    horizon = trading.num_steps(env_params)
-    if (chunks + 1) * agent.steps_per_chunk > horizon:
-        # The episode can't cover warm + timed chunks (the env freezes past
-        # its horizon — timing frozen chunks would count dead steps, e.g.
-        # the full-episode config). Re-init per rep and time each live
-        # chunk individually.
-        elapsed = 0.0
-        for rep in range(chunks):
-            ts = init(jax.random.PRNGKey(rep + 1))
-            jax.block_until_ready(ts.params)
-            t0 = time.perf_counter()
-            ts, _ = step(ts)
-            jax.block_until_ready(ts.params)
-            elapsed += time.perf_counter() - t0
-    else:
-        t0 = time.perf_counter()
-        for _ in range(chunks):
-            ts, _ = step(ts)
-        jax.block_until_ready(ts.params)
-        elapsed = time.perf_counter() - t0
-
-    agent_steps = chunks * agent.steps_per_chunk * agent.num_agents
-    rate = agent_steps / elapsed
-    obs_dim = env_params.window + 2
-    return {
-        "metric": f"{name}_agent_steps_per_sec_per_chip",
-        "value": round(rate, 2),
-        "unit": "agent-steps/s",
-        "vs_baseline": round(rate / REFERENCE_CEILING, 2),
-        "mfu": round(mfu(rate, cfg, obs_dim), 6),
-        "model_gflops_per_agent_step": round(
-            train_flops_per_agent_step(cfg, obs_dim) / 1e9, 6),
-        # Joins the perf-gate's (metric, backend, precision) series key:
-        # the *_bf16 configs' bf16_mixed rows must fork from their
-        # whole-model-cast history, not gate against it.
-        "precision": cfg.precision.mode,
-    }
 
 
 def make_configs() -> dict[str, FrameworkConfig]:
@@ -222,40 +140,3 @@ def make_configs() -> dict[str, FrameworkConfig]:
             parallel__mesh_shape={"dp": 4, "tp": 2},
             learner__unroll_len=128, runtime__chunk_steps=128),
     }
-
-
-def main() -> None:
-    parser = argparse.ArgumentParser()
-    parser.add_argument("--quick", action="store_true",
-                        help="fewer timed chunks (smoke mode)")
-    parser.add_argument("--only", default=None, help="single config name")
-    args = parser.parse_args()
-
-    configs = make_configs()
-    if args.only and args.only not in configs:
-        parser.error(f"unknown config {args.only!r}; "
-                     f"choose from {sorted(configs)}")
-    results = []
-    for name, cfg in configs.items():
-        if args.only and name != args.only:
-            continue
-        chunks = 2 if args.quick else max(
-            2, 2000 // cfg.runtime.chunk_steps)
-        result = bench_config(name, cfg, chunks=chunks)
-        results.append(result)
-        print(json.dumps(result), flush=True)
-
-    width = max(len(r["metric"]) for r in results)
-    print(f"\n{'config':<{width}}  agent-steps/s  vs ref ceiling       MFU",
-          file=sys.stderr)
-    for r in results:
-        if "skipped" in r:
-            print(f"{r['metric']:<{width}}  skipped: {r['skipped']}",
-                  file=sys.stderr)
-            continue
-        print(f"{r['metric']:<{width}}  {r['value']:>13,.0f}  "
-              f"{r['vs_baseline']:>12,.0f}x  {r['mfu']:>8.2%}", file=sys.stderr)
-
-
-if __name__ == "__main__":
-    main()

@@ -244,8 +244,9 @@ class ParallelConfig:
     # inner-megachunk seams). Keeps GSPMD from re-deriving a transposed-mesh
     # layout for the carry around the sp/pp/ep shard_map regions — the
     # "Involuntary full rematerialization" replicate-and-repartition the
-    # shard audit (tools/shard_audit.py) gates on. Off exists ONLY for the
-    # bench_reshard with/without comparison; leave it on in production.
+    # shard audit (tools/shard_audit.py) gates on. Off exists ONLY as the
+    # with/without comparison's other arm (ROADMAP D4: no cell on either
+    # side); leave it on in production.
     shard_constraints: bool = True
 
 
@@ -300,10 +301,10 @@ class CheckpointConfig:
     # same durability contract the framed journal honors). ``os.replace``
     # alone only orders the rename against other renames — without the
     # fsyncs, a crash can surface a fully-named checkpoint directory whose
-    # data blocks never reached the disk. Default on; the cost is measured
-    # by ``bench.py bench_ckpt_fsync`` (BASELINE.md "Checkpoint fsync") and
-    # is paid on the async writer thread, not the training loop. Off exists
-    # for that benchmark and throwaway runs on ephemeral storage.
+    # data blocks never reached the disk. Default on; the cost is paid on
+    # the async writer thread, not the training loop (not measured on the
+    # chip: the cells save nothing, ROADMAP C5). Off exists for throwaway
+    # runs on ephemeral storage.
     fsync: bool = True
 
 
@@ -398,8 +399,9 @@ class RuntimeConfig:
     # as fallback) and the consumer performs the ENTIRE host-processing
     # block — metric rows, flight recorder, journaling, fault hooks,
     # snapshot updates — strictly in chunk order, so the inter-megachunk
-    # dispatch gap no longer includes host time (bench.py
-    # bench_async_pipeline). Semantics preserved exactly: backpressure
+    # dispatch gap no longer includes host time (on the chip:
+    # ``train.host_busy_share`` and ``train.pipeline_stall_share``,
+    # PERF.md §5). Semantics preserved exactly: backpressure
     # when the queue is full (HBM held by in-flight buffers stays bounded),
     # a drain barrier before the exact-completion K=1 fallback,
     # get_avg/get_std snapshots and checkpoint/eval cadence decisions, and
@@ -493,9 +495,9 @@ class ServeConfig:
     # (its handle completes immediately with a ServeRejected error);
     # under "oldest" the OLDEST queued request is shed instead and the
     # new one admitted (brownout: bounded queueing delay, finite p99,
-    # at the cost of failing stale work first — BASELINE.md "Serve
-    # under overload"). Must be >= 1: an unbounded ingress queue turns
-    # a request flood into unbounded host memory growth
+    # at the cost of failing stale work first). Must be >= 1: an
+    # unbounded ingress queue turns a request flood into unbounded host
+    # memory growth
     # (tools/lint_hot_loop.py check 10 guards the code side).
     max_queue: int = 1024
     shed_policy: str = "reject"          # "reject" | "oldest"
@@ -815,8 +817,8 @@ class ObsConfig:
 
     Everything is OFF by default: a run with ``enabled=False`` creates no
     directories, opens no files, and adds no measurable hot-loop cost
-    (pinned by tests/test_obs.py; measured <2% by bench.py
-    ``bench_obs_overhead`` — BASELINE.md "Telemetry overhead"). All
+    (pinned by tests/test_obs.py; what tracing costs on the chip when it
+    is on: PERF.md §5). All
     instrumentation rides the existing ``runtime.metrics_every_chunks``
     sampling cadence and reads only host-side values from the batched
     megachunk readback — enabling obs adds NO new device syncs

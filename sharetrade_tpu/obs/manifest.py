@@ -33,10 +33,8 @@ def _git_rev() -> str | None:
 
 
 def config_hash(cfg: Any) -> str:
-    """THE stable 16-char config identity — manifest.json's
-    ``config_hash`` and the bench envelope's (``bench._result_envelope``)
-    are the same recipe by construction, so run dirs and BENCH rows join
-    on it."""
+    """THE stable 16-char config identity: manifest.json's
+    ``config_hash``, the one recipe every run dir is joined on."""
     blob = json.dumps(cfg.to_dict(), sort_keys=True).encode()
     return hashlib.sha256(blob).hexdigest()[:16]
 

@@ -1,8 +1,10 @@
 """Roofline telemetry: compiled-cost capture + live MFU/HBM gauges.
 
 The ROADMAP's MFU push starts with measurement: MFU existed only as an
-after-the-fact analytic number in ``bench.py`` (utils/flops.py), invisible
-during training and ungated in CI. This module makes device utilization a
+after-the-fact analytic number (utils/flops.py), invisible during
+training. (What the benchmark reports is ``train_step.mfu`` of
+``chipbench/``, PERF.md §3; this module is ROADMAP D2's named debt.) It
+makes device utilization a
 first-class run-time health signal (the Podracer stance, arxiv 2104.06272):
 
 - **Compile time** — :meth:`RooflineCapture.capture` records XLA
@@ -25,8 +27,7 @@ first-class run-time health signal (the Podracer stance, arxiv 2104.06272):
   entry per captured program: static costs, arithmetic intensity, the
   compute-bound vs memory-bound classification against the chip's ridge
   point), summarized by ``cli obs`` and regression-gated by
-  ``tools/shard_audit.py`` (manifest FLOPs/HBM rows) and
-  ``tools/perf_gate.py`` (bench-row MFU bands).
+  ``tools/shard_audit.py`` (manifest FLOPs/HBM rows).
 
 Everything is gated by ``ObsConfig.roofline`` (off by default): disabled
 means no capture compile, no gauges, no file.
@@ -79,8 +80,7 @@ class ProgramCost:
     this: when the corrected XLA count leaves the ±25% band and the
     analytic model is available, the LIVE GAUGES switch to the analytic
     count (``gauge_flops_source="analytic"`` — the PaLM-convention
-    model-FLOPs MFU, and the same counting behind BENCH_r03's 0.16
-    flagship anchor), with bytes scaled by the same factor (intensity is
+    model-FLOPs MFU), with bytes scaled by the same factor (intensity is
     scale-invariant under the uniform correction, so the classification
     holds either way). Agreement keeps the XLA count
     (``gauge_flops_source="xla"``). Both numbers, the ratio, and the

@@ -68,8 +68,8 @@ class ProfileError(ConfigError):
 @dataclass(frozen=True)
 class Knob:
     """One registered tunable: the dotted config path is its identity
-    (the profile file's key, the bench envelope's knob-vector key, and
-    the lint's shadow-detection leaf)."""
+    (the profile file's key, the manifest's knob-vector key, and the
+    lint's shadow-detection leaf)."""
 
     path: str           # dotted config path, e.g. "serve.batch_timeout_ms"
     tier: str           # "train" | "serve" | "distrib"
@@ -147,10 +147,9 @@ def set_knob(cfg: FrameworkConfig, path: str, value: Any) -> None:
 
 
 def knob_vector(cfg: FrameworkConfig) -> dict[str, Any]:
-    """The RESOLVED value of every registered knob — what a run/bench
-    actually executed under. Stamped into every bench row
-    (``bench._result_envelope``) so autotune trials and BENCH history
-    join on actual knob values, not just ``config_hash``."""
+    """The RESOLVED value of every registered knob — what a run actually
+    executed under, so autotune trials and run dirs join on actual knob
+    values, not just ``config_hash``."""
     return {k.path: get_knob(cfg, k.path) for k in KNOBS}
 
 

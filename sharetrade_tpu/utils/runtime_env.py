@@ -2,7 +2,7 @@
 which device a run's numbers belong to.
 
 One definition of each, shared by every entry point (``cli.main``,
-``chip_smoke.py``, ``bench.py``, ``tests/conftest.py``), so no record can
+``chip_smoke.py``, ``tests/conftest.py``), so no record can
 pass a CPU number off as a chip number and no two entry points disagree
 about the cache's location (the path is part of the cache key — a
 directory that moves never hits).

@@ -143,14 +143,6 @@ class TestTunedProfile:
         finally:
             orch.stop()
 
-    def test_bench_envelope_carries_knob_vector(self):
-        import bench
-        cfg = FrameworkConfig()
-        cfg.runtime.megachunk_factor = 16
-        env = bench._result_envelope(cfg)
-        assert env["knobs"] == tuning.knob_vector(cfg)
-        assert env["knobs"]["runtime.megachunk_factor"] == 16
-
 
 # ---------------------------------------------------------------------------
 # online controller state machine (fake engine, fake clock)
@@ -419,7 +411,7 @@ class TestAdaptiveIngest:
 
 
 # ---------------------------------------------------------------------------
-# lint check 13 + perf-gate direction
+# lint check 13
 # ---------------------------------------------------------------------------
 
 
@@ -461,28 +453,6 @@ class TestLintAndGate:
         bad, found = lint.lint_tuned_knob_shadows()
         assert bad == []
         assert found == set(lint.TUNED_KNOB_PATHS)
-
-    def test_perf_gate_autotune_directions(self):
-        import perf_gate
-        assert perf_gate.lower_is_better("autotune_controller_p99_ms")
-        assert perf_gate.lower_is_better("autotune_sweep_cost_frac")
-        assert perf_gate.lower_is_better("autotune_sweep_cost_s")
-        assert not perf_gate.lower_is_better("serve_qps")
-
-    def test_perf_gate_rows_parse_with_knob_vector(self, tmp_path):
-        """A bench snapshot carrying the new ``knobs`` envelope block
-        still yields exactly its metric rows (the knob dict must not be
-        mistaken for a row)."""
-        import bench
-        import perf_gate
-        cfg = FrameworkConfig()
-        doc = {**bench._result_envelope(cfg),
-               "metric": "autotune_controller_p99_ms", "value": 30.0}
-        path = tmp_path / "BENCH_r99.json"
-        path.write_text(json.dumps({"n": 99, "parsed": doc}))
-        snap = perf_gate.parse_bench_file(str(path))
-        assert [r["metric"] for r in snap["rows"]] == [
-            "autotune_controller_p99_ms"]
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,13 @@
+import json
+import os
+import re
+
+import pytest
+
+from benchmarks.run_all import make_configs
 from sharetrade_tpu.config import FrameworkConfig
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_defaults_match_reference_constants():
@@ -54,3 +63,34 @@ def test_override_unknown_key_raises():
         cfg.apply_overrides(["learner.nope=1"])
     with pytest.raises(ValueError):
         cfg.apply_overrides(["learner.gamma"])
+
+
+@pytest.mark.parametrize("name", ["tr_episode_d1024", "tr_episode_d256"])
+def test_benchmark_config_matches_cited_source(name):
+    """The benchmark's configuration files cite
+    ``benchmarks/run_all.py make_configs()["<key>"]`` as their source: every
+    override they do not list as ``reduced``, and every ``assumed`` field,
+    is that entry's value (the one reason that module is still here). The
+    ``reduced`` keys really differ from it, or they are not reductions."""
+    with open(os.path.join(REPO, "chipbench", "configs",
+                           f"{name}.json")) as f:
+        doc = json.load(f)
+    cited = re.search(r'make_configs\(\)\["(\w+)"\]', doc["source"])
+    assert cited, doc["source"]
+    source = make_configs()[cited.group(1)]
+
+    def field(path):
+        obj = source
+        for part in path.split("."):
+            obj = getattr(obj, part)
+        return obj
+
+    reduced = set(doc["reduced"])
+    assert reduced <= set(doc["overrides"])
+    for path, value in doc["overrides"].items():
+        if path in reduced:
+            assert field(path) != value, path
+        else:
+            assert field(path) == value, path
+    for path, value in doc["assumed"].items():
+        assert field(path) == value, path

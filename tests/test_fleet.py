@@ -3,8 +3,9 @@ routed engine fleet, flywheel journaling.
 
 The load-bearing contracts:
 
-- **Wire fidelity**: serving over HTTP is the SAME serving — bitwise
-  logits through JSON, every engine-side outcome reconstructed as its
+- **Wire fidelity**: serving over HTTP is the SAME serving — the engine's
+  logits bit for bit through JSON (and so the reference's answer within
+  the written tolerance, ``serving_parity.assert_same_answer``), every engine-side outcome reconstructed as its
   exact exception class from a distinct wire status.
 - **Deadline propagation**: the client's ``X-Deadline-Ms`` header flows
   into ``submit(deadline_ms=)`` and expiry happens at the ENGINE's
@@ -15,8 +16,9 @@ The load-bearing contracts:
   sample quantiles within one bucket width, through a full
   render→scrape→rebuild round trip.
 - **Migration**: kill a session's affine engine mid-conversation and its
-  next request lands on a survivor COLD — bitwise a fresh session's
-  first step (the PR-8 prefill contract stretched across processes).
+  next request lands on a survivor COLD — bit for bit what that survivor
+  answers a fresh session, and the reference's fresh first step within
+  the written tolerance (the PR-8 prefill contract stretched across processes).
 - **Degrade**: all engines gone ⇒ the router answers ServeEngineFailed
   (503) loudly, never a wedge; the EnginePool's ladder (shared with
   distrib/) classifies crashes, backs off seeded, and fails terminally
@@ -62,6 +64,12 @@ from sharetrade_tpu.serve.engine import (
     latency_percentiles,
 )
 from sharetrade_tpu.utils.metrics import MetricsRegistry
+
+from serving_parity import (
+    SequentialReference,
+    assert_other_answer,
+    assert_same_answer,
+)
 
 WINDOW = 8
 OBS_DIM = WINDOW + 2
@@ -127,14 +135,20 @@ class TestWireProtocol:
             client = FleetClient(frontend.host, frontend.port)
             obs = _obs(3)
             out = client.submit("w1", obs)
-            direct, _ = model.apply(params, obs, model.init_carry())
-            # float64 JSON round-trips float32 exactly: the serving
-            # tier's bitwise parity contract survives the wire.
-            assert np.asarray(out["logits"], np.float32).tobytes() \
+            wire_logits = np.asarray(out["logits"], np.float32)
+            # float64 JSON round-trips float32 exactly: the wire reply is
+            # bit for bit this engine's in-process reply (the same
+            # program on the same bytes; the MLP keeps no carry) ...
+            direct = engine.submit("w1-direct", obs).wait(30.0)
+            assert wire_logits.tobytes() \
                 == np.asarray(direct.logits, np.float32).tobytes()
+            # ... and the one-row reference's answer within the written
+            # tolerance.
+            ref_action, ref_logits = SequentialReference(
+                model, params).step("w1", obs)
+            assert_same_answer(wire_logits, ref_logits, "over the wire")
             assert out["params_step"] == 11
-            assert out["action"] == int(np.argmax(
-                np.asarray(direct.logits)))
+            assert out["action"] == direct.action == ref_action
             stages = out["stages"]
             assert abs(sum(stages.values()) - out["latency_ms"]) < 1e-6
             client.close()
@@ -276,9 +290,11 @@ class TestRouterMigration:
     def test_affinity_sticks_and_migrates_bitwise(self, lstm_model):
         """A session sticks to its engine's slot-pool carry; killing the
         engine mid-conversation re-routes the next request to a survivor
-        where the session re-enters COLD through the prefill — bitwise a
-        fresh session's first step (an LSTM makes warm≠cold observable:
-        a surviving warm carry would change the logits)."""
+        where the session re-enters COLD through the prefill — bit for
+        bit what the survivor answers a fresh session, and the
+        reference's fresh first step within the written tolerance (an
+        LSTM makes warm≠cold observable: a surviving warm carry would
+        change the logits in the first digits)."""
         model, params = lstm_model
         e1, f1, _ = _boot_engine(model, params, step=1)
         e2, f2, _ = _boot_engine(model, params, step=1)
@@ -297,19 +313,24 @@ class TestRouterMigration:
             assert warm["engine"] == home
             # Warm logits differ from a cold first step on obs_b — the
             # carry is real, so the migration claim below is non-trivial.
-            cold_out, _ = model.apply(params, obs_b, model.init_carry())
-            cold_logits = np.asarray(cold_out.logits, np.float32)
-            assert np.asarray(warm["logits"], np.float32).tobytes() \
-                != cold_logits.tobytes()
+            _, cold_logits = SequentialReference(model, params).step(
+                "fresh", obs_b)
+            assert_other_answer(warm["logits"], cold_logits)
             # Kill the home engine (process-death stand-in).
             victim_fe, victim_eng = (f1, e1) if home == "e0" else (f2, e2)
+            survivor = e2 if home == "e0" else e1
             victim_fe.stop()
             victim_eng.stop(drain=False)
             migrated = client.submit("mig", obs_b)
             assert migrated["engine"] != home
+            assert_same_answer(
+                migrated["logits"], cold_logits,
+                "migrated session must answer as a fresh session")
+            # The same program on the same bytes: the survivor's own
+            # answer to a session it has never seen.
+            fresh = survivor.submit("never-seen", obs_b).wait(30.0)
             assert np.asarray(migrated["logits"], np.float32).tobytes() \
-                == cold_logits.tobytes(), \
-                "migrated session must equal a fresh session bitwise"
+                == np.asarray(fresh.logits, np.float32).tobytes()
             assert reg.counters().get("fleet_migrations_total", 0) == 1
             client.close()
         finally:

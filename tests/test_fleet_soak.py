@@ -37,7 +37,10 @@ class TestQuickSoak:
             engines=2, kills=1, ramp_s=3.0, sessions=32, concurrency=8,
             workdir=str(tmp_path))
         assert summary["ok"] is True
-        assert summary["kills_injected"] == 1
+        # One kill, or more (bounded) until one found a request inside
+        # its victim: the forensics below need such a kill.
+        assert 1 <= summary["kills_injected"] \
+            <= 1 + fleet_soak.MAX_EXTRA_KILLS
         # Migration absorbed the kill: the closed loop dropped nothing.
         assert summary["traffic"]["failed"] == 0
         assert summary["traffic"]["completed"] > 0
@@ -50,15 +53,20 @@ class TestQuickSoak:
         # Live merged-histogram SLO gauges.
         assert summary["fleet_slo"]["merged"]["count"] > 0
         assert summary["drain_rc"] == 75
-        # Stitched kill forensics: one CLEAN trace spans the killed
-        # engine (eagerly-flushed ingress marker), a survivor, the
-        # client's root span, and the router's migrate-annotated relay
-        # attempt (run_soak raises unless all of that held).
+        # Stitched kill forensics: when a kill found a request inside its
+        # victim, one CLEAN trace spans the killed engine (eagerly-flushed
+        # ingress marker), a survivor, the client's root span, and the
+        # router's migrate-annotated relay attempt (run_soak raises
+        # unless all of that held); when none of the kills did, there is
+        # no such trace and the soak says so.
         tr = summary["tracing"]
         assert tr["migrated_traces"] >= 1
-        assert len(tr["witness"]["engines"]) >= 2
-        assert "client" in tr["witness"]["procs"]
-        assert "fleet" in tr["witness"]["procs"]
+        if tr["kill_caught_request"]:
+            assert tr["witness"] is not None
+        if tr["witness"] is not None:
+            assert len(tr["witness"]["engines"]) >= 2
+            assert "client" in tr["witness"]["procs"]
+            assert "fleet" in tr["witness"]["procs"]
 
 
 class TestAutoscaleSoak:

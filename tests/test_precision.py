@@ -419,50 +419,8 @@ class TestCheckpointPrecision:
 
 
 # ---------------------------------------------------------------------------
-# satellites: perf-gate precision series split, lint check 7
+# satellites: lint check 7
 # ---------------------------------------------------------------------------
-
-class TestPerfGateSplit:
-    def test_precision_splits_series(self, tmp_path):
-        """A bf16_mixed row never gates against fp32 history: a 10x
-        apparent 'regression' across precisions stays ungated."""
-        import sys
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                        "tools"))
-        import perf_gate
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-            "n": 1, "parsed": {"metric": "m", "value": 1000.0,
-                               "schema_version": 1, "backend": "cpu",
-                               "precision": "fp32"}}))
-        (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-            "n": 2, "parsed": {"metric": "m", "value": 100.0,
-                               "schema_version": 1, "backend": "cpu",
-                               "precision": "bf16_mixed"}}))
-        assert perf_gate.run_gate(tmp_path) == 0
-        # same precision still gates
-        (tmp_path / "BENCH_r03.json").write_text(json.dumps({
-            "n": 3, "parsed": {"metric": "m", "value": 100.0,
-                               "schema_version": 1, "backend": "cpu",
-                               "precision": "fp32"}}))
-        assert perf_gate.run_gate(tmp_path) == 1
-
-    def test_legacy_rows_default_to_fp32_series(self, tmp_path):
-        import sys
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                        "tools"))
-        import perf_gate
-        (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-            "n": 1, "parsed": {"metric": "m", "value": 100.0}}))  # legacy
-        (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-            "n": 2, "parsed": {"metric": "m", "value": 99.0,
-                               "schema_version": 1, "backend": "tpu",
-                               "precision": "fp32"}}))
-        series = perf_gate.collect_series([
-            perf_gate.parse_bench_file(str(tmp_path / "BENCH_r01.json")),
-            perf_gate.parse_bench_file(str(tmp_path / "BENCH_r02.json"))])
-        assert ("m", "tpu", "fp32", "value") in series
-        assert len(series[("m", "tpu", "fp32", "value")]) == 2
-
 
 class TestLintCheck7:
     def test_repo_is_clean(self):

@@ -635,7 +635,7 @@ class TestOrchestratorReplayPlane:
 
 
 # ---------------------------------------------------------------------------
-# guards: lint check 9, perf-gate direction, cli obs section
+# guards: lint check 9, cli obs section
 # ---------------------------------------------------------------------------
 
 class TestGuards:
@@ -660,26 +660,6 @@ class TestGuards:
         # jax.random stays legal; dotted open too.
         assert not pat.search("jax.random.split(key)")
         assert not pat.search("k = jax.random.uniform(key, (3,))")
-
-    def test_perf_gate_direction_for_replay_metrics(self):
-        from perf_gate import gate, lower_is_better
-        assert lower_is_better("journal_bytes_per_record")
-        assert lower_is_better("replay_sample_ms")
-        assert not lower_is_better("replay_per_steps_per_sec")
-
-        def series(metric, *vals):
-            return {(metric, "cpu", "fp32", "value"): [
-                {"round": i, "path": f"r{i}", "value": v}
-                for i, v in enumerate(vals)]}
-
-        # Bytes/record RISE past the band fails; a drop passes.
-        assert not gate(series("journal_bytes_per_record", 100.0, 140.0),
-                        {"value": 0.25})["ok"]
-        assert gate(series("journal_bytes_per_record", 100.0, 60.0),
-                    {"value": 0.25})["ok"]
-        # Replay throughput DROP past the band fails.
-        assert not gate(series("replay_per_steps_per_sec", 1000.0, 700.0),
-                        {"value": 0.25})["ok"]
 
     def test_cli_obs_replay_section(self, tmp_path):
         from sharetrade_tpu.obs import summarize_run_dir

@@ -1,4 +1,4 @@
-"""Roofline telemetry (obs/roofline.py) + perf gate (tools/perf_gate.py).
+"""Roofline telemetry (obs/roofline.py).
 
 What this file pins, per the roofline PR's acceptance criteria:
 
@@ -11,9 +11,6 @@ What this file pins, per the roofline PR's acceptance criteria:
   and no gauges — the knob is inert until asked for;
 - the analytic-vs-XLA discrepancy warning fires (flight ring + log) on a
   deliberately wrong analytic count;
-- ``tools/perf_gate.py`` passes on a self-baseline and fails on a
-  synthetically regressed row — and passes on the repo's real BENCH
-  trajectory (the ``make check`` wiring must not be red on day one);
 - the compile-time-only lint (tools/lint_hot_loop.py check 6) stays
   green on the shipped tree.
 """
@@ -295,96 +292,6 @@ def test_summarize_roofline_orders_by_flops():
     s = summarize_roofline(bundle)
     assert s["compute_bound"][0]["program"] == "b"
     assert s["memory_bound"][0]["program"] == "a"
-
-
-# ---------------------------------------------------------------------------
-# perf gate
-# ---------------------------------------------------------------------------
-
-def _snapshot(path, n, metric, value, mfu=None, backend=None):
-    parsed = {"metric": metric, "value": value, "schema_version": 1,
-              "backend": backend or "cpu"}
-    if mfu is not None:
-        parsed["mfu"] = mfu
-    path.write_text(json.dumps({"n": n, "parsed": parsed}))
-
-
-def test_perf_gate_passes_on_self_baseline(tmp_path):
-    import perf_gate
-
-    _snapshot(tmp_path / "BENCH_r01.json", 1, "m", 100.0, mfu=0.1)
-    _snapshot(tmp_path / "BENCH_r02.json", 2, "m", 100.0, mfu=0.1)
-    assert perf_gate.run_gate(tmp_path) == 0
-
-
-def test_perf_gate_fails_on_degraded_row(tmp_path, capsys):
-    import perf_gate
-
-    _snapshot(tmp_path / "BENCH_r01.json", 1, "m", 100.0, mfu=0.1)
-    _snapshot(tmp_path / "BENCH_r02.json", 2, "m", 50.0, mfu=0.1)
-    assert perf_gate.run_gate(tmp_path) == 1
-    assert "FAIL" in capsys.readouterr().out
-
-
-def test_perf_gate_fails_on_mfu_regression_alone(tmp_path):
-    import perf_gate
-
-    _snapshot(tmp_path / "BENCH_r01.json", 1, "m", 100.0, mfu=0.2)
-    _snapshot(tmp_path / "BENCH_r02.json", 2, "m", 101.0, mfu=0.05)
-    assert perf_gate.run_gate(tmp_path) == 1
-
-
-def test_perf_gate_separates_backends(tmp_path):
-    """A CPU-fallback round must not gate against TPU-era numbers: the
-    r04/r05 outage pattern — huge apparent 'regression', different
-    backend — stays a note, not a failure."""
-    import perf_gate
-
-    _snapshot(tmp_path / "BENCH_r01.json", 1, "m", 100000.0, backend="tpu")
-    _snapshot(tmp_path / "BENCH_r02.json", 2, "m", 100.0, backend="cpu")
-    assert perf_gate.run_gate(tmp_path) == 0
-
-
-def test_perf_gate_legacy_fallback_parser(tmp_path):
-    """Pre-schema snapshots (no schema_version, cpu_fallback subtree, raw
-    tail line) parse through the fallback path."""
-    import perf_gate
-
-    # Legacy TPU row (r01 shape).
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({
-        "n": 1, "parsed": {"metric": "m", "value": 200.0}}))
-    # Parse-failed snapshot whose tail still holds the JSON line.
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({
-        "n": 2, "tail": "noise\n" + json.dumps(
-            {"metric": "m", "value": 195.0}) + "\n"}))
-    # Error round with a cpu_fallback subtree (r05 shape).
-    (tmp_path / "BENCH_r03.json").write_text(json.dumps({
-        "n": 3, "parsed": {"error": "no device", "cpu_fallback": {
-            "metric": "m", "value": 50.0, "backend": "cpu"}}}))
-    snap1 = perf_gate.parse_bench_file(str(tmp_path / "BENCH_r01.json"))
-    assert snap1["rows"] == [{"metric": "m", "value": 200.0,
-                              "backend": "tpu"}]
-    snap3 = perf_gate.parse_bench_file(str(tmp_path / "BENCH_r03.json"))
-    assert snap3["rows"][0]["backend"] == "cpu"
-    assert perf_gate.run_gate(tmp_path) == 0   # 200 -> 195 within band
-
-
-def test_perf_gate_candidate_row(tmp_path):
-    import perf_gate
-
-    _snapshot(tmp_path / "BENCH_r01.json", 1, "m", 100.0)
-    cand = tmp_path / "candidate.json"
-    cand.write_text(json.dumps({"metric": "m", "value": 10.0,
-                                "schema_version": 1, "backend": "cpu"}))
-    assert perf_gate.run_gate(tmp_path, candidate=str(cand)) == 1
-
-
-def test_perf_gate_passes_on_repo_trajectory():
-    """The make-check wiring: the gate must be green on the checked-in
-    BASELINE.json + BENCH_r01..r05 trajectory."""
-    import perf_gate
-
-    assert perf_gate.run_gate(REPO) == 0
 
 
 def test_roofline_lint_green():

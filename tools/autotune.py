@@ -8,12 +8,11 @@ SHORT measured window per trial and an early-stopping search:
 
 - **train** — ``runtime.megachunk_factor`` x ``runtime.pipeline_depth``
   on the dispatch-floor workload (tiny qlearn through the REAL
-  orchestrator hot loop, the bench_async_pipeline harness shape);
-  objective: agent-steps/s.
+  orchestrator hot loop); objective: agent-steps/s.
 - **serve** — ``serve.max_batch`` x ``serve.batch_timeout_ms`` x
   ``serve.max_queue`` on the MLP serving workload (tools/serve_soak.py's
   acceptance stack); objective: closed-loop saturation QPS, with the p99
-  at that load recorded per trial (the BENCH join columns).
+  at that load recorded per trial.
 - **distrib** — ``distrib.ingest_every_updates`` x
   ``distrib.ingest_max_rows`` against a feeder thread appending
   transition rows to a synthetic actor journal while the learner trains;
@@ -30,8 +29,8 @@ the sweep-vs-exhaustive wall-clock ratio measures the search, not
 rebuild overhead. ``--exhaustive`` additionally measures EVERY arm at
 the final (largest) window — the hand-sweep baseline the acceptance
 compares against: chosen-arm objective within 10% of the exhaustive
-best, total sweep cost < 25% of the exhaustive grid's wall-clock
-(recorded in BASELINE.md with seeds).
+best, total sweep cost < 25% of the exhaustive grid's wall-clock (CPU
+readings only: no knob here has been swept on the chip, ROADMAP D2).
 
 Output: an atomic, schema-versioned ``tuned_profile.json`` (host
 fingerprint: cores/backend/device count) that ``config.py`` loads via
@@ -436,8 +435,8 @@ def run_spec(spec: str, *, quick: bool, seed: int, workdir: str,
         if exhaustive:
             # The hand-sweep baseline: EVERY arm at the full-confidence
             # window — double the halving's top rung, best of 2 trials
-            # per arm (the bench_dispatch_floor discipline: a single
-            # short sample on a shared host ranks scheduler luck).
+            # per arm (a single short sample on a shared host ranks
+            # scheduler luck).
             # sweep_cost_frac compares MEASUREMENT seconds only: per-arm
             # build/compile happens exactly once under either strategy
             # (arm state is cached across rungs and reused here), so

@@ -151,8 +151,7 @@ def run_soak(*, duration_s: float = 5.0, sessions: int = 2000,
     # ISSUE-11 stage decomposition: the engine self-checks that every
     # completed request's queue_wait + batch_wait + device stages sum to
     # its end-to-end latency; the soak asserts the violation counter
-    # stayed 0 and reports the histogram-derived per-stage tails (the
-    # perf-gate rows — *_ms suffixes gate lower-is-better).
+    # stayed 0 and reports the histogram-derived per-stage tails.
     reg = engine.registry
     decomp_errors = int(reg.counters().get(
         "serve_trace_decomposition_error_total", 0))

@@ -22,8 +22,7 @@ What the gate certifies (the anti-resharding tentpole, round 8):
    (the remat gate always applies). ``--update`` re-measures and rewrites
    the manifest.
 3. **Memory report.** ``compiled.memory_analysis()`` (arguments / temps /
-   output bytes) per config, recorded in the report for BASELINE.md's
-   "Multichip resharding" table.
+   output bytes) per config, recorded in the report.
 4. **Roofline rows (the obs/roofline PR).** Per-config FLOPs and HBM
    bytes of the compiled megachunk program — ``cost_analysis()`` FLOPs /
    bytes-accessed (raw HLO counts: loop bodies counted once, so the
@@ -87,7 +86,7 @@ CONFIGS: list[dict] = [
      "window": 16, "unroll": 34, "chunk": 34, "workers": 4, "series": 80},
     # The three configs that actually reproduced the involuntary-remat
     # warnings before the round-8 fix (PPO's permuted minibatch gathers
-    # over dp-sharded rollout products; MULTICHIP_r01..r05's
+    # over dp-sharded rollout products; the multichip dry runs'
     # [4,1,2]→[1,2,4] on ts.carry['hist'] is dp4_sp2's signature) — kept in
     # the matrix verbatim so the gate would re-catch a regression at the
     # shapes that exposed it, not just at neighbors.
@@ -130,8 +129,8 @@ CONFIGS: list[dict] = [
 
 
 # ---------------------------------------------------------------------------
-# HLO text analysis (shared with bench.py bench_reshard and the tier-1
-# sharding-consistency tests — parent-side only, no jax import needed)
+# HLO text analysis (shared with the tier-1 sharding-consistency tests —
+# parent-side only, no jax import needed)
 # ---------------------------------------------------------------------------
 
 #: ``<shapes> <op>(`` — group 1 is the result-shape text, group 2 the op.
@@ -154,8 +153,8 @@ def collective_counts(hlo_text: str) -> dict[str, int]:
 
 
 def collective_bytes(hlo_text: str) -> int:
-    """Total result bytes of all collective ops — the per-dispatch collective
-    traffic proxy bench_reshard reports (result size; a same-size all-reduce
+    """Total result bytes of all collective ops — a per-dispatch collective
+    traffic proxy (result size; a same-size all-reduce
     moves ~2x this on a ring, but the METRIC only needs to move when the
     program's collectives do)."""
     total = 0
