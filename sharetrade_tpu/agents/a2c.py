@@ -17,7 +17,7 @@ from sharetrade_tpu.agents.base import (
 )
 from sharetrade_tpu.agents.rollout import (
     collect_rollout, discounted_returns, normalize_advantages_masked,
-    replay_forward,
+    replay_forward, taken_action_log_prob,
 )
 from sharetrade_tpu.config import LearnerConfig
 from sharetrade_tpu.env.core import TradingEnv
@@ -60,8 +60,7 @@ def make_a2c_agent(model: Model, env: TradingEnv,
             logits, values, aux = replay_forward(
                 model, params, traj, replay_init, remat=cfg.remat)
             log_probs = jax.nn.log_softmax(logits)
-            logp = jnp.take_along_axis(
-                log_probs, traj.action[..., None], axis=-1)[..., 0]
+            logp = taken_action_log_prob(log_probs, traj.action)
             adv = jax.lax.stop_gradient(returns - values) * weight
             if cfg.normalize_advantages:
                 adv = normalize_advantages_masked(adv, weight, denom)

@@ -16,7 +16,7 @@ from sharetrade_tpu.agents.base import (
 )
 from sharetrade_tpu.agents.rollout import (
     collect_rollout, discounted_returns, normalize_advantages_masked,
-    replay_forward,
+    replay_forward, taken_action_log_prob,
 )
 from sharetrade_tpu.config import LearnerConfig
 from sharetrade_tpu.env.core import TradingEnv
@@ -63,9 +63,8 @@ def make_pg_agent(model: Model, env: TradingEnv,
         def loss_fn(params):
             logits, _, aux = replay_forward(model, params, traj, replay_init,
                                             remat=cfg.remat)
-            logp = jnp.take_along_axis(
-                jax.nn.log_softmax(logits), traj.action[..., None], axis=-1
-            )[..., 0]
+            logp = taken_action_log_prob(
+                jax.nn.log_softmax(logits), traj.action)
             pg_loss = -jnp.sum(logp * jax.lax.stop_gradient(adv)) / denom
             return pg_loss + cfg.aux_loss_coef * aux
 

@@ -24,7 +24,7 @@ from sharetrade_tpu.agents.base import (
 )
 from sharetrade_tpu.agents.rollout import (
     collect_rollout, gae_advantages, normalize_advantages_masked,
-    replay_carry, replay_forward,
+    replay_carry, replay_forward, taken_action_log_prob,
 )
 from sharetrade_tpu.config import LearnerConfig
 from sharetrade_tpu.env.core import TradingEnv
@@ -96,8 +96,7 @@ def make_ppo_agent(model: Model, env: TradingEnv,
         logits, values, aux = replay_forward(model, params, traj_mb, carry_mb,
                                              remat=cfg.remat)
         log_probs = jax.nn.log_softmax(logits)
-        logp = jnp.take_along_axis(
-            log_probs, traj_mb.action[..., None], axis=-1)[..., 0]
+        logp = taken_action_log_prob(log_probs, traj_mb.action)
         weight = traj_mb.active
         denom = jnp.maximum(jnp.sum(weight), 1.0)
 

@@ -291,11 +291,7 @@ def _collect_rollout_precomputed(model: Model, env: TradingEnv,
 
         logits, value = head_outs(head_i, obs)
         actions = jnp.argmax(logits + g_i, axis=-1).astype(jnp.int32)
-        log_probs = jax.nn.log_softmax(logits)
-        # one_hot contraction, not take_along_axis: gathers are scalar-unit
-        # dispatches inside a scan.
-        logp = jnp.sum(
-            log_probs * jax.nn.one_hot(actions, log_probs.shape[-1]), axis=-1)
+        logp = taken_action_log_prob(jax.nn.log_softmax(logits), actions)
 
         # step_priced is guaranteed by supports_precomputed_trunk.
         stepped, rewards = jax.vmap(
@@ -474,6 +470,23 @@ def replay_forward(model: Model, params: Any, traj: StepData, replay_init,
 
     _, (logits, values, aux) = jax.lax.scan(one_step, replay_init, traj.obs)
     return logits, values, jnp.mean(aux)  # (T, B, A), (T, B), scalar
+
+
+def taken_action_log_prob(log_probs: jax.Array,
+                          action: jax.Array) -> jax.Array:
+    """``log_probs[..., action]``, the log-prob of the action each agent
+    took — THE expression the rollout's behaviour log-prob and every
+    policy-gradient replay share. A select over the action axis, not
+    ``take_along_axis``: gathers are scalar-unit dispatches on the TPU,
+    inside a scan and outside one (half of a d256 PPO chunk, PERF.md PR 31).
+    A select, not a product with the one-hot: an untaken action's ``-inf``
+    stays out of the sum (0 * -inf is NaN), the result is the gather's bit
+    for bit (but a taken -0.0, which no log_softmax gives, reads +0.0), and
+    the derivative is a select of the cotangent — no scatter.
+    ``log_probs`` (..., A), ``action`` (...) integer and in range."""
+    taken = action[..., None] == jnp.arange(log_probs.shape[-1],
+                                            dtype=action.dtype)
+    return jnp.sum(jnp.where(taken, log_probs, 0), axis=-1)
 
 
 def normalize_advantages_masked(adv: jax.Array, weight: jax.Array,

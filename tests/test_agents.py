@@ -377,6 +377,21 @@ def _carry_bytes(carry) -> int:
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(carry))
 
 
+def _params_after_two_chunks(agent):
+    """Parameters after a prefill chunk and a carry-crossing one, seed 3."""
+    ts = agent.init(jax.random.PRNGKey(3))
+    step = jax.jit(agent.step)
+    ts, _ = step(ts)
+    ts, metrics = step(ts)
+    assert np.isfinite(float(metrics["loss"]))
+    return jax.device_get(ts.params)
+
+
+def _assert_same_params(got, want):
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_ppo_chunk_same_params_from_trimmed_or_whole_carry():
     """The loop gathers the model's ``replay_carry`` of the unroll-start
     carry; gathering the WHOLE carry (a test-only model whose hook keeps
@@ -394,16 +409,76 @@ def test_ppo_chunk_same_params_from_trimmed_or_whole_carry():
     whole = build_agent(cfg, tiny_env(), model=whole_model)
     assert whole.replay_carry_bytes > 10 * trimmed.replay_carry_bytes
 
-    results = []
-    for agent in (trimmed, whole):
-        ts = agent.init(jax.random.PRNGKey(3))
-        step = jax.jit(agent.step)
-        ts, _ = step(ts)
-        ts, metrics = step(ts)
-        assert np.isfinite(float(metrics["loss"]))
-        results.append(jax.device_get(ts.params))
-    for a, b in zip(*map(jax.tree.leaves, results)):
-        np.testing.assert_array_equal(a, b)
+    _assert_same_params(_params_after_two_chunks(trimmed),
+                        _params_after_two_chunks(whole))
+
+
+# -- the log-prob of the taken action: a select, not a gather ---------------
+
+def _gathered_log_prob(log_probs, action):
+    return jnp.take_along_axis(log_probs, action[..., None], axis=-1)[..., 0]
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view({2: np.uint16, 4: np.uint32}[x.dtype.itemsize])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("num_actions", [3, 9])
+@pytest.mark.parametrize("batch_shape", [(5,), (7, 5)], ids=["BA", "TBA"])
+@pytest.mark.parametrize("untaken_inf", [False, True],
+                         ids=["finite", "untaken_inf"])
+def test_taken_action_log_prob_is_the_gather_bit_for_bit(
+        batch_shape, num_actions, dtype, untaken_inf):
+    """Value and gradient of the select are ``take_along_axis``'s to the
+    bit, also with a ``-inf`` at an action not taken (a product with the
+    one-hot gives NaN there: 0 * -inf)."""
+    from sharetrade_tpu.agents.rollout import taken_action_log_prob
+
+    k_logits, k_action, k_weight = jax.random.split(jax.random.PRNGKey(11), 3)
+    logits = 3.0 * jax.random.normal(k_logits, batch_shape + (num_actions,))
+    action = jax.random.randint(k_action, batch_shape, 0, num_actions)
+    if untaken_inf:
+        logits = jnp.where(
+            ((action + 1) % num_actions)[..., None] == jnp.arange(num_actions),
+            -jnp.inf, logits)
+    log_probs = jax.nn.log_softmax(logits).astype(dtype)
+    weight = jax.random.normal(k_weight, batch_shape).astype(dtype)
+    assert bool(jnp.any(jnp.isinf(log_probs))) == untaken_inf
+
+    got = jax.jit(taken_action_log_prob)(log_probs, action)
+    want = jax.jit(_gathered_log_prob)(log_probs, action)
+    assert got.dtype == want.dtype == dtype and got.shape == batch_shape
+    assert np.all(np.isfinite(np.asarray(got, np.float32)))
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+    def weighted(fn):
+        return jax.jit(jax.grad(
+            lambda lp: jnp.sum(fn(lp, action) * weight).astype(jnp.float32)))
+
+    g_got = weighted(taken_action_log_prob)(log_probs)
+    g_want = weighted(_gathered_log_prob)(log_probs)
+    assert g_got.dtype == g_want.dtype == dtype
+    np.testing.assert_array_equal(_bits(g_got), _bits(g_want))
+
+
+def test_ppo_chunk_same_params_from_select_or_gather(monkeypatch):
+    """A PPO chunk pair from one seed trains to the same parameters, bit
+    for bit, with the shared select and with ``take_along_axis`` put back
+    in its place (the rollout's behaviour log-prob and the replay's)."""
+    from sharetrade_tpu.agents import ppo, rollout
+
+    def train():
+        return _params_after_two_chunks(
+            build_agent(_episode_ppo_config(), tiny_env()))
+
+    selected = train()
+    for module in (ppo, rollout):
+        monkeypatch.setattr(module, "taken_action_log_prob",
+                            _gathered_log_prob)
+    _assert_same_params(selected, train())
 
 
 @pytest.mark.parametrize("kind", ["transformer_episode", "lstm", "mlp"])

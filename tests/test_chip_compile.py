@@ -232,6 +232,40 @@ def test_agent_step_moves_no_kv_cache_per_minibatch(wide_step):
     assert 0 < checked <= 2 * batch * layers * heads * width * head_dim
 
 
+def test_agent_step_gathers_no_action_log_prob(wide_step):
+    """The replay reads the taken action's log-prob by a select over the
+    action axis (``agents/rollout.py taken_action_log_prob``): the compiled
+    step holds no gather or scatter over the replay's ``[unroll, mb, A]``
+    log-probs, and no element-by-element gather (every slice size 1) of
+    ``unroll * mb`` results — ``take_along_axis`` was one, a serial fusion
+    of 12.5 ns an element on the chip, 15% of the d=1024 chunk and half of
+    the d=256 one (PERF.md PR 31). The minibatch's own column gathers are
+    ``[unroll, mb]`` too, a whole column a slice: they stay."""
+    import math
+    import re
+    agent, _, compiled = wide_step
+    text = compiled.as_text()
+    per_step = agent.steps_per_chunk * (agent.num_agents // 4)   # unroll * mb
+    per_action = per_step * agent.model.num_actions
+
+    def elements(dims):
+        return math.prod(int(d) for d in dims.split(",") if d)
+
+    shape_of = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text))
+    moves = re.findall(
+        r"(%[\w.\-]+) = \w+\[([\d,]*)\]\S* (gather|scatter)"
+        r"\(([^)]*)\)(.*)", text)
+    assert moves            # the minibatch gathers: the pattern still reads
+    found = []
+    for name, dims, op, operands, rest in moves:
+        sizes = [elements(dims)] + [
+            elements(shape_of[o]) for o in re.findall(r"%[\w.\-]+", operands)]
+        by_element = re.search(r"slice_sizes=\{1(,1)*\}", rest) is not None
+        if per_action in sizes or (by_element and sizes[0] == per_step):
+            found.append(f"{name} = [{dims}] {op}({operands})")
+    assert not found, found
+
+
 def _window_config():
     """A window-mode transformer PPO (the other model file that calls the
     flash kernel), small: its dp=4 step must keep the batch dp-sharded
