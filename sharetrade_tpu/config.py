@@ -106,7 +106,7 @@ class EnvConfig:
 class ModelConfig:
     """Policy network (reference: QDecisionPolicyActor.scala:38-50)."""
 
-    kind: str = "mlp"                  # mlp | lstm | transformer | tcn
+    kind: str = "mlp"          # mlp | lstm | transformer | tcn | latent_moe
     hidden_dim: int = 200              # reference h1Dim (tcn: conv channels)
     num_actions: int = 3               # Buy / Sell / Hold
     # transformer-only:
@@ -161,6 +161,41 @@ class ModelConfig:
     # with it, and with pipeline_blocks (each stage then stores only its
     # schedule-tick boundary states).
     remat_blocks: bool = False
+    # latent_moe-only (models/latent_moe_episode.py; SERVE-ONLY, episode
+    # mode): a latent-attention, routed-expert trunk on several residual
+    # streams. Width = hidden_dim, depth = num_layers (the first
+    # ``dense_layers`` with a dense SwiGLU of ``dense_ffn_dim``, the rest
+    # with ``moe_experts`` sigmoid-routed experts of ``moe_ffn_dim``,
+    # ``moe_top_k`` a token, plus ``moe_shared_experts`` shared ones),
+    # ``num_heads`` heads. ``moe_held_experts`` (0 = all) from
+    # ``moe_held_first`` on are the experts THIS chip holds of an
+    # expert-parallel deployment: the router keeps all its outputs, and
+    # picks on experts held elsewhere add nothing here.
+    q_lora_rank: int = 768
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    dense_layers: int = 1
+    dense_ffn_dim: int = 9216
+    moe_ffn_dim: int = 1024
+    moe_held_experts: int = 0
+    moe_held_first: int = 0
+    moe_shared_experts: int = 1
+    moe_routed_scale: float = 2.0
+    # Hyper-connections: residual streams, Sinkhorn rounds (rows, then
+    # columns), the eps added to each sum, the clamp on H_res's logits.
+    hc_streams: int = 4
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    rms_norm_eps: float = 1e-6
+    # RoPE of the decoupled key, YaRN-blended frequencies.
+    rope_theta: float = 10000.0
+    rope_yarn_factor: float = 64.0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_original: int = 4096
 
 
 @dataclass

@@ -78,6 +78,16 @@ def build_model(cfg: ModelConfig, obs_dim: int, *, head: str = "ac",
     actions = cfg.num_actions if num_actions is None else num_actions
     if cfg.seq_mode not in ("window", "episode"):
         raise ConfigError(f"unknown model.seq_mode {cfg.seq_mode!r}")
+    if cfg.kind == "latent_moe":
+        # Imported here alone: the other kinds' set-up pays nothing for it.
+        if cfg.seq_mode != "episode" or head != "ac" or num_assets > 1:
+            raise ConfigError(
+                "model.kind='latent_moe' is a single-asset episode-mode "
+                "actor-critic trunk: set model.seq_mode='episode' and an "
+                "actor-critic learner.algo (ppo)")
+        from sharetrade_tpu.models.latent_moe_episode import (
+            latent_moe_episode_policy)
+        return latent_moe_episode_policy(obs_dim, actions, cfg, dtype=dtype)
     if cfg.seq_mode == "episode" and cfg.kind != "transformer":
         raise ConfigError(
             f"model.seq_mode='episode' is a transformer mode; "

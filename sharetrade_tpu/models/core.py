@@ -34,6 +34,11 @@ class ModelOut(NamedTuple):
     # overflowing tokens. 0.0 for models with no such term; losses weight it
     # by LearnerConfig.aux_loss_coef.
     aux: jax.Array | float = 0.0
+    # Optional small integer array a SERVING step hands the engine's
+    # counters, a row a request (the routed-expert trunk: each row's picks).
+    # It rides on the tick's own readback; ``Model.serve_stats`` reduces a
+    # tick's real rows on the host. None for every other model and path.
+    stats: jax.Array | None = None
 
 
 @dataclass(frozen=True)
@@ -145,6 +150,15 @@ class Model:
                             tuple[ModelOut, Any]] | None = None
     apply_serve_batch: Callable[[Any, jax.Array, Any],
                                 tuple[ModelOut, Any]] | None = None
+    # Optional host-side reduction of a warm tick's ``ModelOut.stats``:
+    # serve_stats(stats of the tick's REAL rows, a numpy array) ->
+    # ({counter: increment}, {histogram: sample}). The engine adds them to
+    # its registry on the consumer thread and knows none of their names.
+    serve_stats: Callable[[Any], tuple[dict, dict]] | None = None
+    # False for a SERVE-ONLY trunk, one with no replay or rollout pass: the
+    # training loop refuses it by this when it builds its agent
+    # (runtime/orchestrator.py), whatever the model is called.
+    trainable: bool = True
     # Optional precision hook: cast_carry(carry, compute_dtype) -> carry,
     # casting exactly the carry leaves the model's forward produces in the
     # compute dtype (K/V caches, recurrent cells). The precision policy

@@ -4,7 +4,11 @@ One dispatch helper shared by BOTH transformer families (window mode,
 models/transformer.py; episode mode, models/transformer_episode.py) so the
 MoE routing variants — dense-mask top-1, capacity top-k, their ep-sharded
 psum forms, and the token-sharded all_to_all dispatch (parallel/moe.py) —
-cannot drift between them. The reference has a single dense 2-layer MLP and
+cannot drift between them. Of these, dense-mask top-1 never drops a token
+(every expert runs every token); the capacity top-k forms drop the picks
+that overflow an expert's buffer. (The serve-only latent_moe trunk has an
+expert layer of its own, models/latent_moe_episode.py: sigmoid-routed top-k
+with a shared expert, no capacity and no drops.) The reference has a single dense 2-layer MLP and
 no MoE at all (SURVEY.md §2.2 lists EP as absent); this is the forward-
 looking expert-parallel capability.
 """

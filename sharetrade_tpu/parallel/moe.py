@@ -16,6 +16,17 @@ schemes, both shape-static:
    and moves only dispatched buffers across the ICI — the pattern that
    scales both E and N.
 
+Which schemes drop tokens: scheme 1 never does (every expert sees every
+token); scheme 2 DROPS whatever overflows an expert's capacity buffer, in
+all three of its variants. A third scheme lives outside this module and
+drops nothing either: the serve-only latent_moe trunk
+(``models/latent_moe_episode.py``) routes by sigmoid scores + a selection
+bias, top-k renormalised and scaled, with a shared expert and NO capacity
+(a warm tick runs every held expert over every row under the routing
+weights; the prefill sorts its tokens into per-expert blocks sized for the
+worst case), and is told which experts this chip holds of an
+expert-parallel deployment. It has no exchange across chips yet.
+
 Every path returns an auxiliary load-balancing loss (mean-importance ·
 mean-load, the standard switch-style regularizer) alongside the output;
 models surface it via ``ModelOut.aux`` and learners weight it by

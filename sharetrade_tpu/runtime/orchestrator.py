@@ -385,6 +385,14 @@ class Orchestrator:
                 initial_budget=self.cfg.env.initial_budget,
                 initial_shares=self.cfg.env.initial_shares)
         self.agent = build_agent(self.cfg, self.env, mesh=self.mesh)
+        if not self.agent.model.trainable:
+            # No replay, rollout trunk or sharding rule exists for such a
+            # trunk: it would fail deep inside the first chunk otherwise.
+            raise ConfigError(
+                f"model.kind={self.cfg.model.kind!r} "
+                f"({self.agent.model.name}) is serve-only: training it is "
+                "not implemented (the model has no replay pass); run it "
+                "through `cli serve`")
         if self.agent.replay_carry_bytes is not None:
             self.metrics.record("train_replay_carry_bytes_per_minibatch",
                                 self.agent.replay_carry_bytes)

@@ -415,7 +415,9 @@ class _DoneBatch(NamedTuple):
     """One dispatched tick handed dispatcher→consumer: per-program request
     groups with their (still device-resident) outputs."""
 
-    groups: list[tuple[list[_Request], Any, Any, Any]]  # (reqs, act, log, val)
+    #: (reqs, act, log, val, stats): ``stats`` the model step's
+    #: ``ModelOut.stats`` of a warm tick, None where the model has none
+    groups: list[tuple[list[_Request], Any, Any, Any, Any]]
     step: int
     n: int                 # real rows in the tick
     cold: int              # rows served through the prefill
@@ -677,6 +679,7 @@ class ServeEngine:
         # compiled programs are held to it (tests/test_chip_compile.py).
         self._registry.record("serve_tick_gather_bytes",
                               cfg.max_batch * self._carry_nbytes)
+        self._registry.record("serve_arena_row_bytes", self._carry_nbytes)
         self._build_arena_and_programs()
 
         # Live tunable knobs (tuned-knob-ok: seeded from config — the
@@ -935,13 +938,19 @@ class ServeEngine:
         #: refresh, so the two never double-scan one window.
         self._spill_scan_t = 0.0
         n_arena = cfg.slots + cfg.max_batch
-        self._pool = jax.tree.map(
-            lambda x: jnp.repeat(jnp.asarray(x)[None], n_arena, axis=0),
-            self._carry0)
+
+        def rows_of(x, n):
+            # One broadcast, one buffer: an eager ``jnp.repeat`` broadcasts
+            # and then reshapes, two copies of a leaf alive at once (2.3 GB
+            # more at set-up's peak for a 2.3 GB leaf: PERF.md, PR 33).
+            x = jnp.asarray(x)
+            return jnp.broadcast_to(x[None], (n,) + x.shape)
+
+        self._pool = jax.tree.map(lambda x: rows_of(x, n_arena),
+                                  self._carry0)
         # Per-row init carries for the generic path's in-program cold reset.
         self._carry0_rows = jax.tree.map(
-            lambda x: jnp.repeat(jnp.asarray(x)[None], cfg.max_batch,
-                                 axis=0), self._carry0)
+            lambda x: rows_of(x, cfg.max_batch), self._carry0)
         donate = (1,)
         if self._episode:
             self._warm_fn = jax.jit(self._warm_program, donate_argnums=donate)
@@ -962,7 +971,11 @@ class ServeEngine:
 
     def _warm_program(self, params, pool, obs, idx):
         """One incremental step for a warm batch: gather slot carries,
-        per-row-clock serve step, scatter back. THE steady-state program."""
+        per-row-clock serve step, scatter back. THE steady-state program.
+        A model step that hands back ``ModelOut.stats`` (small, a row a
+        request) adds them as a fifth result, read back with the tick's
+        answers; every other model's program returns the four it always
+        did."""
         with jax.named_scope("gather"):
             rows = _gather_rows(pool, idx)
         with jax.named_scope("model"):
@@ -971,7 +984,8 @@ class ServeEngine:
             new_pool = jax.tree.map(lambda p, r: p.at[idx].set(r), pool,
                                     new_rows)
         actions = jnp.argmax(out.logits, axis=-1).astype(jnp.int32)
-        return actions, out.logits, out.value, new_pool
+        stats = () if out.stats is None else (out.stats,)
+        return (actions, out.logits, out.value, new_pool, *stats)
 
     def _cold_program(self, params, pool, obs, idx):
         """Batched re-prefill: cold sessions (fresh or evicted) compute
@@ -1288,9 +1302,8 @@ class ServeEngine:
             _, _, _, pool = self._cold_fn(self._live.params, self._pool,
                                           obs, idx)
             self._pool = pool
-            _, _, _, pool = self._warm_fn(self._live.params, self._pool,
-                                          obs, idx)
-            self._pool = pool
+            self._pool = self._warm_fn(self._live.params, self._pool,
+                                       obs, idx)[3]
         else:
             cold = np.ones((cfg.max_batch,), bool)
             _, _, _, pool = self._step_fn(self._live.params, self._pool,
@@ -1902,20 +1915,21 @@ class ServeEngine:
                 req.trace.batch = bid
                 req.trace.cold = cold
 
-        groups: list[tuple[list[_Request], Any, Any, Any]] = []
+        groups: list[tuple[list[_Request], Any, Any, Any, Any]] = []
         if self._episode:
             if cold_reqs:
                 obs, idx = self._pad(cold_reqs, cold_idx)
                 _stamp(cold_reqs, True)
                 act, logit, val, self._pool = self._cold_fn(
                     live.params, self._pool, obs, idx)
-                groups.append((cold_reqs, act, logit, val))
+                groups.append((cold_reqs, act, logit, val, None))
             if warm_reqs:
                 obs, idx = self._pad(warm_reqs, warm_idx)
                 _stamp(warm_reqs, False)
-                act, logit, val, self._pool = self._warm_fn(
+                act, logit, val, self._pool, *stats = self._warm_fn(
                     live.params, self._pool, obs, idx)
-                groups.append((warm_reqs, act, logit, val))
+                groups.append((warm_reqs, act, logit, val,
+                               stats[0] if stats else None))
         else:
             reqs = cold_reqs + warm_reqs
             cold_mask = np.zeros((self.cfg.max_batch,), bool)
@@ -1926,7 +1940,7 @@ class ServeEngine:
                 req.trace.cold = True
             act, logit, val, self._pool = self._step_fn(
                 live.params, self._pool, obs, idx, cold_mask)
-            groups.append((reqs, act, logit, val))
+            groups.append((reqs, act, logit, val, None))
         return _DoneBatch(groups=groups, step=live.step, n=len(batch),
                           cold=len(cold_reqs), evicted=evicted,
                           epoch=self._fault_epoch,
@@ -2271,13 +2285,13 @@ class ServeEngine:
         trace_lines: list[str] | None = (
             [] if self._req_tracer is not None else None)
         try:
-            for reqs, act_dev, logit_dev, val_dev in done.groups:
+            for reqs, act_dev, logit_dev, val_dev, stats_dev in done.groups:
                 t_rb = time.perf_counter()
                 with self._span("serve/readback", tick=done.tick):
                     # serve-host-ok: consumer-side readback — the
                     # dispatcher never blocks on these buffers.
-                    actions, logits, values = jax.device_get(
-                        (act_dev, logit_dev, val_dev))
+                    actions, logits, values, stats = jax.device_get(
+                        (act_dev, logit_dev, val_dev, stats_dev))
                 now = time.perf_counter()
                 readback_s += now - t_rb
                 # The consumer serializes a batch's completions, so the
@@ -2358,6 +2372,16 @@ class ServeEngine:
                                             done.step)
                     self._trace_request(req, "completed", tr.t_done,
                                         lines=trace_lines)
+                if stats is not None:
+                    # The model's own counters over the tick's REAL rows
+                    # (padding rows routed too, and are left out), once the
+                    # tick's answers have gone out.
+                    counts, samples = self.model.serve_stats(
+                        stats[:len(reqs)])
+                    for name, amount in counts.items():
+                        self._registry.inc(name, amount)
+                    for name, value in samples.items():
+                        self._stat_hist(name).observe(value)
         finally:
             if trace_lines:
                 self._req_tracer.emit_lines(trace_lines)
@@ -2389,6 +2413,15 @@ class ServeEngine:
         self._publish_stats()
         self._h_complete_host.observe(
             (time.perf_counter() - t_begin - readback_s) * 1e3)
+
+    def _stat_hist(self, name: str) -> Histogram:
+        """The registry's histogram of one of the model's ``serve_stats``
+        samples, attached on first use (consumer thread only)."""
+        hist = self._hists.get(name)
+        if hist is None:
+            hist = self._hists[name] = self._registry.attach_histogram(
+                name, Histogram())
+        return hist
 
     def _note_exemplar(self, req: _Request, latency_ms: float,
                        stages: dict, step: int) -> None:

@@ -388,3 +388,56 @@ def test_serve_gather_holds_no_arena_sized_temporary(one_chip, tpu_backend,
         rf"\[{n_arena},{layers},{heads},(\d+),{head_dim}\]",
         compiled.as_text())}
     assert widths <= {window}, f"whole-arena slices of widths {widths}"
+
+
+def test_published_latent_moe_warm_tick_moves_no_bank_and_no_arena(
+        one_chip, tpu_backend):
+    """The warm tick of ``xing4_29b_ep2`` (chipbench/configs) at its
+    published widths, 1,024 slots and 64 rows: it fits the chip, and its
+    temporaries are the gathered rows and the rows' activations alone. The
+    first layouts tried did not pass this: an arena row of 201 ticks had the
+    compiler relay out the whole arena around the gather and the scatter
+    (2.3 GB of temporaries for 1.2 GB of bfloat16 rows), and an expert bank stored by expert, or
+    a fused [W_gate | W_up], was copied whole every tick (4 x 470 MB)
+    (described-chip compiles, PR 33)."""
+    import re
+    import types
+
+    from chipbench.harness import common
+    from sharetrade_tpu.models import build_model
+    from sharetrade_tpu.serve.engine import ServeEngine
+    manifest = common.Manifest()
+    cell = manifest.cell("serve_xing4_steady")
+    cfg = common.build_config(manifest.config(cell["config"]),
+                              manifest.traffic(cell["traffic"]), seed=1)
+    model = build_model(cfg.model, cfg.env.window + 2, head="ac")
+
+    params = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, jnp.bfloat16, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    batch, slots = cfg.serve.max_batch, cfg.serve.slots
+    pool = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        (slots + batch,) + x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(model.init_carry))     # float32 rings: cast_carry
+    obs = jax.ShapeDtypeStruct((batch, cfg.env.window + 2), jnp.float32,
+                               sharding=one_chip)
+    idx = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    engine = types.SimpleNamespace(model=model)
+    compiled = jax.jit(
+        types.MethodType(ServeEngine._warm_program, engine),
+        donate_argnums=(1,)).lower(params, pool, obs, idx).compile()
+    mem = compiled.memory_analysis()
+    weights = sum(int(np.prod(x.shape)) * 2 for x in jax.tree.leaves(params))
+    arena = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                for x in jax.tree.leaves(pool))
+    assert 3.39e9 < weights < 3.41e9 and 2.8e9 < arena < 3.0e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    assert mem.alias_size_in_bytes >= arena          # updated in place
+    assert mem.temp_size_in_bytes < arena // 4       # 0.5 GB, not 2.4
+    text = compiled.as_text()
+    held, ffn, width = (cfg.model.moe_held_experts, cfg.model.moe_ffn_dim,
+                        cfg.model.hidden_dim)
+    bank = rf"bf16\[({width},{held * ffn}|{held * ffn},{width})\]"
+    assert not re.search(rf"= {bank}\S* copy\(", text)
+    assert not re.search(rf"= f32\[{slots + batch},\d+,\d+,\d+\]\S* copy\(",
+                         text)
