@@ -207,11 +207,21 @@ def rows_finite(tree: Any, batch: int) -> jax.Array:
     ``Model.replay_carry``, read by its apply_unroll_shared) so the two can
     never silently diverge. Leaves whose leading dim is not
     ``batch`` (unbatched scalars/tables) are ignored; integer leaves pass
-    trivially (isfinite is all-True on ints)."""
+    trivially (their zeros sum to zero).
+
+    Two stages, the test between them: ``x * 0`` is NaN exactly where
+    ``x`` is not finite, and summing it over the second-to-last axis alone
+    (the K/V ring's window) adds whole tiles and keeps every other axis.
+    The one-stage ``all(isfinite(x))`` over a whole row took the chip 6 us
+    a row however short the row, a quarter of a d=256 chunk; this runs at
+    the HBM rate (PERF.md §6, PR 35)."""
     ok = jnp.ones((batch,), bool)
     for leaf in jax.tree.leaves(tree):
         if getattr(leaf, "ndim", 0) >= 1 and leaf.shape[0] == batch:
-            ok &= jnp.all(jnp.isfinite(leaf.reshape(batch, -1)), axis=-1)
+            nans = leaf * 0
+            if nans.ndim > 2:
+                nans = jnp.sum(nans, axis=-2)
+            ok &= jnp.all(jnp.isfinite(nans), axis=tuple(range(1, nans.ndim)))
     return ok
 
 

@@ -206,6 +206,18 @@ def test_agent_step_compiles_for_one_v5e(wide_step):
             < 16 * 1024 ** 3)       # one v5e chip's HBM
 
 
+def _elements(dims: str) -> int:
+    """Elements of a shape as the compiled text prints it ("64,4,8")."""
+    import math
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+def _shape_of(text: str) -> dict:
+    """Instruction name -> its array result's dims, over a compiled text."""
+    import re
+    return dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text))
+
+
 def test_agent_step_moves_no_kv_cache_per_minibatch(wide_step):
     """The shared-trunk replay reads ``hist``, ``t`` and row health of the
     unroll-start carry, so the update phase holds no K/V cache: nothing in
@@ -213,7 +225,16 @@ def test_agent_step_moves_no_kv_cache_per_minibatch(wide_step):
     ``[mb, L, H, W, Dh]`` 16 times a chunk and scanned it for NaNs each
     time: over half of the d=1024 chunk on the chip, PERF.md PR 25), and
     the caches are checked for finiteness at most once, by the rollout's
-    election, whose pass the replay's health vector shares."""
+    election, whose pass the replay's health vector shares.
+
+    The health pass is found by the ``rows_finite`` scope in the
+    instructions' ``op_name``: the fusions under it that read a whole
+    ``[B, L, H, W, Dh]`` carry leaf. Each leaves at most one window slot's
+    worth of it, and none reduces a leaf over ``dimensions={1,2,3,4}``
+    straight to a ``[B]`` result: that form cost the chip 6 us an agent row
+    however short the row (PERF.md PR 35). This reads the program's
+    structure, not its speed: only a chip run says what the two-stage form
+    takes."""
     import re
     agent, ts, compiled = wide_step
     text = compiled.as_text()
@@ -224,12 +245,26 @@ def test_agent_step_moves_no_kv_cache_per_minibatch(wide_step):
     per_mb = re.findall(
         rf"\[{mb},{layers},{heads},\d+,{head_dim}\]", text)
     assert not per_mb, f"{len(per_mb)} minibatch-by-K/V shaped values"
-    checked = sum(
-        batch * layers * heads * int(w) * head_dim
-        for w in re.findall(
-            rf"= pred\[{batch},{layers},{heads},(\d+),{head_dim}\]\S* "
-            r"is-finite\(", text))
-    assert 0 < checked <= 2 * batch * layers * heads * width * head_dim
+    leaf = f"{batch},{layers},{heads},{width},{head_dim}"
+    shape_of = _shape_of(text)
+    passes = [
+        (name, dims, operands)
+        for name, dims, operands in re.findall(
+            r"(%[\w.\-]+) = \w+\[([\d,]*)\]\S* fusion\(([^)]*)\)"
+            r".*op_name=\"[^\"]*/rows_finite/", text)
+        if leaf in [shape_of[o] for o in re.findall(r"%[\w.\-]+", operands)]]
+    checked = sum(_elements(shape_of[o]) for _, _, operands in passes
+                  for o in re.findall(r"%[\w.\-]+", operands))
+    assert 0 < checked <= 2 * _elements(leaf), passes
+    assert all(_elements(dims) <= _elements(leaf) // width
+               for _, dims, _ in passes), passes
+    per_row = [
+        f"{name} = [{dims}] reduce({operand})"
+        for name, dims, operand in re.findall(
+            r"(%[\w.\-]+) = \w+\[([\d,]*)\]\S* reduce\((%[\w.\-]+),"
+            r"[^)]*\), dimensions=\{1,2,3,4\}", text)
+        if shape_of[operand] == leaf and dims == str(batch)]
+    assert not per_row, per_row
 
 
 def test_agent_step_gathers_no_action_log_prob(wide_step):
@@ -241,25 +276,20 @@ def test_agent_step_gathers_no_action_log_prob(wide_step):
     of 12.5 ns an element on the chip, 15% of the d=1024 chunk and half of
     the d=256 one (PERF.md PR 31). The minibatch's own column gathers are
     ``[unroll, mb]`` too, a whole column a slice: they stay."""
-    import math
     import re
     agent, _, compiled = wide_step
     text = compiled.as_text()
     per_step = agent.steps_per_chunk * (agent.num_agents // 4)   # unroll * mb
     per_action = per_step * agent.model.num_actions
-
-    def elements(dims):
-        return math.prod(int(d) for d in dims.split(",") if d)
-
-    shape_of = dict(re.findall(r"(%[\w.\-]+) = \w+\[([\d,]*)\]", text))
+    shape_of = _shape_of(text)
     moves = re.findall(
         r"(%[\w.\-]+) = \w+\[([\d,]*)\]\S* (gather|scatter)"
         r"\(([^)]*)\)(.*)", text)
     assert moves            # the minibatch gathers: the pattern still reads
     found = []
     for name, dims, op, operands, rest in moves:
-        sizes = [elements(dims)] + [
-            elements(shape_of[o]) for o in re.findall(r"%[\w.\-]+", operands)]
+        sizes = [_elements(dims)] + [
+            _elements(shape_of[o]) for o in re.findall(r"%[\w.\-]+", operands)]
         by_element = re.search(r"slice_sizes=\{1(,1)*\}", rest) is not None
         if per_action in sizes or (by_element and sizes[0] == per_step):
             found.append(f"{name} = [{dims}] {op}({operands})")

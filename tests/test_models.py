@@ -16,6 +16,57 @@ def _obs(key):
     return jax.random.uniform(key, (OBS_DIM,), minval=0.0, maxval=100.0)
 
 
+def _rows_finite_cases():
+    """dtype x shape x what is planted, for
+    ``test_rows_finite_is_the_plain_predicate``: ``[B]``, ``[B, 7]`` and a
+    K/V-carry-shaped ``[B, 2, 2, 201, 128]`` at B = 5."""
+    import ml_dtypes
+    cases = []
+    for dtype in (ml_dtypes.bfloat16, np.float32, np.int32):
+        for shape in ((5,), (5, 7), (5, 2, 2, 201, 128)):
+            planted = ["none", "max"]
+            if dtype is not np.int32:
+                spots = ["first", "last", "two_rows"]
+                if len(shape) == 5:
+                    spots.append("slot200")
+                planted += [f"{v}_{s}" for v in ("nan", "posinf", "neginf")
+                            for s in spots]
+            name = np.dtype(dtype).name
+            dims = "x".join(map(str, shape))
+            cases += [pytest.param(dtype, shape, p, id=f"{name}-{dims}-{p}")
+                      for p in planted]
+    return cases
+
+
+_ROWS_FINITE_CASES = _rows_finite_cases()
+
+
+def _rows_finite_input(dtype, shape, planted):
+    rng = np.random.default_rng(0)
+    if planted == "max":
+        top = (jnp.iinfo if dtype is np.int32 else jnp.finfo)(dtype).max
+        x = np.full(shape, top, dtype)
+        x.reshape(-1)[1::2] *= -1          # both signs: no sum may overflow
+        return x
+    x = rng.normal(size=shape).astype(np.float32)
+    x = (x * 100).astype(dtype) if dtype is np.int32 else x.astype(dtype)
+    if planted == "none":
+        return x
+    value, spot = planted.split("_", 1)
+    value = {"nan": np.nan, "posinf": np.inf, "neginf": -np.inf}[value]
+    rows = x.reshape(shape[0], -1)          # a view: writes land in x
+    if spot == "first":
+        rows[2, 0] = value
+    elif spot == "last":
+        rows[4, -1] = value
+    elif spot == "two_rows":
+        rows[0, rows.shape[1] // 2] = value
+        rows[3, -1] = value
+    else:                                   # the window's last slot
+        x[1, 1, 0, 200, 5] = value
+    return x
+
+
 class TestQMLPParity:
     """Architecture parity with QDecisionPolicyActor.scala:38-50."""
 
@@ -432,6 +483,35 @@ class TestEpisodeMode:
             err_msg="replay elected the NaN-carry representative")
         np.testing.assert_allclose(
             np.asarray(v_sh[:, 1:]), np.asarray(v_tw[:, 1:]), atol=1e-6)
+
+    @pytest.mark.parametrize("how", ["eager", "jit"])
+    @pytest.mark.parametrize("dtype,shape,planted", _ROWS_FINITE_CASES)
+    def test_rows_finite_is_the_plain_predicate(self, dtype, shape, planted,
+                                                how):
+        """``rows_finite`` is ``np.isfinite(x.reshape(B, -1)).all(-1)``,
+        bit for bit, whatever its two stages are (PERF.md PR 35): NaN and
+        either infinity anywhere in a row give False, the largest finite
+        value everywhere stays True (no overflow into Inf on the way),
+        integer leaves pass, and an unbatched table beside the batched
+        leaf is ignored even when it holds a NaN."""
+        from sharetrade_tpu.models.core import rows_finite
+
+        x = _rows_finite_input(dtype, shape, planted)
+        batch = shape[0]
+        want = np.isfinite(
+            x.astype(np.float32).reshape(batch, -1)).all(-1)
+        bad_rows = (0 if planted in ("none", "max")
+                    else 2 if planted.endswith("two_rows") else 1)
+        assert want.sum() == batch - bad_rows
+        fn = rows_finite if how == "eager" else jax.jit(
+            rows_finite, static_argnums=1)
+        leaf = jnp.asarray(x)
+        np.testing.assert_array_equal(np.asarray(fn(leaf, batch)), want)
+        tree = {"x": leaf, "t": jnp.arange(batch, dtype=jnp.int32),
+                "table": jnp.full((batch + 1, 4), jnp.nan, jnp.float32)}
+        got = fn(tree, batch)
+        assert got.dtype == jnp.bool_ and got.shape == (batch,)
+        np.testing.assert_array_equal(np.asarray(got), want)
 
     def test_greedy_eval_trunk_matches_incremental(self):
         """Orchestrator.evaluate()'s precomputed-trunk greedy replay must
