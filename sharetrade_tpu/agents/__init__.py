@@ -86,13 +86,6 @@ def build_agent(cfg: FrameworkConfig, env: TradingEnv | trading.EnvParams,
         # rollout→update seam) instead of leaving GSPMD an involuntary
         # full rematerialization per gather (agents/ppo.py).
         kwargs["mesh"] = mesh
-    if mesh is not None:
-        # The fused-update kernel runs per device under a shard_map with
-        # each leaf's own spec, so it needs the mesh and the same param
-        # rules the partitioned step shards the TrainState by.
-        from sharetrade_tpu.parallel.sharding import mesh_param_rules
-        kwargs["update_sharding"] = (
-            mesh, mesh_param_rules(mesh, cfg.parallel.model_axis))
     return _FACTORIES[algo](
         model, env, cfg.learner,
         num_agents=cfg.parallel.num_workers,

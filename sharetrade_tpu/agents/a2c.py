@@ -28,11 +28,10 @@ from sharetrade_tpu.precision import FP32
 def make_a2c_agent(model: Model, env: TradingEnv,
                    cfg: LearnerConfig, *, num_agents: int = 10,
                    steps_per_chunk: int | None = None,
-                   precision=None, update_sharding=None) -> Agent:
+                   precision=None) -> Agent:
     optimizer = build_optimizer(cfg)
     precision = precision or FP32
-    apply_update = make_update_fn(optimizer, cfg, precision,
-                                  sharding=update_sharding)
+    apply_update = make_update_fn(optimizer, cfg, precision)
     unroll = steps_per_chunk or cfg.unroll_len
 
     def init(key: jax.Array) -> TrainState:

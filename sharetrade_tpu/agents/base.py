@@ -123,8 +123,7 @@ def build_optimizer(cfg: LearnerConfig) -> optax.GradientTransformation:
 
 
 def make_update_fn(optimizer: optax.GradientTransformation,
-                   cfg: LearnerConfig, precision,
-                   *, use_pallas: bool | None = None, sharding=None):
+                   cfg: LearnerConfig, precision):
     """THE optimizer-update seam every learner applies its gradients
     through: ``update(grads, opt_state, params) -> (params, opt_state)``.
 
@@ -140,27 +139,15 @@ def make_update_fn(optimizer: optax.GradientTransformation,
       object identity in fp32 mode).
     - **fused** (bf16_mixed default, or ``precision.fused_update='on'``):
       ``ops/fused_update.fused_apply`` — grad-upcast + moment update +
-      param update in one pass per leaf (Pallas on TPU, one fused XLA
-      elementwise chain elsewhere), optax-exact in fp32 and sharing the
-      optax state structure either way.
+      param update as one XLA loop fusion per leaf in its stored layout,
+      optax-exact in fp32 and sharing the optax state structure either
+      way. Elementwise, so on a mesh the compiler partitions it by each
+      leaf's own sharding with no collective.
 
     Unsupported optimizers under 'on'/'auto' fall back to the optax pair
     (fused_supported) rather than failing — the policy is a performance
-    lever, not a capability gate.
-
-    ``sharding = (mesh, param_rules)`` when the update is traced into a
-    program partitioned over a mesh (``build_agent`` passes it): the fused
-    kernel then runs per device under a shard_map with each leaf's own
-    spec, and a non-TPU mesh (the virtual-CPU test client, which cannot
-    lower Mosaic) keeps the XLA chain — the same carve-out as the
-    attention kernels' (models/__init__.py)."""
+    lever, not a capability gate."""
     from sharetrade_tpu.ops.fused_update import fused_apply, fused_supported
-
-    mesh, param_rules = sharding or (None, None)
-    if mesh is not None:
-        from sharetrade_tpu.parallel.mesh import mesh_platform
-        if mesh_platform(mesh) != "tpu":
-            use_pallas = False
 
     if precision is not None and precision.use_fused_update \
             and fused_supported(cfg):
@@ -169,9 +156,7 @@ def make_update_fn(optimizer: optax.GradientTransformation,
 
         def update(grads, opt_state, params):
             return fused_apply(name, lr, grads, opt_state, params,
-                               compute_dtype=compute_dtype,
-                               use_pallas=use_pallas,
-                               mesh=mesh, param_rules=param_rules)
+                               compute_dtype=compute_dtype)
 
         return update
 

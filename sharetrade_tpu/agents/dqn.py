@@ -133,7 +133,7 @@ def make_dqn_agent(model: Model, env: TradingEnv,
                    cfg: LearnerConfig, *, num_agents: int = 10,
                    steps_per_chunk: int = 200,
                    collect_transitions: bool = False,
-                   precision=None, update_sharding=None) -> Agent:
+                   precision=None) -> Agent:
     """``collect_transitions`` makes each chunk additionally return its raw
     transition batch under ``metrics["transitions"]`` so the host can journal
     them (the runtime's ``learner.journal_replay`` switch).
@@ -161,8 +161,7 @@ def make_dqn_agent(model: Model, env: TradingEnv,
     use_per = cfg.replay_priority == "per"
     optimizer = build_optimizer(cfg)
     precision = precision or FP32
-    apply_update = make_update_fn(optimizer, cfg, precision,
-                                  sharding=update_sharding)
+    apply_update = make_update_fn(optimizer, cfg, precision)
     horizon = env.num_steps
     obs_dim = model.obs_dim
 

@@ -45,11 +45,10 @@ def _replicated(seam_mesh):
 def make_ppo_agent(model: Model, env: TradingEnv,
                    cfg: LearnerConfig, *, num_agents: int = 10,
                    steps_per_chunk: int | None = None, mesh=None,
-                   precision=None, update_sharding=None) -> Agent:
+                   precision=None) -> Agent:
     optimizer = build_optimizer(cfg)
     precision = precision or FP32
-    apply_update = make_update_fn(optimizer, cfg, precision,
-                                  sharding=update_sharding)
+    apply_update = make_update_fn(optimizer, cfg, precision)
     # The rollout→update replicate seam applies ONLY on meshes with a
     # shard_map-partitioned axis (mesh.has_shard_map_axis): there, the
     # epoch scans' permuted minibatch gathers over dp-sharded rollout

@@ -41,12 +41,10 @@ from sharetrade_tpu.precision import FP32
 
 def make_qlearn_agent(model: Model, env: TradingEnv,
                       cfg: LearnerConfig, *, num_agents: int = 10,
-                      steps_per_chunk: int = 200, precision=None,
-                      update_sharding=None) -> Agent:
+                      steps_per_chunk: int = 200, precision=None) -> Agent:
     optimizer = build_optimizer(cfg)
     precision = precision or FP32
-    apply_update = make_update_fn(optimizer, cfg, precision,
-                                  sharding=update_sharding)
+    apply_update = make_update_fn(optimizer, cfg, precision)
     horizon = env.num_steps
 
     def init(key: jax.Array) -> TrainState:

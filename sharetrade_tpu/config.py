@@ -317,8 +317,8 @@ class PrecisionConfig:
 
     mode: str = "fp32"                 # "fp32" | "bf16_mixed"
     # Fused optimizer update (ops/fused_update.py): grad-upcast + moment
-    # update + param update in ONE pass per parameter leaf (a Pallas kernel
-    # on TPU, one fused XLA elementwise chain elsewhere) instead of the
+    # update + param update in ONE pass per parameter leaf (one XLA loop
+    # fusion over the leaf as stored, on every backend) instead of the
     # O(params) intermediate buffers optax's update/apply_updates pair
     # materializes. "auto" = on for bf16_mixed, off for fp32 (keeping the
     # default mode's update path literally the pre-policy optax calls);

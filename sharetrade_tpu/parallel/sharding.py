@@ -111,9 +111,7 @@ def mesh_param_rules(mesh: Mesh, model_axis: str = "tp"):
     """THE param rules of a mesh: a tp axis shards parameters via the
     Megatron suffix rules; without rules a tp axis would silently
     replicate params, making the public surface's tensor parallelism a
-    no-op. One definition, so the partitioned step (orchestrator) and the
-    fused-update kernel's shard_map (agents/__init__.py) agree on every
-    leaf's spec."""
+    no-op."""
     return mlp_tp_rules(model_axis) if model_axis in mesh.axis_names else None
 
 

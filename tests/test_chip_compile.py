@@ -118,8 +118,25 @@ def test_attention_fwd_bwd_compiles_for_v5e(one_chip, tpu_backend, shape,
     assert _mosaic_calls(compiled) >= 3
 
 
+def _relayouts(text: str, elements: int) -> list[str]:
+    """``copy`` / ``reshape`` / ``transpose`` instructions of a compiled text
+    whose array result holds ``elements`` elements, in any shape: a leaf
+    laid out again, or viewed as another shape. (An async ``copy-start``
+    that stages a leaf in another memory space keeps its layout and is not
+    one of these.)"""
+    import re
+    return [f"{name} = [{dims}] {op}" for name, dims, op in re.findall(
+        r"(%[\w.\-]+) = \w+\[([\d,]*)\]\S* (copy|reshape|transpose)\(",
+        text) if _elements(dims) == elements]
+
+
 @pytest.mark.parametrize("optimizer", ["adagrad", "adam", "sgd"])
-def test_fused_update_compiles_for_v5e(one_chip, optimizer):
+def test_fused_update_compiles_for_v5e(one_chip, tpu_backend, optimizer):
+    """The update is XLA's own elementwise fusion over the leaf as stored,
+    with the backend answering "tpu" as on the chip: no Mosaic call, and
+    no copy or reshape of the leaf into another view and back (the Pallas
+    kernel's ``(rows, 128)`` view cost the v5e 2.26 ms of relayouts an
+    update of the d=1024 tree: PERF.md §5)."""
     from sharetrade_tpu.agents.base import build_optimizer
     from sharetrade_tpu.config import LearnerConfig
     from sharetrade_tpu.ops.fused_update import fused_apply
@@ -131,18 +148,23 @@ def test_fused_update_compiles_for_v5e(one_chip, optimizer):
 
     def update(g, s, p):
         return fused_apply(optimizer, 0.01, g, s, p,
-                           compute_dtype=jnp.bfloat16, emit_compute=True,
-                           use_pallas=True)
+                           compute_dtype=jnp.bfloat16, emit_compute=True)
 
     compiled = jax.jit(update).lower(
         _on(one_chip, grads), _on(one_chip, state),
         _on(one_chip, params)).compile()
-    assert _mosaic_calls(compiled) == 1
+    text = compiled.as_text()
+    assert _mosaic_calls(compiled) == 0
+    assert not _relayouts(text, 1024 * 4096)
+    assert " fusion(" in text
 
 
-def test_fused_update_compiles_under_tp_specs_for_v5e(chip_compile):
-    """On a dp x tp mesh each leaf's kernel runs under a shard_map with the
-    leaf's own Megatron spec: every device updates the shard it holds."""
+def test_fused_update_compiles_under_tp_specs_for_v5e(chip_compile,
+                                                     tpu_backend):
+    """On a dp x tp mesh the compiler partitions the update by each leaf's
+    own Megatron spec: every device updates the shard it holds, with no
+    collective, and every result keeps its leaf's spec."""
+    from jax.sharding import NamedSharding
     from sharetrade_tpu.agents.base import build_optimizer
     from sharetrade_tpu.config import LearnerConfig
     from sharetrade_tpu.ops.fused_update import fused_apply
@@ -157,17 +179,26 @@ def test_fused_update_compiles_under_tp_specs_for_v5e(chip_compile):
         P(None, "tp"), P("tp", None)}
     state = jax.eval_shape(
         build_optimizer(LearnerConfig(optimizer="adam")).init, params)
+    # The state placed as the TrainState places it: moments like their
+    # parameter, the step count replicated.
+    state_shardings = (state[0]._replace(
+        count=NamedSharding(mesh, P()), mu=shardings, nu=shardings),
+        state[1])
     grads = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, jnp.bfloat16), params)
 
     def update(g, s, p):
-        return fused_apply("adam", 0.01, g, s, p, compute_dtype=jnp.bfloat16,
-                           use_pallas=True, mesh=mesh, param_rules=rules)
+        return fused_apply("adam", 0.01, g, s, p, compute_dtype=jnp.bfloat16)
 
     compiled = jax.jit(update).lower(
-        _on(shardings, grads), state, _on(shardings, params)).compile()
-    assert _mosaic_calls(compiled) == 2
-    assert "all-gather" not in compiled.as_text()   # no leaf is regathered
+        _on(shardings, grads), _on(state_shardings, state),
+        _on(shardings, params)).compile()
+    text = compiled.as_text()
+    assert _mosaic_calls(compiled) == 0
+    assert "all-gather" not in text and "all-reduce" not in text
+    new_params = compiled.output_shardings[0]
+    assert jax.tree.map(lambda s: s.spec, new_params) == jax.tree.map(
+        lambda s: s.spec, shardings)
 
 
 # -- whole programs at the widest supported model ---------------------------
@@ -196,6 +227,31 @@ def wide_step(one_chip):
         compiled = jax.jit(agent.step, donate_argnums=(0,)).lower(
             _on(one_chip, ts)).compile()
     return agent, ts, compiled
+
+
+#: Temporaries of the d=1024 step with the optimiser update as a Pallas
+#: kernel, at the benchmark's 1,024 agents (described-chip compile, PERF.md
+#: §4): the kernel's padded views were part of them.
+KERNEL_PATH_STEP_TEMP_BYTES = 390_668_288
+
+
+def test_agent_step_updates_without_a_kernel_or_relayout(wide_step):
+    """The optimiser update inside the d=1024 step is XLA's own: no
+    ``fused_update`` Mosaic call, no copy, reshape or transpose under the
+    ``update`` scope, and the step's temporaries no larger than the
+    kernel path's."""
+    import re
+    _, _, compiled = wide_step
+    text = compiled.as_text()
+    kernel = r'"kernel"\s*:\s*"{}"'
+    assert re.search(kernel.format("flash_fwd"), text)    # the ids still read
+    assert not re.search(kernel.format("fused_update"), text)
+    moved = re.findall(
+        r"(%[\w.\-]+) = \S+ (copy|reshape|transpose)\(.*op_name=\"[^\"]*/update/",
+        text)
+    assert not moved, moved
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            <= KERNEL_PATH_STEP_TEMP_BYTES)
 
 
 def test_agent_step_compiles_for_one_v5e(wide_step):
@@ -316,10 +372,11 @@ def test_agent_step_compiles_for_v5e_mesh(chip_compile, tpu_backend,
                                           mesh_shape, make_cfg):
     """``cli train --mesh``: a bare ``pallas_call`` inside the partitioned
     program is refused ("Mosaic kernels cannot be automatically
-    partitioned" — wide_dp4 failed so before PR 21), so both kernel call
-    sites run under a shard_map on a multi-device mesh — attention over the
-    batch axis, the fused update with each leaf's own spec — and stay
-    kernels. (wide on dp2 x tp2 also compiles, 18 s: ROADMAP S7.)"""
+    partitioned" — wide_dp4 was once refused so), so the attention
+    kernel runs under a shard_map over the batch axis on a multi-device
+    mesh and stays a kernel; the optimiser update is plain XLA, which the
+    compiler partitions. (wide on dp2 x tp2 also compiles, 18 s: ROADMAP
+    S7.)"""
     from sharetrade_tpu.parallel.sharding import (jit_parallel_step,
                                                   mesh_param_rules)
     mesh = Mesh(np.array(chip_compile.devices).reshape(

@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from sharetrade_tpu.ops import attention, fused_update
+from sharetrade_tpu.ops import attention
 
 METADATA = re.compile(r'kernel_metadata = "([^"]*)"')
 
@@ -56,22 +56,3 @@ def test_attention_kernels_carry_their_ids(tpu_backend, shape, window):
     assert "flash_fwd" not in re.sub(r'kernel_metadata = "[^"]*"', "",
                                      lowered.as_text())
 
-
-def test_fused_update_kernel_carries_its_id(tpu_backend):
-    from sharetrade_tpu.agents.base import build_optimizer
-    from sharetrade_tpu.config import LearnerConfig
-    params = {"w": jnp.zeros((1024, 4096), jnp.float32),
-              "b": jnp.zeros((1024,), jnp.float32)}
-    state = jax.eval_shape(
-        build_optimizer(LearnerConfig(optimizer="adagrad")).init, params)
-    grads = jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct(p.shape, jnp.bfloat16), params)
-
-    def update(g, s, p):
-        return fused_update.fused_apply("adagrad", 0.01, g, s, p,
-                                        compute_dtype=jnp.bfloat16,
-                                        use_pallas=True)
-
-    lowered = jax.jit(update).trace(grads, state, params).lower(
-        lowering_platforms=("tpu",))
-    assert kernel_ids(lowered) == [fused_update.KERNEL_ID] * 2  # per leaf

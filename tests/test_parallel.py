@@ -622,10 +622,11 @@ class TestEpisodeSequenceParallel:
 
 # ---------------------------------------------------------------------------
 # Kernels inside a partitioned program (PR 21): a bare pallas_call cannot be
-# partitioned by the compiler, so on a multi-device mesh both kernel call
-# sites run per device under a shard_map. The TPU side of that is compiled by
-# tests/test_chip_compile.py; here the SAME wraps run the interpreted kernels
-# on the virtual CPU mesh, so their numerics are pinned.
+# partitioned by the compiler, so on a multi-device mesh the attention kernel
+# runs per device under a shard_map. The TPU side of that is compiled by
+# tests/test_chip_compile.py; here the SAME wrap runs the interpreted kernel
+# on the virtual CPU mesh, so its numerics are pinned. The fused optimizer
+# update is plain XLA, partitioned by the compiler like the rest.
 # ---------------------------------------------------------------------------
 
 class TestKernelsUnderShardMap:
@@ -659,13 +660,16 @@ class TestKernelsUnderShardMap:
     @pytest.mark.parametrize("optimizer", ["adagrad", "adam", "sgd"])
     def test_fused_update_on_mesh_is_bitwise_the_single_device_update(
             self, cpu_devices, optimizer):
-        """Each leaf's kernel runs under a shard_map with that leaf's own
-        spec (tp column/row rules here): elementwise, so every device
-        updates exactly its shard — bit for bit the unpartitioned result."""
+        """The fused update is elementwise XLA, so the compiler partitions
+        it by each leaf's own sharding (tp column/row rules here, the
+        state placed like its parameter): every device updates exactly its
+        shard, with no collective — bit for bit the unpartitioned result,
+        and each result keeps its leaf's sharding."""
         from jax.sharding import Mesh, PartitionSpec as P
         from sharetrade_tpu.agents.base import build_optimizer
         from sharetrade_tpu.config import LearnerConfig
         from sharetrade_tpu.ops.fused_update import fused_apply
+        from sharetrade_tpu.parallel.sharding import param_shardings
         mesh = Mesh(np.array(cpu_devices[:4]).reshape(2, 2), ("dp", "tp"))
         rules = {"layer1/w": P(None, "tp"), "layer2/w": P("tp", None)}
         k1, k2 = jax.random.split(jax.random.PRNGKey(1))
@@ -673,21 +677,28 @@ class TestKernelsUnderShardMap:
                              "b": jnp.zeros((64,))},
                   "layer2": {"w": jax.random.normal(k2, (64, 256))}}
         grads = jax.tree.map(lambda x: (x * 0.1).astype(jnp.bfloat16), params)
-        state = build_optimizer(LearnerConfig(optimizer=optimizer)).init(params)
+        shardings = param_shardings(params, mesh, rules)
+        assert {s.spec for s in jax.tree.leaves(shardings)} == {
+            P(None, "tp"), P("tp", None), P()}
+        init = build_optimizer(LearnerConfig(optimizer=optimizer)).init
+        on_mesh = (jax.device_put(grads, shardings),
+                   init(jax.device_put(params, shardings)),  # placed alike
+                   jax.device_put(params, shardings))
+        update = jax.jit(lambda g, s, p: fused_apply(optimizer, 0.01, g, s, p))
 
-        def run(**kw):
-            return jax.jit(lambda g, s, p: fused_apply(
-                optimizer, 0.01, g, s, p, interpret=True, **kw))(
-                    grads, state, params)
-
-        for got, exp in zip(jax.tree.leaves(run(mesh=mesh, param_rules=rules)),
-                            jax.tree.leaves(run())):
-            np.testing.assert_array_equal(got, exp)
+        got = update(*on_mesh)
+        for a, b in zip(jax.tree.leaves(got),
+                        jax.tree.leaves(update(grads, init(params), params))):
+            np.testing.assert_array_equal(a, b)
+        for a, sh in zip(jax.tree.leaves(got[0]), jax.tree.leaves(shardings)):
+            assert a.sharding.is_equivalent_to(sh, a.ndim)
+        text = update.lower(*on_mesh).compile().as_text()
+        assert "all-gather" not in text and "all-reduce" not in text
 
     def test_cpu_mesh_keeps_the_xla_paths(self, cpu_mesh):
         """The virtual-CPU mesh cannot lower Mosaic: build_model turns the
-        attention kernel off there and the update seam follows, so
-        ``cli train --mesh`` on the CPU backend is unchanged."""
+        attention kernel off there, so ``cli train --mesh`` on the CPU
+        backend is unchanged."""
         from sharetrade_tpu.agents import build_agent
         from sharetrade_tpu.config import FrameworkConfig
         from sharetrade_tpu.env import trading

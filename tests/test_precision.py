@@ -258,6 +258,12 @@ def _opt_pair(name):
             "sgd": optax.sgd(0.01)}[name]
 
 
+#: The leaf kinds of the d=1024 training tree (chip_smoke.wide_agent):
+#: a block's 2-D weight, a 1-D bias, the ``(3, d)`` embedding.
+D1024_LEAVES = {"weight": (1024, 3072), "bias": (3072,), "embed": (3, 1024)}
+OPTIMIZERS = ["adagrad", "adam", "sgd"]
+
+
 class TestFusedUpdate:
     params = {
         "a": jax.random.normal(jax.random.PRNGKey(0), (37, 13)),
@@ -266,38 +272,32 @@ class TestFusedUpdate:
     }
     grads = jax.tree.map(lambda x: x * 0.37 + 0.01, params)
 
-    @pytest.mark.parametrize("name", ["adagrad", "adam", "sgd"])
-    def test_fp32_bitwise_vs_optax(self, name):
+    @pytest.mark.parametrize("name,leaf", [
+        *(pytest.param(n, None, id=n) for n in OPTIMIZERS),
+        *(pytest.param(n, kind, id=f"{n}-{kind}")
+          for n in OPTIMIZERS for kind in D1024_LEAVES),
+    ])
+    def test_fp32_bitwise_vs_optax(self, name, leaf):
+        """Bit for bit the optax pair, over the small mixed tree and over
+        each leaf kind of the d=1024 tree at its own shape."""
+        params, grads = self.params, self.grads
+        if leaf is not None:
+            params = {"w": jax.random.normal(jax.random.PRNGKey(2),
+                                             D1024_LEAVES[leaf])}
+            grads = jax.tree.map(lambda x: x * 0.37 + 0.01, params)
         opt = _opt_pair(name)
-        st = opt.init(self.params)
-        p_ref, st_ref = self.params, st
-        p_f, st_f = self.params, st
+        st = opt.init(params)
+        p_ref, st_ref = params, st
+        p_f, st_f = params, st
         for _ in range(3):       # counts/moments exercise multi-step state
-            u, st_ref = opt.update(self.grads, st_ref, p_ref)
+            u, st_ref = opt.update(grads, st_ref, p_ref)
             p_ref = optax.apply_updates(p_ref, u)
-            p_f, st_f = fused_apply(name, 0.01, self.grads, st_f, p_f)
+            p_f, st_f = fused_apply(name, 0.01, grads, st_f, p_f)
         for ref, got in zip(jax.tree.leaves((p_ref, st_ref)),
                             jax.tree.leaves((p_f, st_f))):
             np.testing.assert_array_equal(np.asarray(ref), np.asarray(got))
 
-    @pytest.mark.parametrize("name", ["adagrad", "adam", "sgd"])
-    def test_pallas_kernel_interpret_parity(self, name):
-        """The Pallas kernel path (interpret mode — the CPU stand-in for
-        the TPU compile) agrees with optax to ~1 ulp: interpret mode
-        evaluates ops singly, so XLA's FMA contraction of the fused
-        chain is the only allowed divergence."""
-        opt = _opt_pair(name)
-        st = opt.init(self.params)
-        u, st_ref = opt.update(self.grads, st, self.params)
-        p_ref = optax.apply_updates(self.params, u)
-        p_i, st_i = fused_apply(name, 0.01, self.grads, st, self.params,
-                                interpret=True)
-        for ref, got in zip(jax.tree.leaves((p_ref, st_ref)),
-                            jax.tree.leaves((p_i, st_i))):
-            np.testing.assert_allclose(np.asarray(ref), np.asarray(got),
-                                       rtol=3e-7, atol=1e-7)
-
-    @pytest.mark.parametrize("name", ["adagrad", "adam", "sgd"])
+    @pytest.mark.parametrize("name", OPTIMIZERS)
     def test_bf16_grads_within_tolerance(self, name):
         """bf16 gradients: the fused update (upcast inside the pass)
         equals the optax pair fed explicitly-upcast grads — the only
@@ -338,7 +338,7 @@ class TestFusedUpdate:
 
         @jax.jit
         def step(p, s, g):
-            return fused_apply("adam", 0.01, g, s, p, use_pallas=False)
+            return fused_apply("adam", 0.01, g, s, p)
 
         p1, s1 = step(self.params, st, self.grads)
         u, s_ref = opt.update(self.grads, st, self.params)
