@@ -22,9 +22,16 @@ hot-loop cost when disabled):
   ``serve_tick_host_ms`` / ``serve_done_wait_ms`` /
   ``serve_complete_host_ms`` / ``serve_inflight_ticks`` (always on), with
   ``pipeline_stalls_total``/``pipeline_queue_depth`` in the metrics
-  export. ``trace.jsonl`` opens with a ``clock`` event (epoch ns beside
-  ``perf_counter``): event start = ``epoch_ns + ts * 1000``, which lays
-  the file over a profiler trace (README "Observability");
+  export. ``host/gc`` (identifier ``gen``; ``collected``/``uncollectable``
+  at its end) wraps each collection of the interpreter's garbage collector
+  on whichever thread allocated, from ONE ``gc.callbacks`` entry a process
+  (``attach_gc_pauses``; attached by every serve engine and by an
+  obs-enabled orchestrator), with the process-wide histograms
+  ``host_gc_pause_ms`` (every generation) and ``host_gc_full_pause_ms``
+  (generation 2), both lock-free. ``trace.jsonl`` opens with a ``clock``
+  event (epoch ns beside ``perf_counter``): event start = ``epoch_ns + ts
+  * 1000``, which lays the file over a profiler trace (README
+  "Observability");
 - :mod:`exporter` — background drain of :class:`MetricsRegistry` →
   ``metrics.jsonl`` + Prometheus textfile ``metrics.prom``;
 - :mod:`flight` — bounded ring of recent chunk metrics / lifecycle /

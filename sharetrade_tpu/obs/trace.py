@@ -32,30 +32,50 @@ one of them gives the offset between the two.
 ``SpanTracer(None)`` is the disabled instance: nothing is ever opened or
 written (the obs.enabled=false contract — zero files); its ``span()``
 still hands back the bare annotation.
+
+The interpreter's garbage collector is a host phase too: a collection holds
+the GIL, so it stops every thread wherever it stands. :func:`attach_gc_pauses`
+installs ONE ``gc.callbacks`` entry per process (the first time a registry
+asks; never removed) that opens ``host/gc`` (identifier ``gen``; ``collected``
+and ``uncollectable`` added at its end) through the same entry, and observes
+each pause in ms into two process-wide histograms: ``host_gc_pause_ms``
+(every generation) and ``host_gc_full_pause_ms`` (generation 2). The
+callback runs on whichever thread allocated, possibly inside a histogram's
+``snapshot()`` or a tracer's flush, so it takes no lock: its histograms have
+the callback as their one writer and are read under a sequence number, and
+its ``trace.jsonl`` event is queued for the tracer's next flush.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
 import threading
 import time
+from bisect import bisect_left
 from collections import deque
 from typing import Any
+
+from sharetrade_tpu.obs.hist import Histogram
 
 _TraceAnnotation = None
 
 
-def span(name: str, **ids: Any):
-    """:func:`host_span` with no tracer: the profiler annotation alone."""
+def _annotation_class():
     # Imported on first use: the fleet's router and supervisor processes
     # import this module and must stay off JAX.
     global _TraceAnnotation
     if _TraceAnnotation is None:
         from jax.profiler import TraceAnnotation
         _TraceAnnotation = TraceAnnotation
-    return _TraceAnnotation(name, **ids)
+    return _TraceAnnotation
+
+
+def span(name: str, **ids: Any):
+    """:func:`host_span` with no tracer: the profiler annotation alone."""
+    return _annotation_class()(name, **ids)
 
 
 def clock_pair(samples: int = 5) -> tuple[float, float]:
@@ -76,13 +96,15 @@ class _Span:
     """One in-flight span of an enabled tracer: the profiler annotation,
     and a complete ("ph": "X") ``trace.jsonl`` event on exit."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_ann", "_flush")
 
-    def __init__(self, tracer: "SpanTracer", name: str, args: dict):
+    def __init__(self, tracer: "SpanTracer", name: str, args: dict, *,
+                 flush: bool = True):
         self._tracer = tracer
         self._name = name
         self._args = args
         self._ann = span(name, **args)
+        self._flush = flush
 
     def __enter__(self) -> "_Span":
         self._ann.__enter__()
@@ -103,7 +125,7 @@ class _Span:
             "dur": t1 - self._t0, "pid": self._tracer._pid,
             "tid": threading.get_ident(),
             **({"args": self._args} if self._args else {}),
-        })
+        }, flush=self._flush)
 
 
 def host_span(name: str, tracer: "SpanTracer | None" = None, **ids: Any):
@@ -117,8 +139,13 @@ class SpanTracer:
     def __init__(self, path: str | None, *, flush_every: int = 64):
         self._path = path
         self._flush_every = max(1, flush_every)
+        # Serialized events wait here for the next flush. Appending takes no
+        # lock (a deque's append is atomic), so the garbage collector's
+        # callback can queue its event from inside a flush on its own thread;
+        # the lock only orders the flushes.
+        # trace-buffer-ok: drained whole by every flush (each flush_every)
+        self._pending: deque[str] = deque()
         self._lock = threading.Lock()
-        self._buf: list[str] = []
         self._pid = os.getpid()
         # Trace timestamps are microseconds on the perf_counter clock from
         # tracer construction; the leading clock event anchors ts=0 to the
@@ -166,32 +193,36 @@ class SpanTracer:
 
     def emit_lines(self, lines: list[str]) -> None:
         """Bulk-append PRE-SERIALIZED event lines (no trailing comma/
-        newline) under one lock acquisition — the per-request hot path.
-        The serve engine formats its request-lifecycle events with
-        f-strings instead of per-event ``json.dumps`` (measured ~10x
-        cheaper at 5 events/request on the completion thread); callers
-        own the validity of what they hand in (tests round-trip it
-        through :func:`read_trace`)."""
-        with self._lock:
-            if self._fh is None:
-                return
-            self._buf.extend(lines)
-            if len(self._buf) >= self._flush_every:
-                self._flush_locked()
+        newline) — the per-request hot path. The serve engine formats its
+        request-lifecycle events with f-strings instead of per-event
+        ``json.dumps`` (measured ~10x cheaper at 5 events/request on the
+        completion thread); callers own the validity of what they hand in
+        (tests round-trip it through :func:`read_trace`)."""
+        if self._fh is None:
+            return
+        self._pending.extend(lines)
+        self._flush_if_due()
 
-    def _emit(self, event: dict) -> None:
-        with self._lock:
-            if self._fh is None:
-                return
-            self._buf.append(json.dumps(event))
-            if len(self._buf) >= self._flush_every:
+    def _emit(self, event: dict, *, flush: bool = True) -> None:
+        """Queue one event; ``flush=False`` (the garbage collector's
+        callback) leaves the write, and its lock, to the next flush."""
+        if self._fh is None:
+            return
+        self._pending.append(json.dumps(event))
+        if flush:
+            self._flush_if_due()
+
+    def _flush_if_due(self) -> None:
+        if len(self._pending) >= self._flush_every:
+            with self._lock:
                 self._flush_locked()
 
     def _flush_locked(self) -> None:
-        if self._buf and self._fh is not None:
-            self._fh.write("".join(line + ",\n" for line in self._buf))
+        n = len(self._pending)
+        if n and self._fh is not None:
+            pop = self._pending.popleft
+            self._fh.write("".join(pop() + ",\n" for _ in range(n)))
             self._fh.flush()
-            self._buf.clear()
 
     def flush(self) -> None:
         with self._lock:
@@ -203,6 +234,114 @@ class SpanTracer:
             if self._fh is not None:
                 self._fh.close()
                 self._fh = None
+
+
+# ---------------------------------------------------------------------------
+# The garbage collector's pauses (module docstring): one callback a process.
+
+#: The two process-wide histograms the callback observes into, by the names
+#: every registry that asks exports them under.
+GC_PAUSE_HISTOGRAM = "host_gc_pause_ms"
+GC_FULL_PAUSE_HISTOGRAM = "host_gc_full_pause_ms"
+
+
+class _CallbackHistogram(Histogram):
+    """A :class:`Histogram` whose one writer is the gc callback. The
+    interpreter runs one collection at a time, so ``observe`` needs no lock;
+    it bumps ``_seq`` to odd before its writes and back to even after, and
+    ``snapshot`` copies until it reads one even ``_seq`` on both sides of the
+    copy. A collection that lands inside the copy, on any thread, finishes
+    its sample first and the copy runs again: nothing here can wait on the
+    thread it interrupted."""
+
+    __slots__ = ("_seq",)
+
+    def __init__(self):
+        super().__init__()
+        self._seq = 0
+
+    def observe(self, value: float) -> None:
+        value = float(value)
+        idx = bisect_left(self.bounds, value)
+        self._seq += 1
+        self.counts[idx] += 1
+        self.sum += value
+        self.count += 1
+        self._seq += 1
+
+    def snapshot(self) -> dict:
+        while True:
+            seq = self._seq
+            snap = {"bounds": list(self.bounds), "counts": list(self.counts),
+                    "sum": self.sum, "count": self.count}
+            if not seq & 1 and seq == self._seq:
+                return snap
+            time.sleep(0)       # a writer on another thread: let it finish
+
+
+class _GcHook:
+    """The ``gc.callbacks`` entry: ``host/gc`` around each collection, and
+    its pause observed into the two histograms. Every field is written by the
+    callback alone (one collection at a time), except ``tracer``, which
+    :func:`attach_gc_pauses` replaces by one assignment."""
+
+    __slots__ = ("pauses", "full_pauses", "tracer", "_t0", "_span", "_gen")
+
+    def __init__(self):
+        self.pauses = _CallbackHistogram()
+        self.full_pauses = _CallbackHistogram()
+        self.tracer: SpanTracer | None = None
+        self._t0 = 0.0
+        self._span = None
+        self._gen = 0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            gen = self._gen = info["generation"]
+            tracer = self.tracer
+            if tracer is not None and tracer._fh is not None:
+                sp = _Span(tracer, "host/gc", {"gen": gen}, flush=False)
+            else:
+                sp = _TraceAnnotation("host/gc", gen=gen)
+            sp.__enter__()
+            self._span = sp
+            return
+        sp = self._span
+        if sp is None:          # installed between a start and its stop
+            return
+        ms = (time.perf_counter() - self._t0) * 1e3
+        self._span = None
+        self.pauses.observe(ms)
+        if self._gen == 2:
+            self.full_pauses.observe(ms)
+        sp.set_metadata(collected=info["collected"],
+                        uncollectable=info["uncollectable"])
+        sp.__exit__(None, None, None)
+
+
+_GC_HOOK: _GcHook | None = None
+_GC_INSTALL_LOCK = threading.Lock()
+
+
+def attach_gc_pauses(registry: Any, tracer: "SpanTracer | None" = None
+                     ) -> None:
+    """Attach the process's two gc-pause histograms to ``registry``,
+    installing the one ``gc.callbacks`` entry on the first call. An enabled
+    ``tracer`` becomes the one the callback writes ``trace.jsonl`` events
+    to; a call without one leaves the callback's tracer as it was."""
+    global _GC_HOOK
+    _annotation_class()         # imported now: a callback must not import
+    with _GC_INSTALL_LOCK:
+        if _GC_HOOK is None:
+            _GC_HOOK = _GcHook()
+        hook = _GC_HOOK
+        if hook not in gc.callbacks:
+            gc.callbacks.append(hook)
+    if tracer is not None and tracer.enabled:
+        hook.tracer = tracer
+    registry.attach_histogram(GC_PAUSE_HISTOGRAM, hook.pauses)
+    registry.attach_histogram(GC_FULL_PAUSE_HISTOGRAM, hook.full_pauses)
 
 
 # ---------------------------------------------------------------------------

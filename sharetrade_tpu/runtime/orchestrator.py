@@ -191,6 +191,7 @@ class Orchestrator:
         self._h_host_process = None
         if cfg.obs.enabled:
             from sharetrade_tpu.obs.hist import SECONDS_BOUNDS, Histogram
+            from sharetrade_tpu.obs.trace import attach_gc_pauses
             self._h_chunk_seconds = self.metrics.attach_histogram(
                 "train_chunk_seconds", Histogram(bounds=SECONDS_BOUNDS))
             self._h_dispatch_gap = self.metrics.attach_histogram(
@@ -201,6 +202,8 @@ class Orchestrator:
                 "train_pipeline_stall_ms", Histogram())
             self._h_host_process = self.metrics.attach_histogram(
                 "train_host_process_ms", Histogram())
+            # The process's garbage-collection pauses (obs/trace.py).
+            attach_gc_pauses(self.metrics, self.obs.tracer)
         self.checkpoints = checkpoints or CheckpointManager(
             cfg.runtime.checkpoint_dir, keep=cfg.runtime.keep_checkpoints,
             fsync=cfg.checkpoint.fsync,

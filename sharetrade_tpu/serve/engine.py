@@ -184,7 +184,7 @@ from sharetrade_tpu.config import ConfigError, ServeConfig
 from sharetrade_tpu.models.core import apply_batched
 from sharetrade_tpu.obs import SERVE_STAGES
 from sharetrade_tpu.obs.hist import Histogram
-from sharetrade_tpu.obs.trace import span as trace_span
+from sharetrade_tpu.obs.trace import attach_gc_pauses, span as trace_span
 from sharetrade_tpu.precision import FP32, PrecisionPolicy
 from sharetrade_tpu.serve.spill import SpillArena
 from sharetrade_tpu.utils.logging import get_logger
@@ -819,6 +819,9 @@ class ServeEngine:
         self._h_inflight = self._registry.attach_histogram(
             "serve_inflight_ticks",
             Histogram(bounds=tuple(float(n) for n in range(1, 17))))
+        # The process's garbage-collection pauses (obs/trace.py), which
+        # stop both threads wherever they stand.
+        attach_gc_pauses(self._registry, getattr(obs, "tracer", None))
         self._ticks_dispatched = 0      # dispatcher-thread-owned
         self._ticks_completed = 0       # consumer-thread-owned
         #: End-to-end bucket counts at the last stats publish — the

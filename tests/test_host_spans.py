@@ -5,13 +5,19 @@ One call opens a ``jax.profiler.TraceAnnotation`` (on the ``/host:CPU``
 plane of whatever profiler session runs) and, with an enabled tracer, also
 writes the ``trace.jsonl`` event. The orchestrator, the async pipeline and
 the serve engine open every span through it, per chunk or per tick, under
-fixed names with the serial as an identifier.
+fixed names with the serial as an identifier. The interpreter's garbage
+collector is one more host phase: one ``gc.callbacks`` entry a process opens
+``host/gc`` through the same entry and observes each pause into two
+process-wide histograms, without taking a lock.
 """
 
 from __future__ import annotations
 
+import gc
 import glob
 import os
+import sys
+import threading
 import time
 
 import jax
@@ -22,9 +28,13 @@ import pytest
 from sharetrade_tpu.config import FrameworkConfig, ModelConfig, ServeConfig
 from sharetrade_tpu.models import build_model
 from sharetrade_tpu.obs import SpanTracer, read_trace
-from sharetrade_tpu.obs.trace import clock_pair, host_span, span
+from sharetrade_tpu.obs import trace as obs_trace
+from sharetrade_tpu.obs.trace import (GC_FULL_PAUSE_HISTOGRAM,
+                                      GC_PAUSE_HISTOGRAM, attach_gc_pauses,
+                                      clock_pair, host_span, span)
 from sharetrade_tpu.runtime import Orchestrator, ReplyState
 from sharetrade_tpu.serve import ServeEngine
+from sharetrade_tpu.utils.metrics import MetricsRegistry
 from sharetrade_tpu.utils.profiling import Tracer
 
 WINDOW = 8
@@ -36,6 +46,7 @@ TRAIN_SPANS = {"train/dispatch": {"chunk", "k"},
                "train/queue_wait": set(),
                "train/readback": {"chunk"},
                "train/host_process": {"chunk"}}
+GC_SPANS = {"host/gc": {"gen", "collected", "uncollectable"}}
 SERVE_SPANS = {"serve/collect_batch": {"tick"},
                "serve/dispatch_tick": {"tick", "rows", "cold"},
                "serve/done_wait": {"tick"},
@@ -186,13 +197,14 @@ def test_profiler_session_holds_every_training_and_serving_span(tmp_path):
                             slow_consumer_s=0.05)
     engine = toy_engine()
     serve_ticks(engine, 4, slow_consumer_s=0.05)
+    gc.collect(2)
     jax.profiler.stop_trace()
     orch.stop()
     engine.stop(drain=False)
     on_host: dict[str, list[dict]] = {}
     for name, ids in host_plane_events(tmp_path / "prof"):
         on_host.setdefault(name, []).append(ids)
-    for name, wanted in {**TRAIN_SPANS, **SERVE_SPANS}.items():
+    for name, wanted in {**TRAIN_SPANS, **SERVE_SPANS, **GC_SPANS}.items():
         assert name in on_host, f"{name} is not on the host plane"
         assert all(wanted <= set(ids) for ids in on_host[name]), name
     # One dispatch span a chunk, the chunk's serial as its identifier.
@@ -201,10 +213,15 @@ def test_profiler_session_holds_every_training_and_serving_span(tmp_path):
     assert sorted(ids["tick"] for ids in on_host["serve/dispatch_tick"]) \
         == [1, 2, 3, 4]
     assert not [n for n in on_host if n.startswith("train_chunk_")]
-    # The same spans, from the same calls, in the run's trace.jsonl.
-    written = {e["name"] for e in read_trace(
-        os.path.join(orch.cfg.obs.dir, "trace.jsonl")) if e["ph"] == "X"}
-    assert set(TRAIN_SPANS) <= written
+    assert 2 in {ids["gen"] for ids in on_host["host/gc"]}
+    # The same spans, from the same calls, in the run's trace.jsonl; the
+    # collections' too, the obs-enabled orchestrator's tracer being on.
+    events = [e for e in read_trace(
+        os.path.join(orch.cfg.obs.dir, "trace.jsonl")) if e["ph"] == "X"]
+    assert set(TRAIN_SPANS) <= {e["name"] for e in events}
+    collections = [e["args"] for e in events if e["name"] == "host/gc"]
+    assert all(GC_SPANS["host/gc"] <= set(ids) for ids in collections)
+    assert 2 in {ids["gen"] for ids in collections}
 
 
 # -- the stage histograms ----------------------------------------------------
@@ -232,7 +249,7 @@ def test_training_histograms_are_absent_with_obs_off(tmp_path):
     orch = run_toy_training(toy_train_cfg(tmp_path, obs=False))
     orch.stop()
     assert not [n for n in orch.metrics.histograms()
-                if n.startswith("train_")]
+                if n.startswith(("train_", "host_gc_"))]
     assert not os.path.exists(orch.cfg.obs.dir)
 
 
@@ -263,3 +280,126 @@ def test_serving_histograms_observe_once_per_tick(slow_consumer_s):
     assert snaps["serve_complete_host_ms"]["sum"] > 0
     assert engine.registry.counters().get(
         "serve_trace_decomposition_error_total", 0.0) == 0.0
+
+
+# -- the garbage collector's pauses ------------------------------------------
+
+@pytest.mark.parametrize("generation", [0, 1, 2])
+def test_a_forced_collection_is_one_pause_sample(generation):
+    registry = MetricsRegistry()
+    attach_gc_pauses(registry)
+    was_enabled = gc.isenabled()
+    gc.disable()                    # no collection but the forced one
+    try:
+        before = registry.histograms()
+        gc.collect(generation)
+        after = registry.histograms()
+    finally:
+        if was_enabled:
+            gc.enable()
+    added = {name: after[name]["count"] - before[name]["count"]
+             for name in (GC_PAUSE_HISTOGRAM, GC_FULL_PAUSE_HISTOGRAM)}
+    # The names the benchmark's host.gc_pause_ms / host.gc_full_pause_ms
+    # read.
+    assert added == {"host_gc_pause_ms": 1,
+                     "host_gc_full_pause_ms": int(generation == 2)}
+    assert after[GC_PAUSE_HISTOGRAM]["sum"] > before[GC_PAUSE_HISTOGRAM]["sum"]
+    for snap in after.values():
+        assert snap["count"] == sum(snap["counts"])
+
+
+def test_engines_and_an_orchestrator_share_one_callback(tmp_path):
+    engines = [toy_engine(), toy_engine()]
+    orch = Orchestrator(toy_train_cfg(tmp_path, obs=True))
+    try:
+        ours = [cb for cb in gc.callbacks
+                if isinstance(cb, obs_trace._GcHook)]
+        assert len(ours) == 1
+        registries = [e.registry for e in engines] + [orch.metrics]
+        for name in (GC_PAUSE_HISTOGRAM, GC_FULL_PAUSE_HISTOGRAM):
+            hists = [r.histogram(name) for r in registries]
+            assert hists[0] is not None
+            assert all(h is hists[0] for h in hists), name
+    finally:
+        orch.stop()
+        for engine in engines:
+            engine.stop(drain=False)
+
+
+def test_reading_the_histograms_while_collections_run_cannot_deadlock():
+    """The callback runs on whichever thread allocated, inside a snapshot
+    too: the reader keeps what it reads, so under a low threshold its own
+    copies set off collections, while more threads than cores force a
+    thousand more. A short switch interval stops a writer in the middle of
+    a sample; no snapshot may be torn and no sample lost."""
+    registry = MetricsRegistry()
+    attach_gc_pauses(registry)
+    reads = []
+    n_collectors = (os.cpu_count() or 1) + 1
+
+    def reader():
+        for _ in range(2000):
+            reads.append(registry.histograms())
+
+    def collector(n):
+        for _ in range(n):
+            gc.collect(0)
+
+    share = [1000 // n_collectors + (i < 1000 % n_collectors)
+             for i in range(n_collectors)]
+    threads = [threading.Thread(target=reader, daemon=True)] + [
+        threading.Thread(target=collector, args=(n,), daemon=True)
+        for n in share]
+
+    def counted():
+        """(collections the interpreter ran, pause samples, full ones)."""
+        gc.disable()
+        try:
+            snaps = registry.histograms()
+            return (sum(st["collections"] for st in gc.get_stats()),
+                    snaps[GC_PAUSE_HISTOGRAM]["count"],
+                    snaps[GC_FULL_PAUSE_HISTOGRAM]["count"],
+                    gc.get_stats()[2]["collections"])
+        finally:
+            gc.enable()
+
+    threshold, interval = gc.get_threshold(), sys.getswitchinterval()
+    before = counted()
+    gc.set_threshold(10)
+    sys.setswitchinterval(1e-5)
+    try:
+        deadline = time.monotonic() + 10.0
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(max(0.0, deadline - time.monotonic()))
+    finally:
+        gc.set_threshold(*threshold)
+        sys.setswitchinterval(interval)
+    assert not [t for t in threads if t.is_alive()]
+    after = counted()
+    assert len(reads) == 2000
+    assert all(snap["count"] == sum(snap["counts"])
+               for snaps in reads for snap in snaps.values())
+    ran, samples, full, full_ran = (b - a for a, b in zip(before, after))
+    # A forced collection that meets another one running does nothing.
+    assert ran > 0 and samples == ran and full == full_ran
+
+
+def test_the_callback_queues_its_event_while_the_tracer_flushes(tmp_path):
+    """A collection inside a ``trace.jsonl`` flush on its own thread: the
+    event waits for the next flush instead of for the tracer's lock."""
+    tracer = SpanTracer(str(tmp_path / "trace.jsonl"))
+    attach_gc_pauses(MetricsRegistry(), tracer)
+
+    def flush_then_collect():
+        with tracer._lock:
+            gc.collect(1)
+
+    t = threading.Thread(target=flush_then_collect, daemon=True)
+    t.start()
+    t.join(10.0)
+    assert not t.is_alive()
+    tracer.close()
+    assert 1 in {e["args"]["gen"] for e in read_trace(
+        str(tmp_path / "trace.jsonl")) if e["name"] == "host/gc"}
