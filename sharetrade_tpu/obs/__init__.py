@@ -13,13 +13,14 @@ hot-loop cost when disabled):
   ``train/dispatch`` and ``train/pipeline_stall`` (dispatcher blocked on
   the bounded queue) on the dispatcher tid; ``train/queue_wait`` (consumer
   starved — healthy), ``train/readback`` and ``train/host_process`` on
-  the consumer tid; ``serve/collect_batch``, ``serve/dispatch_tick``,
-  ``serve/done_wait`` on the engine's dispatcher, ``serve/complete_batch``
-  with its ``serve/readback`` children on its consumer (the engine emits
+  the consumer tid; ``serve/slot_wait``, ``serve/collect_batch``,
+  ``serve/dispatch_tick``, ``serve/done_wait`` on the engine's
+  dispatcher, ``serve/complete_batch`` with its ``serve/readback``
+  children on its consumer (the engine emits
   them with or without an ``Obs`` bundle). Beside them, from the same
   stamps, the stage histograms ``train_dispatch_call_ms`` /
   ``train_pipeline_stall_ms`` / ``train_host_process_ms`` (obs-gated) and
-  ``serve_tick_host_ms`` / ``serve_done_wait_ms`` /
+  ``serve_slot_wait_ms`` / ``serve_tick_host_ms`` / ``serve_done_wait_ms`` /
   ``serve_complete_host_ms`` / ``serve_inflight_ticks`` (always on), with
   ``pipeline_stalls_total``/``pipeline_queue_depth`` in the metrics
   export. ``host/gc`` (identifier ``gen``; ``collected``/``uncollectable``

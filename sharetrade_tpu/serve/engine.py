@@ -27,9 +27,11 @@ Structure (mirrors ``runtime/pipeline.py``'s dispatcher/consumer split):
 - **consumer thread** (``_complete_batch``): device readback, request
   completion (events + callbacks), latency accounting, SLO gauge
   publication through ``MetricsRegistry`` (→ ``metrics.prom`` when obs
-  export is on). The dispatcher→consumer queue is bounded, so in-flight
-  device buffers are bounded and dispatch backpressures instead of racing
-  ahead.
+  export is on). The dispatcher collects a tick only while fewer than
+  two are launched and unread (one running on the device, one staged
+  behind it), so a request rides the tick after the running one instead
+  of queueing behind a pile of launched ticks, and in-flight device
+  buffers stay bounded.
 
 Weight swaps are ATOMIC between batches: :meth:`ServeEngine.swap_params`
 replaces one ``(params, step)`` reference; the dispatcher reads it exactly
@@ -198,6 +200,12 @@ _SHUTDOWN = object()
 #: the consumer is already awake) so an IDLE consumer executes the disk
 #: ops now instead of after its 200 ms poll.
 _SPILL_TICK = object()
+#: Ticks launched and not yet consumed before the dispatcher collects the
+#: next: one running on the device and one staged behind it. The least
+#: that keeps the device fed while the host closes and stages a tick in
+#: less than a tick's device time; where it needs more, the count never
+#: reaches the bound and the wait never engages.
+_MAX_INFLIGHT_TICKS = 2
 
 #: Session ids made only of these characters embed into trace JSON
 #: without escaping (the fast path — harness/CLI ids are all of this
@@ -709,7 +717,7 @@ class ServeEngine:
         #: cross-thread drop would race admit()'s LRU iteration).
         self._poisoned: deque = deque()  # trace-buffer-ok: drained to empty
         # by the dispatcher every tick; growth is bounded by in-flight
-        # batches (done_depth * max_batch)
+        # batches (_MAX_INFLIGHT_TICKS * max_batch)
         self._stop_event = threading.Event()
         self._pending = 0
         self._pending_lock = threading.Lock()
@@ -806,10 +814,14 @@ class ServeEngine:
                          *(f"serve_{s}_ms" for s in SERVE_STAGES))}
         self._h_e2e = self._hists["serve_request_ms"]
         # Per-TICK histograms, where the work waits between the two
-        # threads: the dispatcher's host time in a tick, how long it then
-        # blocks handing the tick to the consumer (0 when the done queue
-        # has room), the consumer's host time per tick less its readback,
-        # and the ticks dispatched and not yet completed at each dispatch.
+        # threads: how long the dispatcher waited for a device slot before
+        # collecting the tick (0 with one free), its host time in the tick,
+        # how long it then blocks handing the tick to the consumer (0 when
+        # the done queue has room), the consumer's host time per tick less
+        # its readback, and the ticks dispatched and not yet completed at
+        # each dispatch.
+        self._h_slot_wait = self._registry.attach_histogram(
+            "serve_slot_wait_ms", Histogram())
         self._h_tick_host = self._registry.attach_histogram(
             "serve_tick_host_ms", Histogram())
         self._h_done_wait = self._registry.attach_histogram(
@@ -824,6 +836,9 @@ class ServeEngine:
         attach_gc_pauses(self._registry, getattr(obs, "tracer", None))
         self._ticks_dispatched = 0      # dispatcher-thread-owned
         self._ticks_completed = 0       # consumer-thread-owned
+        #: Read both counters under it; the consumer notifies it each time
+        #: it consumes a tick, waking a dispatcher waiting for a slot.
+        self._slot_cv = threading.Condition()
         #: End-to-end bucket counts at the last stats publish — the
         #: per-window delta the p50/p99 gauges are quantiled over.
         self._p50_prev_counts = self._h_e2e.snapshot()["counts"]
@@ -897,7 +912,7 @@ class ServeEngine:
         # admission state — drains at the top of each tick and drops
         # entries whose session already re-entered).
         # trace-buffer-ok: bounded by in-flight batches
-        # (done_depth * max_batch entries at most)
+        # (_MAX_INFLIGHT_TICKS * max_batch entries at most)
         self._park_inbox: deque = deque()
         # ---- spill tier (ISSUE 20) ----------------------------------
         #: Per-session dispatched-step counts for HOT sessions (the
@@ -1469,6 +1484,9 @@ class ServeEngine:
                                     or RuntimeError("serve consumer fault"))
                 continue
             tick = self._batch_serial + 1
+            slot_wait_ms = self._wait_for_slot(tick)
+            if slot_wait_ms is None:
+                continue        # stopped, or a consumer fault to supervise
             with self._span("serve/collect_batch", tick=tick):
                 batch = self._collect_batch()
             if not batch:
@@ -1490,12 +1508,13 @@ class ServeEngine:
                 self._supervise(exc)
                 continue
             t_put = time.perf_counter()
+            self._h_slot_wait.observe(slot_wait_ms)
             self._h_tick_host.observe((t_put - t_tick) * 1e3)
             self._ticks_dispatched += 1
             self._h_inflight.observe(
                 self._ticks_dispatched - self._ticks_completed)
-            # Bounded handoff: blocking here is the backpressure that
-            # keeps in-flight device buffers bounded (pipeline.py's put).
+            # Bounded handoff; with the slot bound above it blocks only
+            # behind a spill nudge or a shallow done queue.
             try:
                 self._done_q.put_nowait(done)
                 self._h_done_wait.observe(0.0)
@@ -1509,6 +1528,29 @@ class ServeEngine:
         # these structures (stop() and submit() re-sweep only for racers,
         # and only once this thread is provably dead).
         self._fail_leftovers()
+
+    def _wait_for_slot(self, tick: int) -> float | None:
+        """Block until fewer than ``_MAX_INFLIGHT_TICKS`` ticks are
+        launched and unconsumed, so the tick collected next starts right
+        after the one the device runs; requests arriving meanwhile stay in
+        the ingress queue for that tick. Returns the milliseconds waited
+        (0.0 with a slot free), or None when stop() or a consumer fault's
+        restart request ended the wait first."""
+        cv = self._slot_cv
+        with cv:
+            if (self._ticks_dispatched - self._ticks_completed
+                    < _MAX_INFLIGHT_TICKS):
+                return 0.0
+        t0 = time.perf_counter()
+        with self._span("serve/slot_wait", tick=tick), cv:
+            while (self._ticks_dispatched - self._ticks_completed
+                   >= _MAX_INFLIGHT_TICKS):
+                if (self._stop_event.is_set()
+                        or self._restart_requested.is_set()):
+                    return None
+                # Bounded as the idle poll is: stop() sets only its event.
+                cv.wait(0.05)
+        return (time.perf_counter() - t0) * 1e3
 
     def _fail_leftovers(self) -> None:
         """Fail every request still in the ingress/deferred queues with a
@@ -2190,7 +2232,10 @@ class ServeEngine:
             self._consumer_fault_epoch = item.epoch
             self._restart_requested.set()
         finally:
-            self._ticks_completed += 1
+            # Frees the tick's slot, faulted or not.
+            with self._slot_cv:
+                self._ticks_completed += 1
+                self._slot_cv.notify()
 
     #: Arena take verdicts -> registry counters (the fleet router folds
     #: these per engine into fleet_spill_* — ISSUE 20 observability).

@@ -47,7 +47,8 @@ TRAIN_SPANS = {"train/dispatch": {"chunk", "k"},
                "train/readback": {"chunk"},
                "train/host_process": {"chunk"}}
 GC_SPANS = {"host/gc": {"gen", "collected", "uncollectable"}}
-SERVE_SPANS = {"serve/collect_batch": {"tick"},
+SERVE_SPANS = {"serve/slot_wait": {"tick"},
+               "serve/collect_batch": {"tick"},
                "serve/dispatch_tick": {"tick", "rows", "cold"},
                "serve/done_wait": {"tick"},
                "serve/complete_batch": {"tick", "rows"},
@@ -116,9 +117,12 @@ def toy_engine(*, done_depth: int = DONE_DEPTH) -> ServeEngine:
     return engine
 
 
-def serve_ticks(engine, ticks: int, *, slow_consumer_s: float = 0.0) -> None:
-    """``ticks`` one-row ticks; a slowed consumer fills the done queue, so
-    the dispatcher blocks in ``_done_q.put``."""
+def serve_ticks(engine, ticks: int, *, slow_consumer_s: float = 0.0,
+                linger_s: float = 0.0) -> None:
+    """``ticks`` one-row ticks. A slowed consumer holds two ticks in
+    flight, so the dispatcher waits for a slot; one that also lingers after
+    each tick, the next still in the depth-1 done queue, makes the
+    dispatcher block in ``_done_q.put`` as well."""
     if slow_consumer_s:
         complete = engine._complete_batch
 
@@ -126,6 +130,13 @@ def serve_ticks(engine, ticks: int, *, slow_consumer_s: float = 0.0) -> None:
             time.sleep(slow_consumer_s)
             complete(done)
         engine._complete_batch = slowed
+    if linger_s:
+        drain = engine._drain_spill_ops
+
+        def lingering():
+            time.sleep(linger_s)
+            drain()
+        engine._drain_spill_ops = lingering
     obs = np.concatenate([PRICES[:WINDOW], [2400.0, 0.0]]).astype(np.float32)
     handles = [engine.submit(f"s{i}", obs) for i in range(ticks)]
     assert all(h.wait(30.0) is not None for h in handles)
@@ -196,7 +207,7 @@ def test_profiler_session_holds_every_training_and_serving_span(tmp_path):
     orch = run_toy_training(toy_train_cfg(tmp_path, obs=True),
                             slow_consumer_s=0.05)
     engine = toy_engine()
-    serve_ticks(engine, 4, slow_consumer_s=0.05)
+    serve_ticks(engine, 4, slow_consumer_s=0.05, linger_s=0.1)
     gc.collect(2)
     jax.profiler.stop_trace()
     orch.stop()
@@ -262,24 +273,73 @@ def test_serving_histograms_observe_once_per_tick(slow_consumer_s):
     finally:
         engine.stop(drain=False)
     snaps = engine.registry.histograms()
-    for name in ("serve_tick_host_ms", "serve_done_wait_ms",
-                 "serve_complete_host_ms", "serve_inflight_ticks"):
+    for name in ("serve_slot_wait_ms", "serve_tick_host_ms",
+                 "serve_done_wait_ms", "serve_complete_host_ms",
+                 "serve_inflight_ticks"):
         assert snaps[name]["count"] == ticks, name
     inflight = snaps["serve_inflight_ticks"]
     assert inflight["bounds"] == [float(n) for n in range(1, 17)]
     worst = max(b for b, c in zip(inflight["bounds"], inflight["counts"])
                 if c)
-    assert 1 <= worst <= DONE_DEPTH + 2 and inflight["counts"][-1] == 0
+    assert 1 <= worst <= 2 and inflight["counts"][-1] == 0
     if slow_consumer_s:
-        # Ticks pile up behind the consumer: the dispatcher waits in put.
-        assert worst == DONE_DEPTH + 2
-        assert snaps["serve_done_wait_ms"]["sum"] > 10.0
+        # Two ticks in flight behind the consumer: the dispatcher waits
+        # for a slot before it collects the next.
+        assert worst == 2
+        assert snaps["serve_slot_wait_ms"]["sum"] > 10.0
     else:
         assert snaps["serve_done_wait_ms"]["sum"] < \
             snaps["serve_tick_host_ms"]["sum"] + 50.0
     assert snaps["serve_complete_host_ms"]["sum"] > 0
     assert engine.registry.counters().get(
         "serve_trace_decomposition_error_total", 0.0) == 0.0
+
+
+def test_requests_arriving_behind_two_ticks_ride_the_next_one():
+    """Requests submitted while two ticks are in flight wait in the ingress
+    queue, not in ticks of their own: once a slot frees they all ride the
+    third tick, though each came after the last one's 1 ms coalescing
+    window had closed."""
+    model = build_model(ModelConfig(kind="mlp", hidden_dim=16), WINDOW + 2,
+                        head="ac")
+    engine = ServeEngine(
+        model, ServeConfig(max_batch=8, slots=16, batch_timeout_ms=1.0),
+        model.init(jax.random.PRNGKey(1)))
+    engine.warmup()
+    release = threading.Event()
+    complete = engine._complete_batch
+
+    def held(done):
+        release.wait(30.0)
+        complete(done)
+    engine._complete_batch = held
+
+    def dispatched(n):
+        deadline = time.monotonic() + 30.0
+        while engine._ticks_dispatched < n and time.monotonic() < deadline:
+            time.sleep(0.001)
+        return engine._ticks_dispatched
+
+    obs = np.concatenate([PRICES[:WINDOW], [2400.0, 0.0]]).astype(np.float32)
+    try:
+        first = engine.submit("a", obs)
+        assert dispatched(1) == 1
+        second = engine.submit("b", obs)
+        assert dispatched(2) == 2
+        late = []
+        for i in range(4):
+            late.append(engine.submit(f"c{i}", obs))
+            time.sleep(0.005)
+        time.sleep(0.05)
+        assert engine._ticks_dispatched == 2, "a tick launched behind two"
+        release.set()
+        assert all(h.wait(30.0) is not None for h in [first, second, *late])
+    finally:
+        release.set()
+        engine.stop(drain=False)
+    assert [h.trace.batch for h in (first, second)] == [1, 2]
+    assert {h.trace.batch for h in late} == {3}
+    assert engine.registry.histograms()["serve_tick_host_ms"]["count"] == 3
 
 
 # -- the garbage collector's pauses ------------------------------------------

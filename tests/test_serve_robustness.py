@@ -425,6 +425,165 @@ def test_stop_reports_hung_thread(mlp_model, mlp_params, prices):
 
 
 # ---------------------------------------------------------------------------
+# the dispatcher's two device slots: no path may leak one
+
+
+def _until(predicate, timeout_s=20.0) -> bool:
+    deadline = time.monotonic() + timeout_s
+    while not predicate() and time.monotonic() < deadline:
+        time.sleep(0.002)
+    return predicate()
+
+
+def _in_flight(engine) -> int:
+    return engine._ticks_dispatched - engine._ticks_completed
+
+
+def test_stop_while_waiting_on_a_slot(mlp_model, mlp_params, prices):
+    """stop(drain=False) with the consumer stalled and the dispatcher
+    waiting for a slot: the wait ends on the stop at once (the request it
+    held back fails as never dispatched, well before the stall clears),
+    and stop() returns True within its timeout."""
+    engine, stall = _stalled_engine(mlp_model, mlp_params, max_queue=8,
+                                    shed_policy="reject", prices=prices,
+                                    stall_s=3.0)
+    second = engine.submit("second", obs_at(prices, 1, 0))
+    assert _until(lambda: _in_flight(engine) == 2)
+    failed_at = []
+    held = engine.submit("held", obs_at(prices, 2, 0),
+                         callback=lambda r: failed_at.append(
+                             time.perf_counter()))
+    time.sleep(0.05)
+    assert engine.queue_depth() == 1, "the dispatcher did not wait"
+    t_stop = time.perf_counter()
+    assert engine.stop(drain=False, timeout_s=10.0) is True
+    assert time.perf_counter() - t_stop < 10.0
+    assert held.wait(1.0) is None and isinstance(held.error, RuntimeError)
+    assert failed_at and failed_at[0] - t_stop < 1.0
+    assert stall.result is not None and second.result is not None
+
+
+@pytest.mark.parametrize("max_restarts", [0, 1])
+def test_a_faulted_completion_frees_its_slot(mlp_model, mlp_params, prices,
+                                             max_restarts):
+    """A completion that raises still frees its tick's slot: with every
+    slot held by faulting ticks the engine would wedge. Later requests
+    are served, after the supervised rebuild where max_restarts is 1."""
+    registry = MetricsRegistry()
+    engine, stall = _stalled_engine(
+        mlp_model, mlp_params, max_queue=8, shed_policy="reject",
+        prices=prices, registry=registry, stall_s=0.3,
+        max_restarts=max_restarts, restart_backoff_s=0.01,
+        restart_backoff_max_s=0.02)
+    complete = engine._complete_batch
+
+    def faulting(done):
+        if any(str(req.session_id).startswith("bad")
+               for reqs, *_ in done.groups for req in reqs):
+            raise RuntimeError("injected readback fault")
+        complete(done)
+    engine._complete_batch = faulting
+    try:
+        # Two faulting ticks where a restart cannot trip the terminal
+        # state, one where the streak allows a single rebuild.
+        bad = []
+        for i in range(2 if max_restarts == 0 else 1):
+            bad.append(engine.submit(f"bad{i}", obs_at(prices, i, 0)))
+            assert _until(lambda: engine._ticks_dispatched == 2 + i)
+        assert all(h.wait(20.0) is None for h in bad)
+        assert all(isinstance(h.error, RuntimeError) for h in bad)
+        for i in range(4):
+            result = engine.submit(f"good{i}", obs_at(prices, i, 1)).wait(60.0)
+            assert result is not None, "a faulted tick leaked its slot"
+        assert _in_flight(engine) == 0
+        assert registry.counters().get("serve_restarts_total", 0.0) \
+            == float(max_restarts)
+    finally:
+        assert stall.wait(10.0) is not None
+        assert engine.stop(drain=False) is True
+
+
+def test_a_dispatch_that_raises_takes_no_slot(mlp_model, mlp_params, prices):
+    """A malformed request fails in dispatch, before any launch: it takes
+    no slot, so with the stalled tick holding one the next request still
+    launches while the stall lasts."""
+    engine, stall = _stalled_engine(mlp_model, mlp_params, max_queue=8,
+                                    shed_policy="reject", prices=prices,
+                                    stall_s=3.0)
+    try:
+        bad = engine.submit("bad", np.ones(3, np.float32))
+        assert bad.wait(10.0) is None and bad.error is not None
+        assert engine._ticks_dispatched == 1
+        good = engine.submit("good", obs_at(prices, 1, 0))
+        assert _until(lambda: engine._ticks_dispatched == 2, 2.0)
+        assert engine._ticks_completed == 0, "the stall ended first"
+        assert good.wait(20.0) is not None
+    finally:
+        assert stall.wait(10.0) is not None
+        assert engine.stop(drain=False) is True
+    assert _in_flight(engine) == 0
+
+
+def test_a_spill_nudge_is_not_a_tick(mlp_model, mlp_params, prices):
+    """The spill sentinel that nudges the consumer sits in the done queue
+    but holds no slot: with it queued behind the stalled tick, the next
+    request still launches, and both counters end level."""
+    engine, stall = _stalled_engine(mlp_model, mlp_params, max_queue=8,
+                                    shed_policy="reject", prices=prices,
+                                    stall_s=3.0)
+    try:
+        engine._kick_consumer()
+        assert engine._done_q.qsize() == 1
+        good = engine.submit("good", obs_at(prices, 1, 0))
+        assert _until(lambda: engine._ticks_dispatched == 2, 2.0)
+        assert engine._ticks_completed == 0, "the stall ended first"
+        assert good.wait(20.0) is not None
+    finally:
+        assert stall.wait(10.0) is not None
+        assert engine.stop(drain=False) is True
+    assert engine._ticks_dispatched == engine._ticks_completed == 2
+    inflight = engine.registry.histograms()["serve_inflight_ticks"]
+    assert inflight["count"] == 2 and sum(inflight["counts"][2:]) == 0
+
+
+def test_the_slot_bound_holds_under_thread_switch_pressure(
+        mlp_model, mlp_params, prices):
+    """More submitting threads than cores and a 10 µs switch interval: no
+    tick launches behind two unconsumed ones, every request is answered,
+    and the two counters end level, so no slot was lost or leaked."""
+    engine = ServeEngine(mlp_model,
+                         ServeConfig(max_batch=2, slots=64,
+                                     batch_timeout_ms=0.0),
+                         mlp_params)
+    engine.warmup()
+    answered, workers = [], (os.cpu_count() or 1) + 1
+
+    def client(w):
+        for i in range(24):
+            result = engine.submit(f"w{w}", obs_at(prices, w, i)).wait(30.0)
+            answered.append(result is not None)
+
+    old_interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=client, args=(w,))
+                   for w in range(workers)]
+        for thread in threads:
+            thread.start()
+        for thread in threads:
+            thread.join(60.0)
+        assert not any(thread.is_alive() for thread in threads)
+    finally:
+        sys.setswitchinterval(old_interval)
+        assert engine.stop(drain=False) is True
+    assert len(answered) == 24 * workers and all(answered)
+    inflight = engine.registry.histograms()["serve_inflight_ticks"]
+    assert sum(inflight["counts"][2:]) == 0
+    assert inflight["count"] == engine._ticks_dispatched \
+        == engine._ticks_completed
+
+
+# ---------------------------------------------------------------------------
 # swap circuit breaker
 
 

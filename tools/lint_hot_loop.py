@@ -362,8 +362,8 @@ def _scan_nested_funcs(pattern, marker) -> list[tuple[str, int, str, str]]:
 #: ``_complete_loop``), which must keep existing.
 SERVE_TARGET = (pathlib.Path(__file__).resolve().parent.parent
                 / "sharetrade_tpu" / "serve" / "engine.py")
-SERVE_DISPATCH_FUNCS = ("_serve_loop", "_collect_batch", "_dispatch_batch",
-                        "_pad")
+SERVE_DISPATCH_FUNCS = ("_serve_loop", "_wait_for_slot", "_collect_batch",
+                        "_dispatch_batch", "_pad")
 SERVE_CONSUMER_FUNCS = ("_complete_batch", "_complete_loop")
 SERVE_BLOCK_PATTERN = re.compile(
     r"device_get\(|os\.fsync\(|time\.sleep\(|\blog\.\w+\s*\(|"
